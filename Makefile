@@ -103,8 +103,7 @@ online-chaos:
 durability-chaos:
 	$(PY) -m pytest tests/ -m chaos -q -k "wal or replica or durab"
 
-# fused-kernel acceptance (ISSUE 13; docs/perf_notes.md "Fused FM
-# kernel"): byte-identical trajectories across fused_kernel={off, jnp,
+# fused-kernel acceptance (ISSUE 13): byte-identical trajectories across fused_kernel={off, jnp,
 # pallas-if-available} at fs=1 and fs=4, on-device dedup parity vs the
 # host np.unique, and the pallas gather/scatter kernels bit-for-bit vs
 # the jnp contract (interpret mode off-TPU) — tier-1 time budget
@@ -127,21 +126,21 @@ ci: lint test hlomap fleet-chaos durability-chaos smoke
 obs-report:
 	$(PY) tools/obs_report.py --metrics $(METRICS) $(if $(TRACE),--trace $(TRACE))
 
-# one-time text -> rec2 convert (docs/perf_notes.md "Data formats & the
-# streamed fast path"): parallel across cores, zero-copy members out.
+# one-time text -> rec2 convert: parallel across cores, zero-copy
+# members out.
 #   make convert DATA_IN=criteo.txt DATA_FORMAT=criteo [DATA_OUT=criteo.rec]
 convert:
 	$(PY) -m difacto_tpu task=convert data_in=$(DATA_IN) \
 	  data_format=$(DATA_FORMAT) data_out=$(DATA_OUT) data_out_format=rec
 
 # streamed-regime bench alone (convert + replay + streamed epochs, with
-# the per-stage breakdown and the delta vs the newest BENCH_r*.json)
+# the per-stage breakdown)
 stream-bench:
 	$(PY) bench.py --e2e
 
 # fs-sharded capacity-scaling legs alone: table = base*fs rows per fs
 # rung in {1,2,4,8}, ex/s + per-device bytes per leg (the MULTICHIP
-# metric; docs/perf_notes.md "Mesh-sharded parameter table")
+# metric)
 multichip-bench:
 	$(PY) bench.py --multichip
 
@@ -150,7 +149,7 @@ multichip-bench:
 online-bench:
 	$(PY) bench.py --online
 
-# table-capacity levers (ISSUE 19; docs/perf_notes.md "Table capacity"):
+# table-capacity levers (ISSUE 19):
 # quantized-slot AUC legs at 2x/4x/8x effective capacity vs the fp32
 # baseline + cold-tier hit-rate across zipf skews
 capacity-bench:

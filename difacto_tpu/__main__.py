@@ -106,10 +106,10 @@ def main(argv: list[str] | None = None) -> int:
               file=sys.stderr)
         return 1
 
-    # honor an explicit JAX_PLATFORMS=cpu (virtual-mesh runs) before the
-    # first backend touch — multihost initialize below binds devices
-    from .utils.platform import apply_env_platform
-    apply_env_platform()
+    # before the first backend touch — multihost initialize below binds
+    # devices
+    from .utils.device import bound_device, place_compile_cache
+    cache_dir = place_compile_cache()
 
     if "DIFACTO_NPROCS" in os.environ:
         from .parallel.multihost import initialize
@@ -117,6 +117,13 @@ def main(argv: list[str] | None = None) -> int:
 
     kwargs = parse_cli_args(argv)
     param, remain = DifactoParam.init_allow_unknown(kwargs)
+    if param.task != "convert":
+        # convert is host-only and must not take the chip from a trainer
+        # running beside it; every other task names what it bound, so a
+        # process that could not get the accelerator and came up on the
+        # CPU is visible in its first log line
+        log.info("device: %s (compile cache: %s)", bound_device(),
+                 cache_dir)
 
     if param.task in ("train", "pred"):
         if param.task == "pred" and param.learner != "sgd":

@@ -16,7 +16,7 @@ rows per device behind SlotStore knobs.
   promote/demote in batches on the dispatch thread.
 
 All three default off; the defaults are byte-identical to the
-pre-capacity trajectory (docs/perf_notes.md "Table capacity").
+pre-capacity trajectory.
 """
 
 from .sketch import CountMinSketch, AdmissionFilter  # noqa: F401

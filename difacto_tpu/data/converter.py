@@ -169,7 +169,7 @@ class ConverterParam(Param):
     # format exists to make STREAMING fast (the reference picked LZ4 for
     # the same reason, src/data/compressed_row_block.h:20-142) and zlib
     # decompress measured 68% of the streamed-epoch host-pack pass (1.32
-    # of 1.93 s per 600k rows, docs/perf_notes.md "the streamed regime");
+    # of 1.93 s per 600k rows on the development host);
     # uncompressed members are ~2.6x larger but read at page-cache speed.
     # rec2 members are always raw.
     rec_compress: bool = False

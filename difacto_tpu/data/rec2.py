@@ -5,8 +5,8 @@ but every read pays the zip central-directory walk plus a full memcpy of
 each array out of the archive, and the bytes can never be mapped. rec2
 replaces that with the layout the reference's recordio/CRB fast path
 implies (src/reader/crb_parser.h:16-47, src/data/compressed_row_block.h)
-minus the LZ4 (uncompressed members already won the zlib-vs-raw trade,
-docs/perf_notes.md "The streamed regime"): a fixed little-endian header,
+minus the LZ4 (uncompressed members already won the zlib-vs-raw
+trade): a fixed little-endian header,
 a section table, and page-aligned raw array sections, so a reader
 ``mmap``s the file and wraps each section with ``np.frombuffer`` —
 **zero copies until the bytes are actually consumed**, and the OS page

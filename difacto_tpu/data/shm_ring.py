@@ -8,8 +8,8 @@ copies — a worker writes each payload's numpy arrays directly into a ring
 slot of one preallocated ``multiprocessing.shared_memory`` segment, and
 the consumer wraps the slot with ``np.frombuffer`` views. Python threads
 cannot give this overlap (the round-5 decomposition showed the producer
-thread and the dispatch loop serializing on the GIL,
-docs/perf_notes.md "The streamed regime"); processes + shared memory can.
+thread and the dispatch loop serializing on the GIL); processes +
+shared memory can.
 
 Slot layout (one slot = ``slot_bytes`` of the segment)::
 

@@ -113,7 +113,7 @@ def fm_predict_panel_xv(params: FMParams, pb
     token block VMEM-resident: the single big gather made XLA materialize
     the [B*F, 1+k] token stream to HBM plus a layout reshape (~10 ms of a
     39 ms step at bench shapes, traced); the unrolled loop measures
-    37.8 ms vs 39.4 (docs/perf_notes.md). Panels wider than
+    37.8 ms vs 39.4. Panels wider than
     _COLLOOP_MAX_WIDTH fall back to the single-gather form — the loop
     unrolls one gather per column into the jit trace, so program size
     and compile time grow linearly with width."""
@@ -175,7 +175,7 @@ def _fm_grad_panel_chunked(params: FMParams, pb, p: jnp.ndarray,
     are computed as a dense vectorised gather+reduce over fixed-L chunks
     of each lane's token run, and the scatter shrinks to ~U + B*F/L
     partial rows. Measured 53.3 -> 39.4 ms full-step (1.35x faster than
-    the sorted path it replaced) at bench shapes (docs/perf_notes.md).
+    the sorted path it replaced) at bench shapes.
 
     Padded chunk cells gather row b_cap (out of bounds -> 0); padded
     chunks carry lane u_cap (out of bounds -> dropped).

@@ -3,24 +3,26 @@
 The TPU compute path is JAX/XLA; the host runtime around it (parsing, IO)
 uses C++ where the reference did (dmlc-core's parsers are C++ too). Build is
 lazy and cached: first use compiles the shared library with g++ next to this
-package; any failure falls back to the pure-Python implementations, so the
-framework never hard-requires a toolchain.
+package, under a file name that carries a hash of the sources — a library
+built from other sources (a copied tree resets mtimes, so age proves
+nothing) is simply never found. Any failure falls back to the pure-Python
+implementations, so the framework never hard-requires a toolchain.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
 import threading
-from typing import Optional
+from typing import Optional, Sequence
 from ..utils.locktrace import mutex
 
 log = logging.getLogger("difacto_tpu")
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_DIR, "_difacto_native.so")
 _SRC = [os.path.join(_DIR, "libsvm_parser.cc"),
         os.path.join(_DIR, "criteo_parser.cc"),
         os.path.join(_DIR, "adfea_parser.cc")]
@@ -30,14 +32,24 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
+def lib_path(srcs: Sequence[str] = _SRC) -> str:
+    """Where the library built from exactly these source bytes lives.
+    Raises OSError when a source is missing (partial checkout)."""
+    h = hashlib.sha256()
+    for src in srcs:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return os.path.join(_DIR, f"_difacto_native-{h.hexdigest()[:16]}.so")
+
+
+def _build(so: str) -> bool:
     # per-pid tmp so concurrent first-use builds in separate processes
     # can't interleave writes; os.replace is atomic
-    tmp = f"{_SO}.{os.getpid()}.tmp"
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", tmp] + _SRC
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _SO)
+        os.replace(tmp, so)
         return True
     except (OSError, subprocess.SubprocessError) as e:
         log.info("native build skipped (%s); using Python fallbacks", e)
@@ -46,16 +58,6 @@ def _build() -> bool:
         except OSError:
             pass
         return False
-
-
-def _newest_src_mtime() -> float:
-    # a missing source (partial checkout) must not break get_lib's
-    # fallback contract — treat it as infinitely new so the build is
-    # attempted, fails, and callers fall back to Python
-    try:
-        return max(os.path.getmtime(s) for s in _SRC)
-    except OSError:
-        return float("inf")
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
@@ -68,17 +70,21 @@ def get_lib() -> Optional[ctypes.CDLL]:
         if _tried:
             return None
         _tried = True
-        stale = (not os.path.exists(_SO)
-                 or os.path.getmtime(_SO) < _newest_src_mtime())
+        try:
+            so = lib_path()
+        except OSError as e:
+            log.info("native sources unreadable (%s); using Python "
+                     "fallbacks", e)
+            return None
         # the first-use build is serialized on purpose: every caller
         # needs its result anyway, and the compile is bounded by the
         # subprocess timeout=120 (concurrent PROCESS builders are
         # already safe via the per-pid tmp + atomic replace)
         # lint: ok(lock-blocking) intentional bounded build under the init lock
-        if stale and not _build():
+        if not os.path.exists(so) and not _build(so):
             return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
         except OSError as e:
             log.info("native load failed (%s); using Python fallbacks", e)
             return None

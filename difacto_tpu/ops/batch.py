@@ -37,8 +37,7 @@ class DeviceBatch(NamedTuple):
     or the producer-thread np.unique), rewriting the O(nnz) index array
     once per batch. A device-side remap permutation used to carry this for
     the cached reader; it cost an unsorted u_cap-row permute + scatter-add
-    per step — more than the host gather it saved (docs/perf_notes.md,
-    round-5 "host dedup").
+    per step — more than the host gather it saved.
     """
     rows: jnp.ndarray      # int32[NNZ] row of each nonzero (pad: last real row)
     cols: jnp.ndarray      # int32[U-index] of each nonzero (pad: 0)
@@ -79,8 +78,8 @@ class PanelBatch(NamedTuple):
     # lane's token run is padded into fixed-L gather chunks; the per-token
     # sorted scatter (a serial ~10 ns/row update loop, half the fused step
     # at bench shapes) becomes a dense vectorised gather+reduce to per-chunk
-    # partials plus a scatter of only ~U + B*F/L rows (docs/perf_notes.md,
-    # round-4 "chunked backward"). Staged once per batch like sorted_*.
+    # partials plus a scatter of only ~U + B*F/L rows. Staged once per
+    # batch like sorted_*.
     chunk_idx: Optional[jnp.ndarray] = None   # i32[C, L] token row ids
     chunk_lane: Optional[jnp.ndarray] = None  # i32[C] ascending lanes
     chunk_vals: Optional[jnp.ndarray] = None  # f32[C, L] (None if binary)
@@ -281,7 +280,7 @@ def unpack_panel_raw(i32, f32, batch_cap: int, width: int,
 
 # Chunk length of the run-chunked backward layout. L=16 measured fastest at
 # bench shapes (L=8: more chunks to scatter; L=32/64: more gather padding
-# on the zipf run-length distribution — docs/perf_notes.md).
+# on the zipf run-length distribution).
 CHUNK_L = 16
 
 
@@ -436,7 +435,7 @@ def pack_batch(blk: RowBlock, num_uniq: int, slots: np.ndarray,
                counts: Optional[np.ndarray] = None):
     """Pack a localized block + slot vector into TWO host buffers
     (int32 + float32) so staging costs two device transfers instead of
-    eight — on tunneled/remote devices per-transfer latency dominates.
+    eight.
 
     Layout (static per bucket): i32 = [rows(nnz) | cols(nnz) | slots(u)];
     f32 = [vals(nnz)? | labels(B) | rweight(B) | row_mask(B) |

@@ -19,20 +19,20 @@ TABLE-FACING halves of that program behind a ``fused_kernel`` knob
   table row moves through HBM exactly twice per step (out on the pull,
   back on the push) with no composed-op round trips between. The
   update math is the SAME ``row_epilogue`` function the jnp path
-  scatters (traced into the kernel per tile), so the backends cannot
-  drift. Off-TPU the kernels run in Pallas interpret mode — that is
-  the parity harness, not a fast path (``make kernel-parity``).
+  scatters (traced into the kernel per tile). Off-TPU the kernels run
+  in Pallas interpret mode — the parity harness (``make
+  kernel-parity``). ON a TPU backend the knob is refused, typed
+  (:class:`PallasRefused`): Mosaic does not compile these kernels, see
+  ``_MOSAIC_REFUSAL``.
 - ``off`` — the pre-ISSUE-13 composed path (get_rows + apply_grad as
   separate gather/scatter programs, merged only by XLA CSE).
 
-History note (docs/perf_notes.md "Pallas resolution"): the round-3
-per-row-DMA scaffold was measured latency-bound and deleted — it moved
-BARE rows, so it competed with one XLA gather. This kernel revisits the
-design with the update folded into the scatter's epilogue (halving the
-table traffic the composed path pays) and R-row tiles whose DMAs issue
-before any wait; ``auto`` still resolves to ``jnp`` until a driver
-bench (BENCH_r*, the per-backend ``kernel`` block) shows the pallas
-path ahead on real hardware.
+History note: the round-3 per-row-DMA scaffold was measured
+latency-bound and deleted — it moved BARE rows, so it competed with one
+XLA gather. This kernel revisits the design with the update folded into
+the scatter's epilogue (halving the table traffic the composed path
+pays) and R-row tiles whose DMAs issue before any wait. ``auto``
+resolves to ``jnp``.
 
 On-device dedup (:func:`dedup_tokens`): the streamed producer's
 ``np.unique`` over the batch's O(nnz) hashed tokens is the dominant
@@ -63,20 +63,32 @@ _TILE_ROWS = 8
 
 _BACKENDS = ("auto", "pallas", "jnp", "off")
 
+# What Mosaic said when both kernels first went to the compiler (PR 21:
+# TPU v5 lite, jax/jaxlib 0.9.0, libtpu 0.0.34; gather_rows,
+# scatter_rows and fm_update_rows jitted with backend="pallas" over
+# [2^21, 256] bf16, [2^20, 256] f32 and [2^20, 256] int8 tables,
+# u_cap = 131072). The per-row DMA — the design itself — is refused at
+# every dtype on both sides of the copy (``tbl_ref.at[s]`` in HBM,
+# ``scratch.at[j]`` in VMEM), and the in-kernel epilogue is refused on
+# the packed dtypes at row_epilogue's shape-changing bitcast of the
+# scalar lanes.
+_MOSAIC_REFUSAL = (
+    "MosaicError: INTERNAL: Mosaic failed to compile TPU kernel: Slice "
+    "shape along dimension 0 must be aligned to tiling (8), but is 1. "
+    "[tpu.memref_slice of the table / the VMEM row tile to one row; "
+    "f32, bf16 and int8] | NotImplementedError: Changing bitwidths not "
+    "supported. [row_epilogue traced into the kernel; bf16 and int8]")
 
-def pallas_importable() -> bool:
-    try:
-        from jax.experimental import pallas  # noqa: F401
-        from jax.experimental.pallas import tpu  # noqa: F401
-    except ImportError:  # pragma: no cover - jax always bundles pallas
-        return False
-    return True
+
+class PallasRefused(ValueError):
+    """``fused_kernel=pallas`` on a TPU backend: the kernels do not
+    compile there, and nothing falls back to interpret mode."""
 
 
 def interpret_mode() -> bool:
     """Pallas kernels compile through Mosaic only on TPU backends;
-    everywhere else they run interpreted — bit-exact, slow, and only
-    meant for the parity tests."""
+    everywhere else they run interpreted — slow, and only meant for the
+    parity tests. Never true on a TPU backend."""
     return jax.default_backend() != "tpu"
 
 
@@ -90,10 +102,10 @@ def resolve_backend(knob: str, mesh=None, V_dim: int = 0) -> str:
     - ``pallas`` requires an unsharded table (a pallas_call is opaque
       to GSPMD: under fs-sharding it would force the table through a
       replicated intermediate, exactly what state_constrainer exists
-      to prevent) and fails typed rather than silently degrading;
-    - ``auto`` resolves to ``jnp`` — the measured-fastest backend
-      until a driver bench shows the pallas kernels ahead (module
-      docstring); it never picks pallas on its own.
+      to prevent) and a non-TPU backend (interpret mode; on a TPU
+      Mosaic refuses the kernels, :class:`PallasRefused`) — it fails
+      typed rather than silently degrading;
+    - ``auto`` resolves to ``jnp``; it never picks pallas on its own.
     """
     if knob not in _BACKENDS:
         raise ValueError(
@@ -109,27 +121,27 @@ def resolve_backend(knob: str, mesh=None, V_dim: int = 0) -> str:
                 "(mesh_fs/mesh_dp > 1 or mesh_force): pallas_call is "
                 "opaque to GSPMD partitioning — use fused_kernel=jnp "
                 "for mesh runs")
-        if not pallas_importable():
-            raise ValueError(
-                "fused_kernel=pallas but jax.experimental.pallas is "
-                "not importable in this jax build")
+        if not interpret_mode():
+            raise PallasRefused(
+                "fused_kernel=pallas does not compile on a TPU backend "
+                f"— Mosaic's words: {_MOSAIC_REFUSAL} Use "
+                "fused_kernel=jnp (what auto selects).")
         return _log_resolution(knob, "pallas",
-                               "interpret mode (parity harness)"
-                               if interpret_mode() else "TPU Mosaic")
+                               "interpret mode (parity harness)")
     if knob == "jnp":
         return _log_resolution(knob, "jnp", "explicit knob")
     return _log_resolution(
         knob, "jnp",
-        "auto never picks pallas (docs/perf_notes.md); "
+        "auto never picks pallas; "
         + ("mesh run — GSPMD partitions the jnp primitives"
-           if mesh is not None else "measured-fastest backend"))
+           if mesh is not None else "the fused single program"))
 
 
 def _log_resolution(knob: str, backend: str, reason: str) -> str:
     """One INFO line per resolution (i.e. once per learner/store —
     make_fns resolves once): ``auto`` silently landing on ``jnp`` under
-    a mesh confused the BENCH_r05->r06 comparison, so the resolved
-    backend and why are now in the run log."""
+    a mesh once confused a bench comparison, so the resolved backend
+    and why are in the run log."""
     log.info("fused_kernel: %s -> %s (%s)", knob, backend, reason)
     return backend
 
@@ -287,7 +299,7 @@ def _pallas_gather(table: jnp.ndarray, slots: jnp.ndarray) -> jnp.ndarray:
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(u // R,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((R, W), lambda i, s: (i, 0)),
         scratch_shapes=[pltpu.SemaphoreType.DMA((R,))],
     )
@@ -385,12 +397,12 @@ def _scatter_epilogue(table: jnp.ndarray, slots: jnp.ndarray,
     for e in extras:
         w_e = e.shape[1]
         in_specs.append(pl.BlockSpec((R, w_e), lambda i, s: (i, 0)))
-    in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))   # table
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))   # table
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(u // R,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.VMEM((R, W), table.dtype),
                         pltpu.SemaphoreType.DMA((R,))],
     )

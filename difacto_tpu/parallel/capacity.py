@@ -8,8 +8,8 @@ and the driver's ``__graft_entry__.dryrun_multichip`` leg — for each
 ``fs`` rung it builds a table of ``base_capacity * fs`` rows sharded
 over ``fs`` devices, runs the SAME fused train step the product
 dispatches (panel + chunked backward at dp=1), and reports throughput
-next to per-device table bytes, so MULTICHIP_r*.json carries a real
-scaling trajectory instead of a bare {rc, ok}.
+next to per-device table bytes — a real scaling trajectory instead of a
+bare {rc, ok}.
 
 ``scaling``: per-device bytes should stay ~flat while max trainable
 capacity grows linearly — ``capacity_scaling`` is exact by construction
@@ -191,7 +191,7 @@ def bounded_delay_report(hosts_values: Sequence[int] = (1, 2, 4),
     trainings on synthetic data through the windowed schedule at each
     τ, reporting ``auc_delta`` vs the τ=0 run — honest support for the
     τ-invariance claim (device steps stay collective-synchronous, so
-    the trajectory does not move with τ; see docs/perf_notes.md).
+    the trajectory does not move with τ).
     """
     import jax
     import numpy as np
@@ -207,7 +207,11 @@ def bounded_delay_report(hosts_values: Sequence[int] = (1, 2, 4),
                    state_sharding)
 
     n_dev = len(jax.devices())
-    fs = min(fs, n_dev)
+    if fs > n_dev:
+        raise ValueError(
+            f"bounded_delay_report: fs={fs} needs {fs} devices, this "
+            f"process sees {n_dev} — a smaller mesh would be a different "
+            "measurement under the same name")
     cap = base_capacity * fs
     rng = np.random.RandomState(seed)
     param = SGDUpdaterParam(V_dim=V_dim, V_threshold=0, lr=0.1,

@@ -33,6 +33,10 @@ from typing import Optional, Tuple
 
 log = logging.getLogger("difacto_tpu")
 
+# exit code of a rank that joined the rendezvous and then could not bind
+# a device (outside fault.exit_code_for's 101..127 dead-peer band)
+NO_DEVICE_EXIT_CODE = 3
+
 
 def initialize(coordinator_address: Optional[str] = None,
                num_processes: Optional[int] = None,
@@ -51,9 +55,22 @@ def initialize(coordinator_address: Optional[str] = None,
         coordinator_address=coordinator_address,
         num_processes=num_processes,
         process_id=process_id)
+    try:
+        n_devices = len(jax.devices())
+    except RuntimeError as e:
+        # rendezvous done, but this process cannot bind a device — on one
+        # TPU host, because a peer process holds the chip (one process
+        # per chip). The peers are now waiting for this process's
+        # devices, and a normal interpreter exit would wait for THEM in
+        # jax.distributed's shutdown barrier: nobody leaves. Leave hard
+        # and non-zero, so the launcher sees a dead rank and takes the
+        # job down (launch.py _run_once).
+        log.error("rank %s cannot bind a device after the rendezvous: %s",
+                  process_id, e)
+        logging.shutdown()
+        os._exit(NO_DEVICE_EXIT_CODE)
     log.info("multi-host initialized: process %d of %d, %d global devices",
-             jax.process_index(), jax.process_count(),
-             len(jax.devices()))
+             jax.process_index(), jax.process_count(), n_devices)
 
 
 def allgather_np(arr) -> "np.ndarray":

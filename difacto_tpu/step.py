@@ -12,7 +12,7 @@ dedup happens on the HOST (store.map_keys_dedup / the producer-thread
 np.unique), which rewrites the O(nnz) index array once per batch. The
 device-side remap permutation that used to carry this for the cached
 reader cost an unsorted u_cap-row permute + scatter-add per step — more
-than the host gather it saved (docs/perf_notes.md, round-5 "host dedup").
+than the host gather it saved.
 
 ``train_auc`` picks the per-step training metric: "binned" (default) is the
 O(B) histogram AUC — the sort-based exact AUC costs ~10 ms at 64k batches,

@@ -78,8 +78,8 @@ class SGDUpdaterParam(Param):
     # multiple of the 128-lane TPU tile width. Sub-lane-width rows make the
     # per-row table scatter a misaligned read-modify-write: at V_dim=16
     # over a 4.2M-row table, the [196k, 32] scatter measured 33 ms vs
-    # 15 ms for the padded [196k, 128] row — MORE bytes, half the time
-    # (docs/perf_notes.md). The pad costs up to 4x VVg HBM at V_dim<=32,
+    # 15 ms for the padded [196k, 128] row — MORE bytes, half the
+    # time. The pad costs up to 4x VVg HBM at V_dim<=32,
     # so it auto-disables when the padded table would exceed
     # ``pad_v_rows_max_mb`` (the donated-state double plus the batch
     # cache must still fit; an 8.4M-row V16 bf16 table OOMed a 16 GB
@@ -94,15 +94,14 @@ class SGDUpdaterParam(Param):
     # FTRL/AdaGrad epilogue scatters once — byte-identical
     # trajectories, guaranteed single gather); "pallas" = the same
     # dataflow as pl.pallas_call DMA kernels with the row update folded
-    # into the scatter's epilogue (TPU backends; interpret-mode parity
-    # elsewhere; unsharded tables only); "auto" = jnp until a driver
-    # bench shows the pallas kernels ahead (docs/perf_notes.md "Fused
-    # FM kernel").
+    # into the scatter's epilogue (unsharded tables, interpret mode
+    # off-TPU only: on a TPU backend Mosaic refuses the kernels and
+    # the knob raises ops/fused.PallasRefused); "auto" = jnp.
     fused_kernel: str = field(default="auto",
                               metadata=dict(enum=["auto", "pallas",
                                                   "jnp", "off"]))
-    # ---- table-capacity levers (difacto_tpu/capacity/; docs/perf_notes
-    # "Table capacity"). All default OFF: fp32 + admit-all + no tier is
+    # ---- table-capacity levers (difacto_tpu/capacity/). All default
+    # OFF: fp32 + admit-all + no tier is
     # byte-identical to the pre-capacity trajectory.
     # Storage dtype of the fused slot rows. "fp32" = full precision (the
     # container still follows the legacy V_dtype knob, so existing bf16
@@ -154,8 +153,7 @@ class SGDState(NamedTuple):
       instead of ~10 per-slot table ops; each op costs ~10-19 ns per
       ROW regardless of width, so merging ops is the lever (measured
       52.4 -> 37.4 ms for the u=262k V64 table-op train, 31.0 -> 21.0 ms
-      for u=196k V16 where the scalars ride the EXISTING pad lanes —
-      docs/perf_notes.md round-5 "fused scalar lanes").
+      for u=196k V16 where the scalars ride the EXISTING pad lanes).
 
     Reference analog: the SGDEntry record (src/sgd/sgd_updater.h:20-69)
     keeps w, z, sqrt_g and V[] contiguous per feature for the same
@@ -229,7 +227,7 @@ def fuse_vvg(V, Vg, h: int):
 # f32 spans 4/itemsize adjacent lanes, low bits first), which keeps XLA
 # on the row-major layout — per-lane extraction with uint shifts made
 # layout assignment prefer a TRANSPOSED gather and insert a full-table
-# copy of the donated state every step (docs/perf_notes.md). Lanes 5/6
+# copy of the donated state every step. Lanes 5/6
 # carry the per-row quantization scales of the V/Vg halves when
 # slot_dtype is int8/fp8 (ops/fused.quant_half); exact 0.0 otherwise —
 # bit-identical to the old spare-lane zeros.
@@ -251,8 +249,7 @@ def row_layout(param: SGDUpdaterParam, capacity: int
     load-bearing: a 192-lane row made XLA's entry-layout pass choose a
     TRANSPOSED {0,1} table layout (it avoids the 192->256 tile padding),
     which inserted two full-table transpose copies around every step's
-    gather/scatter — ~5.7 ms/step of pure copy at 2M rows
-    (docs/perf_notes.md round-5 "fused scalar lanes"). A tile-aligned
+    gather/scatter — ~5.7 ms/step of pure copy at 2M rows. A tile-aligned
     width costs the same HBM as the padded 192 and keeps {1,0}."""
     k = param.V_dim
     assert k > 0, "flat layout has no fused row"

@@ -13,12 +13,9 @@ harness. Here:
 from __future__ import annotations
 
 import contextlib
-import logging
 import time
 from collections import defaultdict
 from typing import Dict, Iterator
-
-log = logging.getLogger("difacto_tpu")
 
 
 class Timer:
@@ -44,22 +41,10 @@ class Timer:
             for name, tot in rows)
 
 
-@contextlib.contextmanager
-def device_trace(log_dir: str) -> Iterator[None]:
-    """Capture a device profile into ``log_dir`` (view with xprof/
-    TensorBoard). No-op shield: profiling failures never break training."""
+def device_trace(log_dir: str):
+    """Context manager capturing a device profile into ``log_dir`` (view
+    with xprof/TensorBoard) — jax's own, so a trace that was asked for
+    and cannot start or stop raises: a run that silently carries on
+    without its trace is a measurement nobody took."""
     import jax
-    started = False
-    try:
-        jax.profiler.start_trace(log_dir)
-        started = True
-    except Exception as e:  # pragma: no cover - backend-dependent
-        log.debug("device trace unavailable: %s", e)
-    try:
-        yield
-    finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception as e:  # pragma: no cover
-                log.debug("stop_trace failed: %s", e)
+    return jax.profiler.trace(log_dir)

@@ -14,11 +14,6 @@ import numpy as np
 
 from difacto_tpu.learners import Learner
 from tests.test_bcd import OBJV_DIAG_NEWTON
-import pytest  # noqa: F401  (guard mark below)
-
-from conftest import requires_shard_map
-
-pytestmark = requires_shard_map
 
 
 def run_sharded(rcv1_path, **over):

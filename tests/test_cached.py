@@ -1,8 +1,7 @@
 """Round-3 fast-path tests (round-3 verdict #2): the pre-localized rec
 cache (data/cached.py), its producer-thread collision dedup
 (learners/sgd.py _prepare_from_uniq — the uniq->slot gather that
-replaced the per-step device remap, docs/perf_notes.md round-5 "host
-dedup"), and the producer pool's failure path (data/producer_pool.py).
+replaced the per-step device remap), and the producer pool's failure path (data/producer_pool.py).
 
 The parity tests assert the cache reproduces the LIBSVM trajectory exactly
 (same hyperparameters, shuffle off): the cached path must be a faster

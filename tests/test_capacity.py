@@ -308,8 +308,6 @@ def test_quantized_trajectory_across_backends(rcv1_path, backends):
 
 
 def test_quantized_trajectory_pallas_interpret(rcv1_path):
-    if not fused.pallas_importable():  # pragma: no cover
-        pytest.skip("no pallas in this jax build")
     s0, t0 = _learner_run(rcv1_path, slot_dtype="int8",
                           fused_kernel="off")
     s2, t2 = _learner_run(rcv1_path, slot_dtype="int8",

@@ -391,3 +391,53 @@ def test_capacity_scaling_report_legs():
     assert rep["capacity_scaling"] == 2.0
     assert rep["scaling_efficiency"] == 1.0
     assert all(leg["examples_per_sec"] > 0 for leg in rep["legs"])
+
+
+def test_bounded_delay_report_refuses_a_mesh_it_cannot_build():
+    """fs beyond the visible devices used to be clamped silently — a
+    different measurement under the same name; now it raises."""
+    from difacto_tpu.parallel.capacity import bounded_delay_report
+    with pytest.raises(ValueError, match="fs=16 needs 16 devices"):
+        bounded_delay_report(fs=16)
+
+
+def test_mesh_store_is_built_sharded():
+    """The initial table comes out of one jitted program; under a mesh
+    it is pinned to the fs layout (no device ever holds the whole
+    table) with the same values as the unsharded init, which are the
+    eager init_state's."""
+    param = SGDUpdaterParam(V_dim=4, hash_capacity=1 << 10, seed=3)
+    flat = SlotStore(param)
+    sharded = SlotStore(param, mesh=make_mesh(dp=1, fs=4))
+    vvg = sharded.state.VVg
+    assert len({s.device for s in vvg.addressable_shards}) == 4
+    assert all(s.data.shape[0] == (1 << 10) // 4
+               for s in vvg.addressable_shards)
+    from difacto_tpu.updaters.sgd_updater import init_state
+    for eager, a, b in zip(init_state(param, 1 << 10), flat.state,
+                           sharded.state):
+        np.testing.assert_array_equal(np.asarray(eager), np.asarray(a))
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("fs", [1, 4])
+def test_tables_load_in_slabs_bit_identically(tmp_path, monkeypatch, fs):
+    """A checkpoint is assembled a slab of rows at a time into a
+    destination born sharded (the one-shot build of a 2^23-row table fit
+    on no chip) — the saved table's bits whatever the slab size, in the
+    store's layout, from a per-shard family into any mesh."""
+    from difacto_tpu.store import local
+    path = str(tmp_path / "model")
+    saved = _filled_store(make_mesh(dp=1, fs=4))
+    saved.save(path, save_aux=True)
+    mesh = make_mesh(dp=1, fs=fs) if fs > 1 else None
+    one_slab = SlotStore(saved.param, mesh=mesh)
+    layout = one_slab.state.VVg.sharding
+    one_slab.load(path)
+    monkeypatch.setattr(local, "_SLAB_ROWS", 512)   # 4 slabs
+    slabs = SlotStore(saved.param, mesh=mesh)
+    slabs.load(path)
+    for loaded in (one_slab, slabs):
+        assert loaded.state.VVg.sharding == layout
+        np.testing.assert_array_equal(np.asarray(loaded.state.VVg),
+                                      np.asarray(saved.state.VVg))

@@ -118,17 +118,26 @@ def test_trajectory_byte_identical_pallas_interpret():
     bodies Mosaic compiles) reproduce the off-path trajectory
     bit-for-bit: gather, in-kernel FTRL/AdaGrad epilogue, DMA
     scatter-back, OOB pad handling."""
-    if not fused.pallas_importable():  # pragma: no cover - jax bundles it
-        pytest.skip("no pallas in this jax build")
     o0, t0 = _run_steps("off", "bfloat16", steps=3)
     o2, t2 = _run_steps("pallas", "bfloat16", steps=3)
     assert o0 == o2
     np.testing.assert_array_equal(t0, t2)
 
 
+def test_pallas_is_refused_typed_on_a_tpu_backend(monkeypatch):
+    """Mosaic does not compile these kernels (ops/fused._MOSAIC_REFUSAL,
+    taken on the chip): on a TPU backend the knob must raise at
+    resolution, in the compiler's words — never crash mid-run, never
+    reach interpret mode."""
+    monkeypatch.setattr(fused.jax, "default_backend", lambda: "tpu")
+    assert not fused.interpret_mode()
+    with pytest.raises(fused.PallasRefused, match="Mosaic failed to "
+                                                  "compile TPU kernel"):
+        fused.resolve_backend("pallas", V_dim=8)
+    assert fused.resolve_backend("auto", V_dim=8) == "jnp"
+
+
 def test_pallas_gather_scatter_kernels_match_jnp():
-    if not fused.pallas_importable():  # pragma: no cover
-        pytest.skip("no pallas in this jax build")
     rng = np.random.RandomState(1)
     table = jnp.asarray(rng.randn(64, 16).astype(np.float32))
     slots = jnp.asarray(

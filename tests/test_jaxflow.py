@@ -374,7 +374,10 @@ def test_jaxtrace_gate_serve_steady_state(tmp_path, repo_model):
         assert rec["compiles"] == w["compiles"], \
             f"{site} recompiled in steady state: " \
             f"{w['compiles']} -> {rec['compiles']}"
-        assert rec["calls"] > w["calls"]
+        # ... while the serve program kept being called (a set-up
+        # program — the store's jitted init — runs once, before warm-up)
+        if site in serve_site:
+            assert rec["calls"] > w["calls"]
     for site, rec in sorted(final["fetches"].items()):
         assert site in declared, \
             f"device->host transfer at undeclared site {site} " \

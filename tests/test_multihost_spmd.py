@@ -163,3 +163,24 @@ def test_two_process_mesh_panel_path(tmp_path):
     ln.add_epoch_end_callback(lambda e, t, v: seen.append(t.loss))
     ln.run()
     np.testing.assert_allclose(trajs[0]["train"], seen, rtol=2e-4)
+
+
+def test_rank_that_cannot_bind_a_device_takes_the_job_down(rcv1_path):
+    """One process per chip: on a single TPU host the second local rank
+    finds the chip taken. Stood in for here by a rank whose platform has
+    no device — it must leave at once and non-zero so the launcher kills
+    its peer, instead of both waiting on each other in jax.distributed
+    (the peer for this rank's devices, this rank in the shutdown
+    barrier) until the 2-minute topology timeout."""
+    from difacto_tpu.parallel.multihost import NO_DEVICE_EXIT_CODE
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(REPO))
+    worker = ('if [ "$DIFACTO_RANK" = 1 ]; then export JAX_PLATFORMS=tpu; '
+              f'fi; exec {sys.executable} -m difacto_tpu task=train '
+              f'data_in={rcv1_path} hash_capacity=4096 batch_size=100 '
+              'max_num_epochs=1 mesh_dp=2 mesh_fs=1')
+    proc = subprocess.run(
+        [sys.executable, str(REPO / "launch.py"), "-n", "2",
+         "--port", "7953", "--", "sh", "-c", worker],
+        cwd=str(REPO), env=env, capture_output=True, text=True, timeout=60)
+    assert proc.returncode == NO_DEVICE_EXIT_CODE, proc.stderr[-2000:]
+    assert "cannot bind a device" in proc.stderr

@@ -188,3 +188,36 @@ def test_adfea_native_matches_python():
     tabbed = chunk.replace(b" ", b"\t")
     a, b = parse_adfea(tabbed), parse_adfea_native(tabbed)
     np.testing.assert_array_equal(a.offset, b.offset)
+
+
+@needs_native
+def test_library_is_keyed_on_source_bytes(tmp_path, monkeypatch):
+    """A library built from other sources must never be loaded: the
+    file name carries a hash of the three .cc files (mtimes prove
+    nothing — a copied tree resets them)."""
+    import os
+    import shutil
+
+    from difacto_tpu import native
+
+    srcs = []
+    for s in native._SRC:
+        srcs.append(str(tmp_path / os.path.basename(s)))
+        shutil.copy(s, srcs[-1])
+    assert native.lib_path(srcs) == native.lib_path()
+    with open(srcs[1], "ab") as f:
+        f.write(b"\n")
+    assert native.lib_path(srcs) != native.lib_path()
+
+    # a stale library under the pre-hash name is not even looked at
+    stale = os.path.join(os.path.dirname(native.lib_path()),
+                         "_difacto_native.so")
+    with open(stale, "wb") as f:
+        f.write(b"not a shared object")
+    try:
+        monkeypatch.setattr(native, "_lib", None)
+        monkeypatch.setattr(native, "_tried", False)
+        lib = native.get_lib()
+        assert lib is not None and lib._name == native.lib_path()
+    finally:
+        os.unlink(stale)

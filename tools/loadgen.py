@@ -106,8 +106,8 @@ def make_picker(n: int, zipf_alpha: float, seed: int = 0):
     round-robin (every row equally hot — the historical behavior);
     ``zipf_alpha > 0`` draws ranks from a Zipf law ``p(r) ~ 1/r^alpha``
     over the row set, the skewed key popularity real traffic has and
-    the shape the cold tier's hit-rate depends on (docs/perf_notes.md
-    "Table capacity"; bench.py --capacity sweeps two alphas). Seeded
+    the shape the cold tier's hit-rate depends on (bench.py --capacity
+    sweeps two alphas). Seeded
     and independent of the arrival-schedule RNG, so turning skew on
     never perturbs the offered-rate schedule."""
     if zipf_alpha <= 0.0 or n <= 1:

@@ -164,7 +164,7 @@ def report_capacity(snap: dict) -> None:
     eviction and the per-shard occupancy gauges in one block, plus the
     derived tier hit-rate — the first read when judging whether
     ``cold_tier_rows`` / ``admit_min_count`` are sized right for the
-    key skew (docs/perf_notes.md "Table capacity")."""
+    key skew."""
     counters = snap.get("counters", {})
     gauges = snap.get("gauges", {})
 

@@ -128,7 +128,7 @@ def main() -> None:
 
         # -------------------------------------------------- streamed e2e
         # both producer transports, so the thread-vs-process overlap is a
-        # measured table (docs/perf_notes.md "The streamed regime"), each
+        # measured table, each
         # with the learner's pack/transfer/step second totals attached
         def streamed_run(mode: str) -> dict:
             ln = make_learner(0, producer_mode=mode)

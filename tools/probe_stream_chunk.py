@@ -1,7 +1,7 @@
 """Probe: should STREAMED (cache-less) panel training chunk on device?
 
 Staged runs build the chunked-run backward layout once at staging time
-and replay it (docs/perf_notes.md "the chunked backward"). Streamed runs
+and replay it. Streamed runs
 currently dispatch the unsorted-scatter backward — the round-4 note
 ("a per-batch per-epoch argsort would eat the win") was measured for the
 HOST-side sort in the old sorted-backward era. This probe times one mode

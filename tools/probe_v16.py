@@ -1,7 +1,7 @@
 """Probe: the V16 step's width-independent ~38 ms floor (verdict weak #4).
 
 The V64 and V16 steps cost the same wall clock even though V16 moves ~4x
-fewer bytes. perf_notes attributes the residue to the forward tail: 39
+fewer bytes. The round-5 trace put the residue in the forward tail: 39
 per-column gathers of the combined [w | V] token rows. At V16 those rows
 are 17 bf16 elements = 34 bytes — well under the 128-lane tile, so every
 gather row is a misaligned read (the same pathology pad_v_rows fixed for
