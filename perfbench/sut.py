@@ -1,0 +1,439 @@
+"""The system under test, as the benchmark drives it.
+
+The only module of the benchmark that touches ``difacto_tpu``. It takes
+from the program the entry that ``python -m difacto_tpu task=train`` runs
+(``place_compile_cache``, ``Learner.create("sgd")``, ``init``, ``run``), the
+epoch-end callback, the obs registry's ``stage_seconds_total``, the rec
+member writer, and the accessors of the table's row layout. It plants
+nothing in the program.
+
+Two things the program offers no public way to do, and how each is done
+here (listed in PERF.md under Open questions for a program PR to replace
+by an interface):
+
+- *Seeing single steps.* The learner's ``_dispatch_item(job_type, item,
+  push_cnt, want_counts, job, dim_min, pending, ...)`` consumes one
+  streamed batch, on one chip or on a mesh: it runs one step and appends
+  ``(nrows, objv, auc)`` to ``pending``. For the first ``N_STEPS`` steps
+  of epoch 0 the instance's attribute is wrapped so that the step's loss
+  and a few sums over the touched table rows are kept; then the wrapper
+  is taken off, and the window runs the method as it is.
+- *Knowing that the pair-replay program is compiled.* It compiles on a
+  thread named ``pair-exec-compile`` and replay switches to it when it is
+  ready. The window opens only once no such thread runs and
+  ``_pair_execs`` holds no pending entry.
+- *Seeing the program that the window times.* A replay window runs one
+  compiled executable only, two cached batches a call, kept in
+  ``_pair_execs``. Once it is ready its entry is wrapped for its next
+  call, the first pair of the warm epoch: the touched rows are read
+  before and after that one call of the executable itself, then the
+  entry is put back, and the window calls the same object bare.
+"""
+
+from __future__ import annotations
+
+import gc
+import os
+import threading
+import time
+
+import numpy as np
+
+N_STEPS = 3          # steps of epoch 0 that the reference follows
+HUGE_EPOCHS = 1_000_000
+
+
+# ---------------------------------------------------------------- device
+def bind(chips: int) -> dict:
+    """Place the compile cache as the program's entry does, bind the
+    backend, and refuse anything but a TPU with at least ``chips``."""
+    from difacto_tpu.utils.device import place_compile_cache
+    cache_dir = place_compile_cache()
+    import jax
+    # programs that compile in under a second are kept too: set-up is
+    # then the same work in every run after the first
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    backend = jax.default_backend()
+    devs = jax.devices()
+    if backend != "tpu":
+        raise SystemExit(
+            f"perfbench: no TPU: jax.default_backend() is {backend!r}; "
+            "the benchmark measures the accelerator and does not fall "
+            "back to the CPU")
+    if len(devs) < chips:
+        raise SystemExit(
+            f"perfbench: the cell needs {chips} chips, JAX has "
+            f"{len(devs)}")
+    return describe(cache_dir)
+
+
+def describe(cache_dir=None) -> dict:
+    import jax
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs), "host_cores": os.cpu_count(),
+            "compile_cache_dir": cache_dir}
+
+
+def memory_peak_bytes() -> int:
+    """Peak bytes in use on the fullest device, 0 where the backend does
+    not say."""
+    import jax
+    peak = 0
+    for d in jax.devices():
+        stats = d.memory_stats() or {}
+        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
+    return peak
+
+
+class Compiles:
+    """Seconds JAX spent in backend compiles (or in cache retrievals that
+    stand for them), and the cache's hits and misses, from
+    ``jax.monitoring`` (copied from ``chip_smoke._Compiles``)."""
+
+    def __init__(self) -> None:
+        import jax.monitoring as mon
+        self.seconds = 0.0
+        self.count = self.hits = self.misses = 0
+        mon.register_event_duration_secs_listener(self._duration)
+        mon.register_event_listener(self._event)
+
+    def _duration(self, event: str, secs: float, **_kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.seconds += secs
+            self.count += 1
+
+    def _event(self, event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+
+# ------------------------------------------------------------------ data
+def write_member(data_dir: str, m: int, label, uniq, index, width: int
+                 ) -> int:
+    """One pre-localized rec member, as ``task=convert`` writes them,
+    through the program's own writer. Returns its bytes."""
+    from difacto_tpu.data.rec import write_rec_block
+    from difacto_tpu.data.rowblock import RowBlock
+    rows = len(label)
+    blk = RowBlock(offset=np.arange(rows + 1, dtype=np.int64) * width,
+                   label=label, index=index, value=None)
+    path = os.path.join(data_dir, f"part-{m:05d}.rec2")
+    write_rec_block(path, blk, uniq=uniq)
+    return os.path.getsize(path)
+
+
+# ----------------------------------------------------------------- probe
+class ProbeDone(Exception):
+    """Raised out of the run after the compared steps, where only the
+    comparison's numbers are wanted (``calibrate.py``)."""
+
+
+class Probe:
+    """What the comparison reads of the program: sums over the table rows
+    that the first steps touch, after each of them, and those rows whole
+    before and after the first call of the pair-replay executable. All of
+    it is read from the learner's own state."""
+
+    LEAVES = ("w", "z", "sg", "cnt", "live", "V", "Vg")
+
+    def __init__(self, learner, rows: np.ndarray, stop_after: str = ""):
+        # ``stop_after``: "first" or "pair" ends the run (ProbeDone) once
+        # that part is read, for ``calibrate.py``
+        self.stop_after = stop_after
+        import jax
+        import jax.numpy as jnp
+        from difacto_tpu.updaters.sgd_updater import (quantized,
+                                                      row_layout,
+                                                      scal_f32)
+        self.learner = learner
+        param = learner.store.param
+        if param.V_dim <= 0:
+            raise ValueError("the probe reads fused rows: V_dim > 0")
+        capacity = learner.store.state.capacity
+        k, h, _, off = row_layout(param, capacity)
+
+        def leaves(VVg, r):
+            got = VVg[r]
+            f = scal_f32(got[:, off:])
+            if quantized(param):
+                from difacto_tpu.ops import fused
+                V = fused.dequant_half(got[:, :k], f[:, 5],
+                                       param.slot_dtype)
+                Vg = fused.dequant_half(got[:, h:h + k], f[:, 6],
+                                        param.slot_dtype)
+            else:
+                V = got[:, :k].astype(jnp.float32)
+                Vg = got[:, h:h + k].astype(jnp.float32)
+            return f[:, 0], f[:, 1], f[:, 2], f[:, 3], f[:, 4] > 0, V, Vg
+
+        def sums(VVg, r, V0):
+            w, _, sg, _, live, V, Vg = leaves(VVg, r)
+            d = V - V0
+            return jnp.stack([
+                jnp.sum(w * w), jnp.sum(sg * sg), jnp.sum(Vg * Vg),
+                jnp.sum(d * d), jnp.sum((w != 0).astype(jnp.float32)),
+                jnp.sum(live.astype(jnp.float32))])
+
+        self._rows = self._replicated(np.asarray(rows, np.int32))
+        self._sums = jax.jit(sums)
+        self._leaves = jax.jit(leaves)
+        self.emb = None     # host (V, Vg) of the touched rows, at the end
+        self._V0 = jax.jit(lambda VVg, r: leaves(VVg, r)[5])(
+            learner.store.state.VVg, self._rows)
+        self.loss = []      # device scalars, one a step
+        self.sums = []      # device f32[6], one a step
+        self.pair = None    # the pair executable's one watched call
+        self._armed = {}    # key -> the executable, while wrapped
+        self._orig = learner._dispatch_item
+        learner._dispatch_item = self._spy
+
+    def _replicated(self, x):
+        import jax.numpy as jnp
+        mesh = self.learner.mesh
+        if mesh is None:
+            return jnp.asarray(x)
+        from difacto_tpu.parallel import put_global, replicated
+        return put_global(x, replicated(mesh))
+
+    def _host_leaves(self, VVg) -> dict:
+        """The touched rows as the table holds them now, on the host at
+        once: nothing of the probe stays on the device."""
+        return dict(zip(self.LEAVES, (np.asarray(x) for x in
+                                      self._leaves(VVg, self._rows))))
+
+    def _spy(self, *args, **kw):
+        pending = kw["pending"] if "pending" in kw else args[6]
+        before = len(pending)
+        self._orig(*args, **kw)
+        if len(pending) != before + 1:
+            raise RuntimeError("a streamed batch of epoch 0 ran "
+                               f"{len(pending) - before} steps, not one")
+        self.loss.append(pending[-1][1])
+        self.sums.append(self._sums(self.learner.store.state.VVg,
+                                    self._rows, self._V0))
+        if len(self.loss) >= N_STEPS:
+            got = self._host_leaves(self.learner.store.state.VVg)
+            self.emb = (got["V"], got["Vg"])
+            self.release()
+            if self.stop_after == "first":
+                raise ProbeDone()
+
+    def release(self) -> None:
+        """Take the wrapper off; drop what only it needed."""
+        if self._orig is not None:
+            del self.learner._dispatch_item     # back to the class's
+            self._orig = None
+            self._V0 = None
+
+    def arm_pair(self) -> None:
+        """Wrap every ready pair-replay executable for its next call."""
+        if self.pair is not None:
+            return
+        execs = getattr(self.learner, "_pair_execs", {})
+        for key, ex in list(execs.items()):
+            if ex is None or isinstance(ex, Exception):
+                continue
+            self._armed[key] = ex
+            execs[key] = self._pair_spy(ex)
+        if self.stop_after == "pair" and not self._armed:
+            raise RuntimeError("the learner holds no pair-replay "
+                               "executable to read")
+
+    def disarm_pair(self) -> None:
+        execs = getattr(self.learner, "_pair_execs", {})
+        for key, ex in self._armed.items():
+            if key in execs:
+                execs[key] = ex
+        self._armed = {}
+
+    def _pair_spy(self, ex):
+        def spy(state, pa, pb):
+            before = self._host_leaves(state.VVg)
+            out = ex(state, pa, pb)
+            self.pair = {"loss": [float(out[1]), float(out[3])],
+                         "before": before,
+                         "after": self._host_leaves(out[0].VVg)}
+            self.disarm_pair()
+            if self.stop_after == "pair":
+                # the run ends here: the learner takes the new state
+                self.learner.store.state = out[0]
+                raise ProbeDone()
+            return out
+        return spy
+
+    def numbers(self) -> dict:
+        """The program's side of the comparison, as floats and host
+        arrays."""
+        if len(self.loss) < N_STEPS:
+            raise RuntimeError(
+                f"the program ran {len(self.loss)} steps through "
+                f"_dispatch_item in epoch 0, {N_STEPS} are compared")
+        s = np.asarray([np.asarray(x, np.float64) for x in self.sums])
+        return {
+            "loss": [float(x) for x in self.loss[:N_STEPS]],
+            "grad": {"w": float(np.sqrt(s[0][1])),
+                     "V": float(np.sqrt(s[1][2]))},
+            "change": {"w": float(np.sqrt(s[N_STEPS - 1][0])),
+                       "V": float(np.sqrt(s[N_STEPS - 1][3]))},
+            "V": self.emb[0], "Vg": self.emb[1],
+            "Vg_after_step1": float(np.sqrt(s[0][2])),
+            "nnz_w": int(s[N_STEPS - 1][4]),
+            "live": int(s[N_STEPS - 1][5]),
+            "pair": self.pair,
+        }
+
+
+# ------------------------------------------------------------------- run
+def learner_kwargs(config: dict, traffic: dict, data_dir: str, seed: int,
+                   override: dict = None) -> dict:
+    kw = dict(config)
+    kw.update(traffic.get("learner", {}))
+    kw.update(override or {})
+    kw.update(data_in=data_dir, max_num_epochs=HUGE_EPOCHS,
+              # the table's own seed: any whole number, as PRNGKey takes it
+              seed=int(seed) % (1 << 31))
+    return kw
+
+
+def _pair_compile_pending(learner) -> bool:
+    if any(t.name == "pair-exec-compile" and t.is_alive()
+           for t in threading.enumerate()):
+        return True
+    return any(v is None for v in getattr(learner, "_pair_execs",
+                                          {}).values())
+
+
+OPEN_MARK, CLOSE_MARK = "perfbench_window_open", "perfbench_window_close"
+
+
+def _mark(name: str) -> None:
+    """An event of the host's in the running trace: where the window
+    opens and closes on the trace's own clock."""
+    import jax
+    with jax.profiler.TraceAnnotation(name):
+        time.sleep(0.0002)
+
+
+def _stage_seconds(learner) -> dict:
+    snap = learner.obs.snapshot()
+    series = snap.get("counters", {}).get("stage_seconds_total", {})
+    return {dict(k).get("stage", ""): float(v) for k, v in series.items()}
+
+
+def drive(kwargs: dict, probe_rows, seconds: float, trace_dir: str = None,
+          stop_after: str = "") -> dict:
+    """Build the learner, run it as the entry does, and keep the marks.
+
+    The window opens at the first epoch-end mark after the warm-up (epoch
+    0 streams, stages and compiles; then whole epochs until one has run
+    with every program compiled) and closes at the first epoch-end mark
+    at or after ``seconds``. With ``trace_dir`` the profiler runs over
+    exactly the window, and two marks in the trace give its span there.
+    ``stop_after`` ("first", "pair") ends the run once the probe has
+    read that part: ``calibrate.py``'s readings need no window."""
+    import jax
+    from difacto_tpu.learners import Learner
+
+    learner = Learner.create("sgd")
+    # The table's seed is a constant of the program that draws the table,
+    # so a seed the cache has seen would load what a fresh seed compiles.
+    # Nothing that compiles in ``init`` is written to the cache: every
+    # run compiles it, and set-up is the same work for any seed.
+    knob = "jax_persistent_cache_min_compile_time_secs"
+    kept = getattr(jax.config, knob)
+    jax.config.update(knob, 1e9)
+    t_init = time.perf_counter()
+    try:
+        remain = learner.init([(k, str(v)) for k, v in kwargs.items()])
+    finally:
+        jax.config.update(knob, kept)
+    if remain:
+        raise ValueError(f"keys the learner does not know: {remain}")
+    probe = (Probe(learner, probe_rows, stop_after=stop_after)
+             if probe_rows is not None else None)
+    init_s = time.perf_counter() - t_init    # table init and the probe
+
+    marks = []          # (epoch, time, rows, loss) at each epoch's end
+    win = {"ready_at": None}
+
+    def on_epoch_end(k, train_prog, _val):
+        now = time.perf_counter()
+        marks.append((k, now, float(train_prog.nrows),
+                      float(train_prog.loss)))
+        if "open" not in win:
+            ready = win["ready_at"]
+            if ready is not None and k > ready:
+                win["open"] = len(marks) - 1
+                win["stages_open"] = _stage_seconds(learner)
+                if trace_dir is not None:
+                    jax.profiler.start_trace(trace_dir)
+                    _mark(OPEN_MARK)
+                win["t_open"] = time.perf_counter()
+            elif ready is None and not _pair_compile_pending(learner):
+                win["ready_at"] = k
+                if probe is not None:
+                    probe.arm_pair()    # read in the epoch that follows
+        elif "close" not in win and now - win["t_open"] >= seconds:
+            win["close"] = len(marks) - 1
+            win["t_close"] = now
+            win["stages_close"] = _stage_seconds(learner)
+            if trace_dir is not None:
+                _mark(CLOSE_MARK)
+                jax.profiler.stop_trace()
+            learner.param.max_num_epochs = k + 1
+
+    learner.add_epoch_end_callback(on_epoch_end)
+    t0 = time.perf_counter()
+    try:
+        learner.run()
+    except ProbeDone:
+        learner.stop()
+        numbers = probe.numbers()
+        learner.store.state = None
+        learner = probe = None
+        gc.collect()
+        return {"probe": numbers}
+    finally:
+        if probe is not None:
+            probe.release()
+            probe.disarm_pair()
+    if "close" not in win:
+        raise RuntimeError("the run ended before the window closed")
+
+    o, c = win["open"], win["close"]
+    rows = sum(m[2] for m in marks[o + 1:c + 1])
+    out = {
+        "t_open": win["t_open"],
+        # the open mark's own clock reading lies before the trace starts;
+        # the rate runs from the moment the window is open
+        "window_s": win["t_close"] - win["t_open"],
+        "window_rows": rows,
+        "window_rows_by_epoch": [m[2] for m in marks[o + 1:c + 1]],
+        "window_epochs": c - o,
+        "warm_epochs": o,
+        "init_s": init_s,
+        "epoch0_s": marks[0][1] - t0,
+        "warm_s": win["t_open"] - marks[0][1],
+        "stages": {k: win["stages_close"].get(k, 0.0)
+                   - win["stages_open"].get(k, 0.0)
+                   for k in win["stages_close"]},
+        "producer_mode": getattr(learner, "_last_producer_mode", "?"),
+        "paired_dispatches": getattr(learner, "_paired_dispatches", 0),
+        "device_cache": learner.device_cache_info(),
+        "table_rows": int(learner.store.state.capacity),
+        "table_bytes": int(learner.store.state.VVg.nbytes),
+        "probe": probe.numbers() if probe is not None else None,
+    }
+    out["memory_peak_bytes"] = memory_peak_bytes()
+    # free the program's state before the reference runs
+    learner.epoch_end_callbacks.clear()
+    learner.store.state = None
+    getattr(learner, "_dev_caches", {}).clear()
+    getattr(learner, "_pair_execs", {}).clear()
+    del learner, probe
+    gc.collect()
+    return out

@@ -1,0 +1,73 @@
+"""A tiny copy of the benchmark for the CPU tests: the harness's own files
+with the table, the batch and the epoch cut down, in a directory of the
+test's. Not a test file."""
+
+import json
+import os
+import shutil
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+# a cell on a mesh replays no pairs: the first steps' numbers alone
+FIRST_LIMITS = dict({n: 1e-5 for n in (
+    "loss1", "loss2", "loss3", "grad_w", "grad_V", "change_w", "change_V",
+    "keep_V", "round_V", "round_Vg")}, epoch_rows=0)
+TINY_LIMITS = dict(FIRST_LIMITS, **{n: 1e-5 for n in (
+    "pair_loss1", "pair_loss2", "pair_change_w", "pair_change_V",
+    "pair_round_V")})
+
+
+def bench() -> dict:
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def make_root(tmp: str, config: str = "fm_v64_criteo", batch: int = 64,
+              steps: int = 8, capacity: int = 4096, limits=None,
+              **cfg) -> str:
+    """``tmp/perfbench`` = the benchmark's files, with ``config`` cut to
+    a ``capacity``-row float32 table and every traffic mix to ``steps``
+    steps of ``batch`` rows over a few hundred tokens."""
+    bdir = os.path.join(tmp, "perfbench")
+    shutil.copytree(os.path.join(ROOT, "perfbench"), bdir,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    path = os.path.join(bdir, "configs", config + ".json")
+    with open(path) as f:
+        c = json.load(f)
+    c.update(V_dim=8, V_dtype="float32", hash_capacity=capacity,
+             batch_size=batch)
+    c.update(cfg)
+    with open(path, "w") as f:
+        json.dump(c, f)
+    for name in os.listdir(os.path.join(bdir, "traffic")):
+        tpath = os.path.join(bdir, "traffic", name)
+        with open(tpath) as f:
+            t = json.load(f)
+        t["rows_per_epoch"] = batch * steps
+        t["generator"].update(int_tokens=20, cat_tokens=300)
+        t["trace_seconds"] = 0.3
+        t["learner"]["producer_mode"] = "thread"
+        if t["learner"].get("device_cache_mb"):
+            t["learner"]["device_cache_mb"] = 64
+        with open(tpath, "w") as f:
+            json.dump(t, f)
+    os.makedirs(os.path.join(bdir, "limits"), exist_ok=True)
+    for w in bench()["workloads"]:
+        with open(os.path.join(bdir, "limits", w["name"] + ".json"),
+                  "w") as f:
+            json.dump(limits or TINY_LIMITS, f)
+    return tmp
+
+
+def run(tmp: str, workload: str = "fm_v64_criteo.replay", seed: int = 5,
+        seconds: float = 0.0, trace: bool = False, override=None):
+    from perfbench import run as R
+    lines = {}
+    res = R.run_cell(bench(), tmp, workload, seed, seconds, trace,
+                     require_tpu=False, override=override,
+                     out=lambda k, v: lines.__setitem__(k, v))
+    return res, lines
