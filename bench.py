@@ -1274,8 +1274,8 @@ def main() -> None:
 
     import contextlib
 
-    from difacto_tpu.utils.profiling import device_trace
-    trace = (device_trace(args.profile) if args.profile
+    # jax's own: a trace that was asked for and cannot start raises
+    trace = (jax.profiler.trace(args.profile) if args.profile
              else contextlib.nullcontext())
     with trace:
         t0 = time.perf_counter()

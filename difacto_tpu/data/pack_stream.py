@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-import time
 from dataclasses import dataclass, field
 from typing import Iterator, Optional, Sequence
 
@@ -297,19 +296,18 @@ class StreamSpec:
     trace_id: int = 0
 
 
-def timed_reader(it: Iterator, parse_c, part: int) -> Iterator:
+def timed_reader(it: Iterator, registry, part: int) -> Iterator:
     """Yield from ``it`` accounting each blocking ``next`` to the PARSE
-    stage (a counter of seconds + one trace span per batch) — the read +
+    stage of ``registry`` (obs.stage: seconds into the counter + one
+    ``producer.parse`` span per batch, same start and end) — the read +
     parse half of the pipeline, as opposed to the pack half timed at the
     prepare call. One definition for threads and worker processes, so
     bench's stage table means the same thing in both transports."""
-    from ..obs import trace
+    from ..obs import names, stage
     it = iter(it)
     while True:
-        t0 = time.perf_counter()
-        with trace.span("producer.parse", part=part):
+        with stage(registry, names.PARSE, part=part):
             item = next(it, None)
-        parse_c.inc(time.perf_counter() - t0)
         if item is None:
             return
         yield item
@@ -326,13 +324,9 @@ def spec_iter(spec: StreamSpec, part_i: int) -> Iterator:
     (stage_seconds_total{stage=parse|pack}, producer rows/batches); the
     pool ships its snapshot back to the consumer (obs/proc.py), which is
     how the stage decomposition survives the process boundary."""
-    from ..obs import REGISTRY, trace
+    from ..obs import REGISTRY, names, stage, trace
     if spec.trace_id:
         trace.set_trace_id(spec.trace_id)
-    stage = REGISTRY.counter(
-        "stage_seconds_total",
-        "seconds spent per streamed-pipeline stage, summed over threads")
-    parse_c, pack_c = stage.labels(stage="parse"), stage.labels(stage="pack")
     rows_c = REGISTRY.counter("producer_rows_total",
                               "rows produced by the streamed pipeline")
     batches_c = REGISTRY.counter("producer_batches_total",
@@ -348,11 +342,8 @@ def spec_iter(spec: StreamSpec, part_i: int) -> Iterator:
                        label=blk.label if spec.need_label else None)
 
     def packed(fn, *args, **kw):
-        t0 = time.perf_counter()
-        with trace.span("producer.pack", part=part):
-            out = fn(*args, **kw)
-        pack_c.inc(time.perf_counter() - t0)
-        return out
+        with stage(REGISTRY, names.PACK, part=part):
+            return fn(*args, **kw)
 
     if spec.cached_uri is not None:
         from .cached import CachedBatchReader
@@ -362,7 +353,7 @@ def spec_iter(spec: StreamSpec, part_i: int) -> Iterator:
             neg_sampling=spec.neg_sampling,
             seed=spec.epoch * max(g_num, 1) + g_idx,
             need_counts=spec.fill_counts)
-        for sub, uniq, cnts in timed_reader(rdr, parse_c, part):
+        for sub, uniq, cnts in timed_reader(rdr, REGISTRY, part):
             rows_c.inc(sub.size)
             batches_c.inc()
             yield ("ready", info(sub), packed(
@@ -378,7 +369,7 @@ def spec_iter(spec: StreamSpec, part_i: int) -> Iterator:
                          spec.batch_size, spec.batch_size * spec.shuffle,
                          spec.neg_sampling,
                          seed=spec.epoch * max(g_num, 1) + g_idx)
-    for blk in timed_reader(reader, parse_c, part):
+    for blk in timed_reader(reader, REGISTRY, part):
         rows_c.inc(blk.size)
         batches_c.inc()
         yield ("ready", info(blk), packed(

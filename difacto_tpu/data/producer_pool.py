@@ -220,11 +220,7 @@ def _pp_worker_main(worker_id: int, make_iter_bytes: bytes, ring_desc,
 
     from .shm_ring import ShmRing, SlotOverflow, _align, encode_item
     make_iter = pickle.loads(make_iter_bytes)
-    from ..obs import REGISTRY, proc, trace
-    ring_wait_c = REGISTRY.counter(
-        "stage_seconds_total",
-        "seconds spent per streamed-pipeline stage, summed over threads"
-    ).labels(stage="ring_wait")
+    from ..obs import REGISTRY, names, proc, stage, trace
     ring_wait_h = REGISTRY.histogram(
         "ring_slot_wait_seconds",
         "producer wait for a free shm-ring slot (the backpressure point)")
@@ -262,19 +258,16 @@ def _pp_worker_main(worker_id: int, make_iter_bytes: bytes, ring_desc,
                     return sum(_align(a.nbytes) for a in arrays) + 4096
 
                 def lease_slot(seq):
-                    t_wait = time.perf_counter()
                     s = None
-                    with trace.span("producer.ring_wait", part=part,
-                                    seq=seq):
+                    with stage(REGISTRY, names.RING_WAIT, part=part,
+                               seq=seq) as wait:
                         while not stop_ev.is_set():  # backpressure point
                             try:
                                 s = free_q.get(timeout=0.1)
                                 break
                             except queue.Empty:
                                 continue
-                    wait_dt = time.perf_counter() - t_wait
-                    ring_wait_c.inc(wait_dt)
-                    ring_wait_h.observe(wait_dt)
+                    ring_wait_h.observe(wait.seconds)
                     return s
 
                 def send_single(seq, it_, dt, span, slot=None) -> bool:

@@ -20,6 +20,7 @@ stopping, model load/save, epoch-end callbacks, progress rows.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 import os
@@ -35,6 +36,7 @@ import numpy as np
 from ..config import KWArgs, Param
 from ..data import BatchReader, Reader, compact
 from ..losses import create as create_loss
+from ..obs import names, stage, trace
 from ..ops.batch import bucket, pad_batch
 from ..store.local import SlotStore
 from ..updaters.sgd_updater import SGDUpdaterParam
@@ -409,28 +411,35 @@ class SGDLearner(Learner):
         # learners in one process — bench's replay + streamed windows —
         # must not blur together); producer worker processes report into
         # it through the pool's snapshot channel (obs/proc.py). The
-        # streamed-epoch stage decomposition lives in
-        # stage_seconds_total{stage}:
-        #   parse    = read+parse half of the producer pipeline
-        #   pack     = localize/slot-map/pack half
-        #   ring_wait= producer blocked on a free shm-ring slot
-        #   transfer = host->device staging of packed buffers
-        #   step     = step dispatch + the metric-fetch waits where
-        #              device time surfaces
+        # stage decomposition lives in stage_seconds_total{stage}, every
+        # stage timed by obs.stage (a span and its counter at ONE
+        # boundary; the names and their meaning: obs/names.py):
+        #   parse, pack, ring_wait, transfer   the streamed pipeline
+        #   dispatch   = host time to enqueue one step program
+        #   fetch_wait = blocked in the metric fetch, where device time
+        #                surfaces
+        #   step       = dispatch + fetch_wait (the same boundaries)
+        #   epoch_turn = an epoch's final fetch returned -> the next
+        #                epoch's first enqueue
+        #   compile    = backend-compile seconds (jax.monitoring)
         # bench.py's e2e.streamed.stages is stage_stats() over this
         # registry — no private timers.
-        from ..obs import Registry
+        from ..obs import Registry, watch_compiles
+        from ..obs.stage import stage_counter
         self.obs = Registry()
-        stage_c = self.obs.counter(
-            "stage_seconds_total",
-            "seconds spent per streamed-pipeline stage, summed over "
-            "threads")
-        self._stage_c = {k: stage_c.labels(stage=k)
-                         for k in ("parse", "pack", "ring_wait",
-                                   "transfer", "step")}
+        for name in names.STAGES:
+            stage_counter(self.obs, name)     # every series exists at 0
+        watch_compiles(self.obs)
         self._step_h = self.obs.histogram(
             "train_step_seconds",
-            "host-side dispatch+wait time of one fused device step")
+            "host-side enqueue time of one fused device step (a paired "
+            "dispatch counts half its time twice)")
+        # where the run stands, for the spans' args; the open
+        # ``epoch_turn`` stage (begun at an epoch's final fetch, ended
+        # by the next enqueue or by stop())
+        self._epoch = 0
+        self._step_num = 0
+        self._turn = None
         self._rows_c = self.obs.counter(
             "train_rows_total", "examples consumed by dispatched steps")
         self._gather_c = self.obs.counter(
@@ -833,7 +842,11 @@ class SGDLearner(Learner):
             # (the reference merges these from server Evaluate reports,
             # sgd_updater.cc:15-32); printed here, unconditionally, so an
             # all-zero model (nnz 0) is visible rather than suppressed
-            train_prog.penalty, train_prog.nnz_w = self._take_eval_scalars()
+            # (these are children of the open epoch_turn stage, so that
+            # a device-idle gap at the epoch boundary names its cause)
+            with trace.span(names.TURN_EVAL, epoch=k):
+                train_prog.penalty, train_prog.nnz_w = \
+                    self._take_eval_scalars()
             log.info("epoch[%d] training: %s, nnz(w) = %g, penalty = %g",
                      k, train_prog.text(), train_prog.nnz_w,
                      train_prog.penalty)
@@ -841,7 +854,8 @@ class SGDLearner(Learner):
             # occupancy-pressure eviction (ISSUE 19, evict_occupancy):
             # epoch boundary only — one full-table column read, and the
             # dispatch queue is drained so demotes cannot race a step
-            n_evicted = self.store.maybe_evict()
+            with trace.span(names.TURN_EVICT, epoch=k):
+                n_evicted = self.store.maybe_evict()
             if n_evicted:
                 log.info("epoch[%d] evicted %d rows under occupancy "
                          "pressure", k, n_evicted)
@@ -851,8 +865,10 @@ class SGDLearner(Learner):
                 self._run_epoch(k, K_VALIDATION, val_prog)
                 log.info("epoch[%d] validation: %s", k, val_prog.text())
 
-            for cb in self.epoch_end_callbacks:
-                cb(k, train_prog, val_prog)
+            # the callers' own work, told apart from the program's
+            with trace.span(names.TURN_CALLBACKS, epoch=k):
+                for cb in self.epoch_end_callbacks:
+                    cb(k, train_prog, val_prog)
 
             if p.ckpt_interval > 0 and p.model_out \
                     and (k + 1) % p.ckpt_interval == 0:
@@ -896,6 +912,7 @@ class SGDLearner(Learner):
         self.stop()
 
     def stop(self) -> None:
+        self._end_turn()
         if self._fo_pred is not None:
             self._fo_pred.close()
             self._fo_pred = None
@@ -1059,9 +1076,51 @@ class SGDLearner(Learner):
         return None
 
     def _run_epoch(self, epoch: int, job_type: int, prog: Progress) -> None:
-        from ..obs import trace
-        with trace.span("epoch", epoch=epoch, job=job_type):
+        self._epoch, self._step_num = epoch, 0
+        with trace.span(names.EPOCH, epoch=epoch, job=job_type):
             self._run_epoch_body(epoch, job_type, prog)
+
+    # ---------------------------------------------------------- tracing
+    def _begin_turn(self) -> None:
+        """Open the ``epoch_turn`` stage: a pass's final fetch has
+        returned, so the device is idle until the next enqueue."""
+        self._end_turn()
+        self._turn = stage(self.obs, names.EPOCH_TURN,
+                           epoch=self._epoch).begin()
+
+    def _end_turn(self) -> None:
+        turn, self._turn = self._turn, None
+        if turn is not None:
+            turn.end()
+
+    @contextlib.contextmanager
+    def _enqueue(self, job_type: int, u_cap: int, n_steps: int = 1):
+        """The one prologue and accounting of EVERY step-program enqueue
+        (single, paired replay, mesh, SPMD): traverse the ``step.device``
+        chaos point (step.py), count the table row traffic of
+        ``n_steps`` steps (u_cap fused rows pulled, and pushed again
+        when training — updaters.gather_bytes; the serve path counts
+        its own under path="serve"), close an open ``epoch_turn``, and
+        run the body under the ``dispatch`` stage (its seconds also land
+        in ``step``) with one ``train_step_seconds`` observation a
+        step."""
+        from ..step import fire_step_fault
+        from ..updaters.sgd_updater import gather_bytes
+        fire_step_fault()
+        per_dir = gather_bytes(self.store.param, self.store.state.capacity,
+                               u_cap)
+        self._gather_c.inc(
+            per_dir * n_steps * (2 if job_type == K_TRAINING else 1))
+        self._end_turn()
+        st = stage(self.obs, names.DISPATCH, also=(names.STEP,),
+                   epoch=self._epoch, step_num=self._step_num)
+        try:
+            with st:
+                yield
+        finally:
+            for _ in range(n_steps):
+                self._step_h.observe(st.seconds / n_steps)
+            self._step_num += n_steps
 
     def _run_epoch_body(self, epoch: int, job_type: int,
                         prog: Progress) -> None:
@@ -1570,32 +1629,22 @@ class SGDLearner(Learner):
                 # so store-state mutations stay ordered with the steps
                 self.store.state = self._apply_count(
                     self.store.state, slots_dev, cts_dev)
-            from ..step import fire_step_fault
-            fire_step_fault()
-            # table row traffic of this synchronized step (PR 12
-            # leftover: the SPMD drain path never counted it): the
-            # replicated global slot union is pulled once — and pushed
-            # once when training — at the fused-row width
-            # (updaters.gather_bytes; docs/observability.md)
-            from ..updaters.sgd_updater import gather_bytes
-            per_dir = gather_bytes(self.store.param,
-                                   self.store.state.capacity,
-                                   slots_dev.shape[0])
-            self._gather_c.inc(
-                per_dir * (2 if job_type == K_TRAINING else 1))
-            if job_type == K_TRAINING:
-                self.store.state, objv, auc = self._train_step(
-                    self.store.state, batch, slots_dev)
-            else:
-                pred, objv, auc = self._eval_step(self.store.state, batch,
-                                                  slots_dev)
-                if job_type == K_PREDICTION and p.pred_out and \
-                        cblk is not None:
-                    # pred is dp-sharded; this host's rows are its own block
-                    from ..parallel.multihost import local_rows
-                    lo = self._host_rank * b_cap
-                    self._save_pred(
-                        local_rows(pred, lo, lo + cblk.size), cblk.label)
+            # the replicated global slot union is pulled once — and
+            # pushed once when training — at the fused-row width
+            with self._enqueue(job_type, slots_dev.shape[0]):
+                if job_type == K_TRAINING:
+                    self.store.state, objv, auc = self._train_step(
+                        self.store.state, batch, slots_dev)
+                else:
+                    pred, objv, auc = self._eval_step(
+                        self.store.state, batch, slots_dev)
+            if job_type == K_PREDICTION and p.pred_out and \
+                    cblk is not None:
+                # pred is dp-sharded; this host's rows are its own block
+                from ..parallel.multihost import local_rows
+                lo = self._host_rank * b_cap
+                self._save_pred(
+                    local_rows(pred, lo, lo + cblk.size), cblk.label)
             if cache is not None and cache.staging:
                 # stage the global (batch, slots) pair: replayed epochs
                 # rerun the identical synchronized step schedule on every
@@ -1626,7 +1675,6 @@ class SGDLearner(Learner):
         # contain cross-host collectives — keep the dead-host watchdog armed
         # (a peer dying after the final allgather but before its queued
         # steps complete would otherwise hang this fetch forever)
-        import contextlib
         drain_guard = (self.monitor.collective() if self.monitor is not None
                        else contextlib.nullcontext())
         with drain_guard:
@@ -1637,10 +1685,11 @@ class SGDLearner(Learner):
             # since round 5; the SPMD drain predates it and never did —
             # found by the jax-host-sync pass, difacto-lint v4)
             if pending:
-                vals = jaxtrace.fetch(
-                    jnp.stack([s for _, o, a in pending
-                               for s in (o, a)]),
-                    point="sgd.spmd_metrics")
+                flat = jnp.stack([s for _, o, a in pending
+                                  for s in (o, a)])
+                with stage(self.obs, names.FETCH_WAIT, also=(names.STEP,),
+                           epoch=self._epoch, step_num=self._step_num):
+                    vals = jaxtrace.fetch(flat, point="sgd.spmd_metrics")
                 for i, (nrows, _o, _a) in enumerate(pending):
                     prog.merge(Progress(nrows=nrows,
                                         loss=float(vals[2 * i]),
@@ -1729,25 +1778,38 @@ class SGDLearner(Learner):
         return uri if self._cache_probe[uri] else None
 
     def _merge_pending(self, pending: list, prog: Progress,
-                       extra=()) -> list:
+                       extra=(), final: bool = False) -> list:
         """Fetch all dispatched metric scalars in ONE transfer and merge —
         JAX async dispatch supplies the pipeline overlap. ``extra`` device
         scalars ride the same fetch (their values are returned): one RTT
-        instead of two for the epoch-end store.evaluate()."""
+        instead of two for the epoch-end store.evaluate(). ``final``: the
+        pass's last fetch — its return opens the ``epoch_turn`` stage,
+        and the merges run as its child ``epoch.merge``."""
         extra = list(extra)
         if not pending and not extra:
+            if final:
+                self._begin_turn()
             return []
-        flat = jnp.stack([s for _, o, a in pending for s in (o, a)]
-                         + extra)
-        t0 = time.perf_counter()
+        # (eager: one tiny program a scalar, each an enqueue of its own —
+        # with the device's queue full the host is still enqueueing
+        # them when the device runs dry, which the span shows)
+        with trace.span(names.MERGE_STACK, epoch=self._epoch,
+                        n=2 * len(pending) + len(extra)):
+            flat = jnp.stack([s for _, o, a in pending for s in (o, a)]
+                             + extra)
         # the declared sync point where device time lands (jaxtrace
         # counts it under DIFACTO_JAXTRACE)
-        vals = jaxtrace.fetch(flat, point="sgd.metrics")
-        self._add_stage("step_s", time.perf_counter() - t0)
-        for i, (nrows, _, _) in enumerate(pending):
-            self._rows_c.inc(nrows)
-            prog.merge(Progress(nrows=nrows, loss=float(vals[2 * i]),
-                                auc=float(vals[2 * i + 1])))
+        with stage(self.obs, names.FETCH_WAIT, also=(names.STEP,),
+                   epoch=self._epoch, step_num=self._step_num):
+            vals = jaxtrace.fetch(flat, point="sgd.metrics")
+        if final:
+            self._begin_turn()
+        with (trace.span(names.TURN_MERGE, epoch=self._epoch) if final
+              else contextlib.nullcontext()):
+            for i, (nrows, _, _) in enumerate(pending):
+                self._rows_c.inc(nrows)
+                prog.merge(Progress(nrows=nrows, loss=float(vals[2 * i]),
+                                    auc=float(vals[2 * i + 1])))
         return [float(v) for v in vals[2 * len(pending):]]
 
     @staticmethod
@@ -1821,14 +1883,10 @@ class SGDLearner(Learner):
         return out
 
     # ------------------------------------------------ streamed pipeline
+    # the streamed-pipeline stages that stage_stats() reports, in its
+    # legacy "<stage>_s" form (bench.py and tests/test_obs.py read them)
     _STAGE_KEYS = ("parse_s", "pack_s", "ring_wait_s", "transfer_s",
                    "step_s")
-
-    def _add_stage(self, key: str, dt: float) -> None:
-        # key is the legacy "<stage>_s" form; the value lands in the
-        # registry counter stage_seconds_total{stage} (per-thread cells,
-        # so producer threads report without contention)
-        self._stage_c[key[:-2]].inc(dt)
 
     def stage_stats(self) -> dict:
         """Streamed-epoch stage decomposition accumulated over the run —
@@ -1967,9 +2025,14 @@ class SGDLearner(Learner):
 
         def build():
             try:
-                lowered = self._packed_panel_train_chunked2.lower(
-                    state_s, pa, pa, b_cap, width, u_cap, False, binary)
-                self._pair_execs[key] = lowered.compile()
+                # its backend-compile seconds reach
+                # stage_seconds_total{stage=compile} through the
+                # process-wide listener (obs.watch_compiles)
+                with trace.span(names.COMPILE_PAIR, u_cap=u_cap):
+                    lowered = self._packed_panel_train_chunked2.lower(
+                        state_s, pa, pa, b_cap, width, u_cap, False,
+                        binary)
+                    self._pair_execs[key] = lowered.compile()
             except Exception as e:
                 # handed to the dispatch thread: the next replay of this
                 # shape raises it (_replay_cached) — a program the run
@@ -1988,7 +2051,6 @@ class SGDLearner(Learner):
         (same counts, same epoch-seeded permutation), so the synchronized
         step schedule holds with no DCN handshakes; the dead-host
         watchdog stays armed for the collective-bearing steps."""
-        import contextlib
         p = self.param
         is_train = job_type == K_TRAINING
         guard = (self.monitor.collective() if self.monitor is not None
@@ -2009,17 +2071,23 @@ class SGDLearner(Learner):
                 held = None
 
         def dispatch_pair(a, b, exec_):
+            # the same prologue and accounting as a single step
+            # (_enqueue), for two steps: this is the path a steady
+            # replay window runs
             pa = (a[1], a[2], a[3], a[4], a[5])
             pb = (b[1], b[2], b[3], b[4], b[5])
-            self.store.state, o1, a1, o2, a2 = exec_(
-                self.store.state, pa, pb)
+            with self._enqueue(job_type, a[8], n_steps=2):
+                self.store.state, o1, a1, o2, a2 = exec_(
+                    self.store.state, pa, pb)
             pending.append((a[11], o1, a1))
             pending.append((b[11], o2, a2))
             self._paired_dispatches = getattr(
                 self, "_paired_dispatches", 0) + 1
+        with trace.span(names.TURN_ITER_PARTS, epoch=epoch):
+            order = list(cache.iter_parts(is_train and p.shuffle > 0,
+                                          seed=epoch))
         with guard:
-            for part, payload in cache.iter_parts(
-                    is_train and p.shuffle > 0, seed=epoch):
+            for part, payload in order:
                 if reports and part != cur_part:
                     cur_part = part
                     if self._row_due(job_type):
@@ -2079,7 +2147,7 @@ class SGDLearner(Learner):
         (penalty, nnz) scalars on the same transfer (run() reads them via
         _take_eval_scalars) — one RTT instead of two per epoch."""
         extra = self.store.evaluate_dev() if job_type == K_TRAINING else ()
-        vals = self._merge_pending(pending, prog, extra=extra)
+        vals = self._merge_pending(pending, prog, extra=extra, final=True)
         if extra:
             self._eval_scalars = (vals[0], vals[1])
 
@@ -2202,18 +2270,14 @@ class SGDLearner(Learner):
                         and not push_cnt and not tier_on)
 
         from ..data.pack_stream import timed_reader
-        from ..obs import trace
-        parse_c, pack_c = self._stage_c["parse"], self._stage_c["pack"]
 
         def packed(part, fn, *args, **kw):
             # pack-stage accounting (the thread-mode twin of
-            # pack_stream.spec_iter's instrumentation): one counter inc
-            # + one trace span per prepared batch, on the producer thread
-            t0 = time.perf_counter()
-            with trace.span("producer.pack", part=part):
-                out = fn(*args, **kw)
-            pack_c.inc(time.perf_counter() - t0)
-            return out
+            # pack_stream.spec_iter's instrumentation): one obs.stage —
+            # counter + ``producer.pack`` span — per prepared batch, on
+            # the producer thread
+            with stage(self.obs, names.PACK, part=part):
+                return fn(*args, **kw)
 
         def make_iter(part):
             # EVERYTHING host-side happens on producer threads so it
@@ -2231,7 +2295,7 @@ class SGDLearner(Learner):
                     neg_sampling=p.neg_sampling if is_train else 1.0,
                     seed=epoch * max(g_num, 1) + g_idx,
                     need_counts=push_cnt)
-                for sub, uniq, cnts in timed_reader(rdr, parse_c, part):
+                for sub, uniq, cnts in timed_reader(rdr, self.obs, part):
                     if hashed_fast:
                         yield ("ready", sub, packed(
                             part, self._prepare_from_uniq, sub, uniq,
@@ -2251,7 +2315,7 @@ class SGDLearner(Learner):
                 self.store.param.admit_min_count,
                 self.store.param.seed, epoch, g_idx) if is_train else None
             reader = self._make_reader(job_type, epoch, g_idx, g_num)
-            for blk in timed_reader(reader, parse_c, part):
+            for blk in timed_reader(reader, self.obs, part):
                 if hashed_fast:
                     yield ("ready", blk, packed(
                         part, self._prepare_hashed, blk, want_counts,
@@ -2352,20 +2416,12 @@ class SGDLearner(Learner):
         def dispatch_entry(entry) -> None:
             e_part, e_item, e_lease, e_span = entry
             n_before = len(pending)
-            if trace.active():
-                # consumer-side span pointing at the exact producer span
-                # that packed this batch (the id rode the ring slot
-                # header across the process boundary)
-                # step_num makes this a StepTraceAnnotation under
-                # DIFACTO_TRACE_DEVICE: the profiler's per-step device
-                # timeline aligns with the part cadence
-                with trace.span("consumer.dispatch", part=e_part,
-                                step_num=e_part,
-                                producer_span=e_span):
-                    self._dispatch_item(job_type, e_item, push_cnt,
-                                        want_counts, job, dim_min,
-                                        pending, cache=cache, part=e_part)
-            else:
+            # consumer-side span pointing at the exact producer span
+            # that packed this batch (the id rode the ring slot header
+            # across the process boundary); the step's own ``dispatch``
+            # stage nests inside it
+            with trace.span(names.CONSUMER_DISPATCH, part=e_part,
+                            producer_span=e_span):
                 self._dispatch_item(job_type, e_item, push_cnt,
                                     want_counts, job, dim_min, pending,
                                     cache=cache, part=e_part)
@@ -2428,28 +2484,12 @@ class SGDLearner(Learner):
         """Run the fused step on an already-staged packed batch. ``payload``
         = (layout, i32_dev, f32_dev, b_cap, dim2, u_cap, want_counts,
         binary, nrows); dim2 is the panel width or the COO nnz_cap.
-        Traverses the ``step.device`` chaos injection point (step.py)
-        and accounts the dispatch into stage_seconds_total{stage=step}
-        + the train_step_seconds histogram."""
-        from ..step import fire_step_fault
-        fire_step_fault()
-        # table row traffic of this dispatch: u_cap fused rows pulled,
-        # and pushed again when training (updaters.gather_bytes; the
-        # serve path counts its own under path="serve")
-        from ..updaters.sgd_updater import gather_bytes
+        Prologue and accounting: :meth:`_enqueue`."""
         u_cap = (payload[2].shape[0] if payload[0] == "devbatch"
                  else payload[8] if payload[0] == "panel_chunked"
                  else payload[5])
-        per_dir = gather_bytes(self.store.param, self.store.state.capacity,
-                               u_cap)
-        self._gather_c.inc(per_dir * (2 if job_type == K_TRAINING else 1))
-        t0 = time.perf_counter()
-        try:
+        with self._enqueue(job_type, u_cap):
             self._dispatch_packed_inner(job_type, payload, pending, label)
-        finally:
-            dt = time.perf_counter() - t0
-            self._stage_c["step"].inc(dt)
-            self._step_h.observe(dt)
 
     def _dispatch_packed_inner(self, job_type: int, payload, pending: list,
                                label=None) -> None:
@@ -2583,16 +2623,13 @@ class SGDLearner(Learner):
             c[:len(cnts)] = cnts
             self.store.state = self._apply_count(
                 self.store.state, slots, jnp.asarray(c))
-        from ..updaters.sgd_updater import gather_bytes
-        per_dir = gather_bytes(self.store.param,
-                               self.store.state.capacity, u_cap)
-        self._gather_c.inc(per_dir * (2 if job_type == K_TRAINING else 1))
-        if job_type == K_TRAINING:
-            self.store.state, objv, auc = self._train_step(
-                self.store.state, dev, slots)
-        else:
-            pred, objv, auc = self._eval_step(self.store.state, dev,
-                                              slots)
+        with self._enqueue(job_type, u_cap):
+            if job_type == K_TRAINING:
+                self.store.state, objv, auc = self._train_step(
+                    self.store.state, dev, slots)
+            else:
+                pred, objv, auc = self._eval_step(self.store.state, dev,
+                                                  slots)
         if cache is not None and cache.staging:
             cache.add(part, ("devbatch", dev, slots, blk.size),
                       self._payload_nbytes((dev, slots)),
@@ -2646,22 +2683,22 @@ class SGDLearner(Learner):
         on, the payload's logical slots become device hot rows here —
         promotes/demotes ride this same dispatch thread, between the
         previous step's enqueue and this batch's H2D copies."""
-        t0 = time.perf_counter()
-        if self.store.tier is not None and payload[0] in ("panel", "coo"):
-            from ..capacity.tier import route_payload
-            payload = route_payload(self.store.tier, payload)
-        if payload[0] == "panel_chunked":
-            (_, i32, f32, (ci, cl, cv), binary, b_cap, d2, u_cap) = payload
-            out = ("panel_chunked", jnp.asarray(i32), jnp.asarray(f32),
-                   (jnp.asarray(ci), jnp.asarray(cl),
-                    None if cv is None else jnp.asarray(cv)),
-                   binary, b_cap, d2, u_cap)
-        else:
+        with stage(self.obs, names.TRANSFER, epoch=self._epoch):
+            if self.store.tier is not None \
+                    and payload[0] in ("panel", "coo"):
+                from ..capacity.tier import route_payload
+                payload = route_payload(self.store.tier, payload)
+            if payload[0] == "panel_chunked":
+                (_, i32, f32, (ci, cl, cv), binary, b_cap, d2,
+                 u_cap) = payload
+                return ("panel_chunked", jnp.asarray(i32),
+                        jnp.asarray(f32),
+                        (jnp.asarray(ci), jnp.asarray(cl),
+                         None if cv is None else jnp.asarray(cv)),
+                        binary, b_cap, d2, u_cap)
             layout, i32, f32, binary, b_cap, d2, u_cap = payload
-            out = (layout, jnp.asarray(i32), jnp.asarray(f32), binary,
-                   b_cap, d2, u_cap)
-        self._add_stage("transfer_s", time.perf_counter() - t0)
-        return out
+            return (layout, jnp.asarray(i32), jnp.asarray(f32), binary,
+                    b_cap, d2, u_cap)
 
     def _dispatch_prepared(self, job_type: int, blk, payload,
                            push_cnt: bool, want_counts: bool,
@@ -2684,23 +2721,22 @@ class SGDLearner(Learner):
             self._wal_step += 1
             self._wal_lo = self._wal_step
             return
-        t0 = time.perf_counter()
-        if payload[0] == "panel_chunked":
-            # producer-side chunked layout (stream_chunks): the host
-            # sort already ran on the producer thread, so both
-            # streamed dispatch AND cache staging use these chunks
-            (_, i32, f32, (ci_np, cl_np, cv_np), binary, b_cap, d2,
-             u_cap) = payload
-            layout = "panel"
-            i32, f32 = jnp.asarray(i32), jnp.asarray(f32)
-            ci, cl = jnp.asarray(ci_np), jnp.asarray(cl_np)
-            cv = None if cv_np is None else jnp.asarray(cv_np)
-            chunked = True
-        else:
-            layout, i32, f32, binary, b_cap, d2, u_cap = payload
-            i32, f32 = jnp.asarray(i32), jnp.asarray(f32)
-            chunked = False
-        self._add_stage("transfer_s", time.perf_counter() - t0)
+        with stage(self.obs, names.TRANSFER, epoch=self._epoch):
+            if payload[0] == "panel_chunked":
+                # producer-side chunked layout (stream_chunks): the host
+                # sort already ran on the producer thread, so both
+                # streamed dispatch AND cache staging use these chunks
+                (_, i32, f32, (ci_np, cl_np, cv_np), binary, b_cap, d2,
+                 u_cap) = payload
+                layout = "panel"
+                i32, f32 = jnp.asarray(i32), jnp.asarray(f32)
+                ci, cl = jnp.asarray(ci_np), jnp.asarray(cl_np)
+                cv = None if cv_np is None else jnp.asarray(cv_np)
+                chunked = True
+            else:
+                layout, i32, f32, binary, b_cap, d2, u_cap = payload
+                i32, f32 = jnp.asarray(i32), jnp.asarray(f32)
+                chunked = False
         wc = want_counts if is_train else False
         staging = (cache is not None and cache.staging
                    and layout == "panel" and is_train)

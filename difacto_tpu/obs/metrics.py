@@ -3,8 +3,9 @@
 The observability spine every component reports through (ISSUE 4). Before
 this module each subsystem kept its own ad-hoc channel — ``#stats`` dicts
 in serve, ``_stage_acc`` dicts in the SGD learner, ``Timer`` strings in
-utils/profiling.py — none of which composed, crossed the producer process
-boundary, or exported anywhere. The registry gives them one vocabulary:
+the since-removed utils/profiling.py — none of which composed, crossed
+the producer process boundary, or exported anywhere. The registry gives
+them one vocabulary:
 
 - :class:`Counter` — monotonically increasing, labeled
   (``counter("x_total").labels(stage="pack").inc(dt)``);
@@ -276,6 +277,9 @@ class Registry:
         self._mu = mutex()
         self._metrics: Dict[str, _Metric] = {}
         self._children: Dict[object, dict] = {}
+        # obs.stage's resolved stage_seconds_total{stage} series, by
+        # stage: the lookup sits on per-batch paths (obs/stage.py)
+        self._stage_series: Dict[str, object] = {}
 
     # -------------------------------------------------------- factories
     def _get(self, cls: type, name: str, help: str, **kw):

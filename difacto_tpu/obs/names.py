@@ -1,0 +1,102 @@
+"""The names of the tracing system, fixed in one place.
+
+Three vocabularies, each read by somebody outside the program
+(docs/observability.md, PERF.md section 3, ``perfbench/spans.py``), so a
+rename here is a change to what a metric reads:
+
+- **legs** — ``jax.named_scope`` names inside the step program. They are
+  HLO metadata (``op_name`` paths), so they reach the profiler's device
+  timeline (an ``XLA Ops`` event's ``tf_op``) and change no arithmetic.
+  XLA fuses across scopes; a fused operation belongs to the leg of its
+  fusion's root. Each scope also sets the frontend attribute
+  ``leg=<name>`` on its operations: metadata is stripped from the
+  persistent compile cache's key, so without it a program compiled
+  before a scope was added or moved would be served from the cache with
+  its OLD ``op_name`` paths and the trace would attribute by them. The
+  attribute is part of the program text, so the key follows the scopes.
+- **stages** — the ``stage`` labels of ``stage_seconds_total``; every one
+  is produced by :func:`difacto_tpu.obs.stage`, so each also has a span
+  of the same name with the same start and end.
+- **spans** — host spans that have no counter (children of a stage, or
+  boundaries nobody sums).
+"""
+
+from __future__ import annotations
+
+import contextlib
+import functools
+
+# ------------------------------------------------------------------ legs
+UNPACK = "unpack"        # unpack_panel/unpack_batch, dedup_tokens
+GATHER = "gather"        # the fused-row gather of the batch's slots
+FORWARD = "forward"      # rows_to_params, predict, objective, AUC
+BACKWARD = "backward"    # calc_grad
+UPDATE = "update"        # row_epilogue / FTRL on the gathered rows
+SCATTER = "scatter"      # the write-back of the updated rows
+EVALUATE = "evaluate"    # the epoch-end full-table penalty/nnz
+
+LEGS = (UNPACK, GATHER, FORWARD, BACKWARD, UPDATE, SCATTER, EVALUATE)
+
+# ---------------------------------------------------------------- stages
+STAGE_METRIC = "stage_seconds_total"
+STAGE_HELP = ("seconds spent per pipeline stage, summed over threads "
+              "(one obs.stage boundary each: a span of the same name "
+              "has the same start and end)")
+
+PARSE = "parse"              # read+parse half of the producer pipeline
+PACK = "pack"                # localize/slot-map/pack half
+RING_WAIT = "ring_wait"      # producer blocked on a free shm-ring slot
+TRANSFER = "transfer"        # host->device staging of packed buffers
+DISPATCH = "dispatch"        # host time to enqueue one step program
+FETCH_WAIT = "fetch_wait"    # blocked in the metric fetch
+STEP = "step"                # dispatch + fetch_wait, by construction
+EPOCH_TURN = "epoch_turn"    # final fetch's return -> next first enqueue
+COMPILE = "compile"          # backend-compile seconds (jax.monitoring)
+
+STAGES = (PARSE, PACK, RING_WAIT, TRANSFER, DISPATCH, FETCH_WAIT, STEP,
+          EPOCH_TURN, COMPILE)
+
+# spans under which a stage's seconds are recorded, where the span's name
+# predates the stage's and dashboards know it
+STAGE_SPAN = {PARSE: "producer.parse", PACK: "producer.pack",
+              RING_WAIT: "producer.ring_wait"}
+
+# ----------------------------------------------------------------- spans
+EPOCH = "epoch"
+CONSUMER_DISPATCH = "consumer.dispatch"
+MERGE_STACK = "merge.stack"        # the eager stack before a fetch
+TURN_MERGE = "epoch.merge"
+TURN_EVAL = "epoch.eval_scalars"
+TURN_EVICT = "epoch.evict_check"
+TURN_CALLBACKS = "epoch.callbacks"
+TURN_ITER_PARTS = "replay.iter_parts"
+COMPILE_PAIR = "compile.pair_exec"
+
+# the children of ``epoch_turn``: idle time under one of them is idle
+# time of the turn even where the turn's own span is cut by the start or
+# the stop of a profiler session
+TURN_CHILDREN = (TURN_MERGE, TURN_EVAL, TURN_EVICT, TURN_CALLBACKS,
+                 TURN_ITER_PARTS)
+
+
+@contextlib.contextmanager
+def scope(name: str):
+    """Trace the body under leg ``name``: ``jax.named_scope(name)`` plus
+    the frontend attribute ``leg=name`` (see the module's docstring).
+    The one seam every leg goes through (a test stubs it to prove the
+    scopes change no arithmetic)."""
+    import jax
+    from jax.experimental.xla_metadata import set_xla_metadata
+    with jax.named_scope(name), set_xla_metadata(leg=name):
+        yield
+
+
+def leg(name: str):
+    """Decorator: trace the function's body under leg ``name``."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kw):
+            with scope(name):
+                return fn(*args, **kw)
+        return scoped
+    return deco

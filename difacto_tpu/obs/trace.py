@@ -1,11 +1,18 @@
-"""Nestable trace spans emitting Chrome trace-event JSON (Perfetto).
+"""Nestable trace spans: Chrome trace-event JSON (Perfetto) and the
+profiler's own timeline.
 
-The timing half of the obs subsystem (metrics.py is the counting half):
-``with span("consumer.step", part=3):`` records one complete ("X") event
-with microsecond timestamps. Events carry ``pid``/``tid``, so a file
-holding events from the parent AND its producer worker processes renders
-as one timeline in Perfetto / chrome://tracing — worker parse -> pack ->
-ring wait -> consumer unpack -> device step, side by side.
+The timing half of the obs subsystem (metrics.py is the counting half;
+:func:`difacto_tpu.obs.stage` joins the two at one boundary):
+``with span("consumer.step", part=3):`` times its body once and hands
+that one interval to two sinks.
+
+**The span file** (``DIFACTO_TRACE=<path>`` or ``start()``): one complete
+("X") event with microsecond timestamps. Events carry ``pid``/``tid``,
+so a file holding events from the parent AND its producer worker
+processes renders as one timeline in Perfetto / chrome://tracing —
+worker parse -> pack -> ring wait -> consumer unpack -> device step,
+side by side. The event buffer is bounded (default 200k events) —
+overflow drops new events and counts them, never grows without limit.
 
 Cross-process story: timestamps come from ``time.perf_counter`` (Linux
 CLOCK_MONOTONIC — one clock for every process on the machine), so worker
@@ -19,33 +26,38 @@ span's id additionally rides the shm-ring slot header
 (data/shm_ring.py), so the consumer's unpack/step spans can point at the
 exact producer span that built their batch (``producer_span`` arg).
 
-Tracing is OFF unless ``DIFACTO_TRACE=<path>`` is set (or ``start()`` is
-called); an inactive ``span`` is a single global read plus a no-op yield.
-The event buffer is bounded (default 200k events) — overflow drops new
-events and counts them, never grows without limit.
+**The profiler's timeline** (the shared clock): wherever ``jax`` is
+already loaded, every span also opens a ``jax.profiler.TraceAnnotation``
+under its own name (``StepTraceAnnotation`` when it carries a
+``step_num`` arg). An annotation lands in WHATEVER profiler session is
+live in the process — one started by ``DIFACTO_TRACE_DEVICE=<logdir>``
+(:func:`start_device`), by ``jax.profiler.start_trace`` in a benchmark
+harness, or by a profiling server — and costs well under a microsecond
+when none is. The program's spans then sit in the same ``.xplane.pb`` as
+the device's ``XLA Ops``: that is what lets a reader attribute a
+device-idle gap to the innermost program span that covers it
+(``perfbench/spans.py``, which also reads the 1.5 ms by which a v5e's
+device planes run early against the host planes from the runtime's own
+events in the same file). An annotation that is open when a
+session starts or stops is not recorded (the profiler keeps complete
+events only). ``jax`` is never imported from here, so a producer worker
+that has not loaded it stays without it.
 
-Device time (the PR 4 leftover, ROADMAP item 3): with
-``DIFACTO_TRACE_DEVICE=<logdir>`` the module also starts the JAX
-profiler and wraps every span body in a
-``jax.profiler.TraceAnnotation`` (``StepTraceAnnotation`` when the span
-carries a ``step_num`` arg), so the XLA device timeline the profiler
-writes into ``<logdir>`` carries the SAME span names as the host
-Chrome-trace file — load both in Perfetto and host stages line up with
-the device programs they dispatched. Annotations are no-ops when the
-profiler is off, so the knob composes freely with ``DIFACTO_TRACE``.
+With neither sink on, a span is two clock reads and the annotation's
+no-op: about a microsecond (tests/test_obs.py bounds it).
 """
 
 from __future__ import annotations
 
 import atexit
-import contextlib
 import itertools
 import json
 import logging
 import os
+import sys
 import threading
 import time
-from typing import Iterator, List, Optional
+from typing import List, Optional
 from ..utils.locktrace import mutex
 
 _MAX_EVENTS = 200_000
@@ -55,14 +67,10 @@ _events: List[dict] = []
 _dropped = 0
 _active = False
 _path: Optional[str] = None
-_annotate = None          # jax.profiler module once device tracing is on
+_device_on = False        # a profiler session started by start_device
 _trace_id = 0
 _span_ids = itertools.count(1)
 _tls = threading.local()  # per-thread span stack
-
-
-def _now_us() -> float:
-    return time.perf_counter() * 1e6
 
 
 def active() -> bool:
@@ -101,32 +109,36 @@ def stop() -> None:
     _active = False
 
 
-def start_device(logdir: str) -> bool:
-    """Start the JAX profiler into ``logdir`` and annotate every span
-    from here on (``DIFACTO_TRACE_DEVICE``). Returns False when jax or
-    its profiler is unavailable — span capture still works without."""
-    global _annotate
+def start_device(logdir: str) -> None:
+    """Start the JAX profiler into ``logdir`` (``DIFACTO_TRACE_DEVICE``):
+    the operator's knob for a session of the program's own. Spans need
+    no switching on — they annotate any live session. A trace that was
+    asked for and cannot start RAISES: a run that silently carries on
+    without its trace is a measurement nobody took."""
+    global _device_on
+    import jax
     try:
-        import jax
+        os.makedirs(logdir, exist_ok=True)
         jax.profiler.start_trace(logdir)
-        # lint: ok(data-race) write-once setup before any span thread
-        _annotate = jax.profiler
-        return True
-    except Exception as e:  # pragma: no cover - profiler/backend quirks
-        logging.getLogger(__name__).warning(
-            "device trace unavailable (%s); host spans continue", e)
-        return False
+    except Exception as e:
+        raise RuntimeError(
+            f"DIFACTO_TRACE_DEVICE={logdir!r}: the device trace could "
+            f"not start ({e})") from e
+    # lint: ok(data-race) write-once setup before any span thread
+    _device_on = True
 
 
 def stop_device() -> None:
-    global _annotate
-    prof, _annotate = _annotate, None
-    if prof is not None:
-        try:
-            prof.stop_trace()
-        except Exception as e:  # pragma: no cover - teardown shield
-            logging.getLogger(__name__).warning(
-                "device trace stop failed: %s", e)
+    global _device_on
+    if not _device_on:
+        return
+    _device_on = False
+    import jax
+    try:
+        jax.profiler.stop_trace()
+    except Exception as e:  # pragma: no cover - teardown shield
+        logging.getLogger(__name__).warning(
+            "device trace stop failed: %s", e)
 
 
 def current_span_id() -> int:
@@ -171,44 +183,88 @@ def drain_events() -> List[dict]:
     return out
 
 
-@contextlib.contextmanager
-def span(name: str, **args) -> Iterator[int]:
-    """Record a complete trace event around the body. Nesting is
-    per-thread; the event carries its span id, parent span id and the
-    run's trace id, plus any keyword args (ints/strings only — they go
-    straight into the JSON)."""
-    if not _active:
-        yield 0
-        return
-    stack = getattr(_tls, "stack", None)
-    if stack is None:
-        stack = _tls.stack = []
-    sid = next(_span_ids)
-    parent = stack[-1] if stack else 0
-    stack.append(sid)
-    # device-timeline annotation (DIFACTO_TRACE_DEVICE): the profiler
-    # stamps the span name onto the XLA trace so Perfetto shows device
-    # programs under the same labels as these host events; a span
-    # carrying step_num= uses StepTraceAnnotation (JAX's step marker)
-    ann = contextlib.nullcontext()
-    if _annotate is not None:
-        ann = (_annotate.StepTraceAnnotation(
-                   name, step_num=args["step_num"])
-               if "step_num" in args
-               else _annotate.TraceAnnotation(name))
-    t0 = _now_us()
-    try:
-        with ann:
-            yield sid
-    finally:
-        dur = _now_us() - t0
-        stack.pop()
-        _tls.last = sid
-        ev = {"name": name, "ph": "X", "ts": t0, "dur": dur,
-              "pid": os.getpid(), "tid": threading.get_ident() & 0xFFFFFFFF,
-              "args": {"span_id": sid, "parent": parent,
-                       "trace_id": _trace_id, **args}}
-        add_event(ev)
+def _annotation(name: str, args: dict):
+    """The profiler annotation of a span, or None where ``jax`` is not
+    loaded in this process (never imported for this)."""
+    prof = sys.modules.get("jax.profiler")
+    cls = getattr(prof, "TraceAnnotation", None)
+    if cls is None:
+        return None
+    if "step_num" in args:
+        # JAX's step marker: the profiler's per-step device timeline
+        # aligns with the span's cadence
+        return prof.StepTraceAnnotation(name, **args)
+    return cls(name, **args)
+
+
+class span:
+    """``with span(name, **args) as sid:`` — time the body once; record a
+    complete event in the span file when that is on, and annotate the
+    live profiler session when there is one. Nesting is per-thread; the
+    event carries its span id, parent span id and the run's trace id,
+    plus the keyword args (ints/strings only — they go straight into the
+    JSON and the annotation). ``seconds`` holds the body's duration
+    after the close.
+
+    ``begin()``/``end()`` are the explicit form for a boundary that
+    crosses functions (the learner's ``epoch_turn``); such a span may
+    outlive the span that was open around its ``begin``."""
+
+    __slots__ = ("name", "args", "sid", "seconds", "_parent", "_ann",
+                 "_t0")
+
+    def __init__(self, name: str, **args) -> None:
+        self.name = name
+        self.args = args
+        self.sid = 0
+        self.seconds = 0.0
+        self._t0 = None
+
+    def __enter__(self) -> int:
+        if _active:
+            stack = getattr(_tls, "stack", None)
+            if stack is None:
+                stack = _tls.stack = []
+            self.sid = next(_span_ids)
+            self._parent = stack[-1] if stack else 0
+            stack.append(self.sid)
+        self._ann = _annotation(self.name, self.args)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self.sid
+
+    def __exit__(self, *exc) -> None:
+        t0, self._t0 = self._t0, None
+        self.seconds = time.perf_counter() - t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        if not self.sid:
+            return
+        stack = getattr(_tls, "stack", None)
+        if stack:
+            # a begin()/end() span may close after its neighbours
+            if stack[-1] == self.sid:
+                stack.pop()
+            elif self.sid in stack:
+                stack.remove(self.sid)
+        _tls.last = self.sid
+        add_event({"name": self.name, "ph": "X", "ts": t0 * 1e6,
+                   "dur": self.seconds * 1e6, "pid": os.getpid(),
+                   "tid": threading.get_ident() & 0xFFFFFFFF,
+                   "args": {"span_id": self.sid, "parent": self._parent,
+                            "trace_id": _trace_id, **self.args}})
+
+    def begin(self) -> "span":
+        self.__enter__()
+        return self
+
+    def end(self) -> float:
+        """Close a begun span (idempotent) -> its seconds."""
+        if self._t0 is not None:
+            self.__exit__(None, None, None)
+        return self.seconds
 
 
 def save(path: Optional[str] = None) -> Optional[str]:
@@ -248,8 +304,8 @@ def _maybe_start_from_env() -> None:
     if dev:
         # one profiler session per process, closed at exit so the
         # device trace flushes into <logdir> next to the span file
-        if start_device(dev):
-            atexit.register(stop_device)
+        start_device(dev)
+        atexit.register(stop_device)
 
 
 _maybe_start_from_env()

@@ -27,6 +27,7 @@ import numpy as np
 
 from ..base import REAL_DTYPE
 from ..data.rowblock import RowBlock
+from ..obs import names
 
 
 class DeviceBatch(NamedTuple):
@@ -193,6 +194,7 @@ def pack_panel(blk: RowBlock, num_uniq: int, slots: np.ndarray,
     return i32, f32, binary
 
 
+@names.leg(names.UNPACK)
 def unpack_panel(i32, f32, batch_cap: int, width: int, u_cap: int,
                  has_counts: bool = False, binary: bool = False):
     """jit-traceable inverse of pack_panel ->
@@ -255,6 +257,7 @@ def pack_panel_raw(blk: RowBlock, num_uniq: int, batch_cap: int,
     return i32, f32, binary
 
 
+@names.leg(names.UNPACK)
 def unpack_panel_raw(i32, f32, batch_cap: int, width: int,
                      binary: bool = False):
     """jit-traceable inverse of pack_panel_raw -> (PanelBatch with RAW
@@ -484,6 +487,7 @@ def pack_batch(blk: RowBlock, num_uniq: int, slots: np.ndarray,
     return i32, f32, binary
 
 
+@names.leg(names.UNPACK)
 def unpack_batch(i32, f32, batch_cap: int, nnz_cap: int, u_cap: int,
                  has_counts: bool = False, binary: bool = False):
     """jit-traceable inverse of pack_batch ->

@@ -50,6 +50,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..obs import names
 from ..utils import jaxtrace
 
 log = logging.getLogger("difacto_tpu")
@@ -147,6 +148,7 @@ def _log_resolution(knob: str, backend: str, reason: str) -> str:
 
 
 # --------------------------------------------------------------- dedup
+@names.leg(names.UNPACK)
 def dedup_tokens(tok: jnp.ndarray, u_cap: int, capacity: int
                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """On-device twin of the producer's ``np.unique`` + ``pad_slots_oob``
@@ -231,6 +233,7 @@ def dequant_half(codes: jnp.ndarray, scale: jnp.ndarray, kind: str
 
 
 # ------------------------------------------------------------- backends
+@names.leg(names.GATHER)
 def gather_rows(table: jnp.ndarray, slots: jnp.ndarray,
                 backend: str = "jnp") -> jnp.ndarray:
     """ONE fused-row gather of the batch's sorted unique slots.
@@ -247,6 +250,7 @@ def gather_rows(table: jnp.ndarray, slots: jnp.ndarray,
                                mode="fill", fill_value=0)
 
 
+@names.leg(names.SCATTER)
 def scatter_rows(table: jnp.ndarray, slots: jnp.ndarray,
                  rows: jnp.ndarray, backend: str = "jnp") -> jnp.ndarray:
     """Write ``rows`` back at ``slots`` (padded OOB entries dropped)."""
@@ -340,8 +344,11 @@ def fm_update_rows(table: jnp.ndarray, slots: jnp.ndarray,
         def tile_epilogue(rows_t, gw_t, gv_t, vm_t):
             return epilogue(rows_t, gw_t[:, 0], gv_t, vm_t[:, 0])
 
-        return _scatter_epilogue(table, slots, rows, extras,
-                                 tile_epilogue)
+        # one kernel does the epilogue and the write-back: it counts
+        # under the write-back's leg
+        with names.scope(names.SCATTER):
+            return _scatter_epilogue(table, slots, rows, extras,
+                                     tile_epilogue)
     new = epilogue(rows, gw, gV, vmask)
     return scatter_rows(table, slots, new, backend="jnp")
 

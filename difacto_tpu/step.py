@@ -40,6 +40,7 @@ import jax.numpy as jnp
 
 from .losses import FMParams, LossSpec
 from .losses.metrics import auc_times_n_binned_jnp, auc_times_n_jnp
+from .obs import names
 
 
 def state_constrainer(state_shardings):
@@ -93,9 +94,10 @@ def make_step_fns(fns, loss: LossSpec, train_auc: str = "binned",
 
     def forward(state, batch, slots):
         params, _, _ = pull(state, batch, slots)
-        pred = loss.predict(params, batch)
-        objv = loss.evaluate(pred, batch)
-        auc = auc_times_n_jnp(batch.labels, pred, batch.row_mask)
+        with names.scope(names.FORWARD):
+            pred = loss.predict(params, batch)
+            objv = loss.evaluate(pred, batch)
+            auc = auc_times_n_jnp(batch.labels, pred, batch.row_mask)
         return params, pred, objv, auc
 
     def train_step(state, batch, slots):
@@ -103,15 +105,18 @@ def make_step_fns(fns, loss: LossSpec, train_auc: str = "binned",
         # the forward hands its X·V to the backward so the fused step
         # gathers the [U, 1+k] token rows exactly once (round-4 profile:
         # the duplicate gather was ~15% of the step)
-        pred, xv = loss.predict_xv(params, batch)
-        objv = loss.evaluate(pred, batch)
-        if train_auc == "binned":
-            auc = auc_times_n_binned_jnp(batch.labels, pred, batch.row_mask)
-        elif train_auc == "exact":
-            auc = auc_times_n_jnp(batch.labels, pred, batch.row_mask)
-        else:
-            auc = jnp.float32(0.0)
-        gw, gV = loss.calc_grad(params, batch, pred, xv)
+        with names.scope(names.FORWARD):
+            pred, xv = loss.predict_xv(params, batch)
+            objv = loss.evaluate(pred, batch)
+            if train_auc == "binned":
+                auc = auc_times_n_binned_jnp(batch.labels, pred,
+                                             batch.row_mask)
+            elif train_auc == "exact":
+                auc = auc_times_n_jnp(batch.labels, pred, batch.row_mask)
+            else:
+                auc = jnp.float32(0.0)
+        with names.scope(names.BACKWARD):
+            gw, gV = loss.calc_grad(params, batch, pred, xv)
         if fused:
             state = fns.apply_grad_rows(state, slots, rows, gw, gV,
                                         slot_vmask)
@@ -152,9 +157,10 @@ def make_predict_fn(fns, loss: LossSpec):
     def predict_step(state, batch, slots):
         w, V, vmask = fns.get_rows(state, slots)
         params = FMParams(w=w, V=V, v_mask=vmask)
-        pred = loss.predict(params, batch)
-        objv = loss.evaluate(pred, batch)
-        auc = auc_times_n_jnp(batch.labels, pred, batch.row_mask)
+        with names.scope(names.FORWARD):
+            pred = loss.predict(params, batch)
+            objv = loss.evaluate(pred, batch)
+            auc = auc_times_n_jnp(batch.labels, pred, batch.row_mask)
         return pred, objv, auc
 
     return predict_step

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import Param
+from ..obs import names
 
 TRASH_SLOT = 0  # row 0 absorbs padded scatters; never a real feature
 
@@ -489,6 +490,7 @@ def ftrl_w(w, z, sg, gw, l1: float, l2: float, lr: float, lr_beta: float):
     return w_new, z_new, sg_new
 
 
+@names.leg(names.UPDATE)
 def row_epilogue(param: SGDUpdaterParam, capacity: int, rows: jnp.ndarray,
                  gw: jnp.ndarray, gV: Optional[jnp.ndarray],
                  pull_vmask: Optional[jnp.ndarray]) -> jnp.ndarray:
@@ -590,6 +592,7 @@ def make_fns(param: SGDUpdaterParam, mesh=None):
     def _layout(state):
         return row_layout(param, state.capacity)
 
+    @names.leg(names.UPDATE)
     def _ftrl(w, z, sg, gw):
         return ftrl_w(w, z, sg, gw, l1, l2, lr, lr_beta)
 
@@ -604,6 +607,7 @@ def make_fns(param: SGDUpdaterParam, mesh=None):
         can ride bf16."""
         return fused.gather_rows(state.VVg, slots, backend)
 
+    @names.leg(names.FORWARD)
     def rows_to_params(state: SGDState, rows: jnp.ndarray):
         """(w, V, v_mask) views of gathered fused rows (Get,
         sgd_updater.cc:34-58): the embedding is served only when live
@@ -644,15 +648,17 @@ def make_fns(param: SGDUpdaterParam, mesh=None):
             return state._replace(cnt=cnt)
         _, _, _, off = _layout(state)
         rows = _gather(state.VVg, slots)
-        f = scal_f32(rows[:, off:])
-        w, z, sg, cnt, live = f[:, 0], f[:, 1], f[:, 2], f[:, 3], f[:, 4] > 0
-        cnt_new = cnt + counts
-        live_new = live | ((w != 0) & (cnt_new > thr))
-        # scale lanes 5/6 carried through — a count push must not zero a
-        # quantized row's dequant scales
-        scal = pack_scal(w, z, sg, cnt_new, live_new, state.VVg.dtype,
-                         scale_V=f[:, 5], scale_Vg=f[:, 6])
-        out = jnp.concatenate([rows[:, :off], scal], axis=1)
+        with names.scope(names.UPDATE):
+            f = scal_f32(rows[:, off:])
+            w, z, sg, cnt, live = (f[:, 0], f[:, 1], f[:, 2], f[:, 3],
+                                   f[:, 4] > 0)
+            cnt_new = cnt + counts
+            live_new = live | ((w != 0) & (cnt_new > thr))
+            # scale lanes 5/6 carried through — a count push must not
+            # zero a quantized row's dequant scales
+            scal = pack_scal(w, z, sg, cnt_new, live_new, state.VVg.dtype,
+                             scale_V=f[:, 5], scale_Vg=f[:, 6])
+            out = jnp.concatenate([rows[:, :off], scal], axis=1)
         return state._replace(VVg=_scatter(state.VVg, slots, out))
 
     def apply_grad_rows(state: SGDState, slots: jnp.ndarray,
@@ -698,6 +704,7 @@ def make_fns(param: SGDUpdaterParam, mesh=None):
         rows = pull_rows(state, slots)
         return apply_grad_rows(state, slots, rows, gw, gV, pull_vmask)
 
+    @names.leg(names.EVALUATE)
     def evaluate(state: SGDState) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """(penalty, nnz) over real rows (Evaluate, sgd_updater.cc:15-32).
         Full-table column reads of the fused rows — once per epoch."""

@@ -26,6 +26,8 @@ class Reporter:
 
     def set_monitor(self, fn: Callable[[int, Any], None]) -> None:
         """fn(node_id, payload)."""
+        # lint: ok(data-race) write-once wiring before the reporting
+        # thread starts (the serve batcher's loop, the learner's run)
         self._monitor = fn
 
     def report(self, payload: Any, node_id: int = 0) -> int:

@@ -426,8 +426,16 @@ def test_trace_device_spans_and_profile_artifacts(tmp_path):
 
 def test_trace_device_absent_knob_keeps_spans_plain(tmp_path,
                                                     monkeypatch):
-    # without the knob the module never touches jax — spans stay the
-    # cheap host-only path
+    # the module never imports jax for a span: where jax is not loaded
+    # (a producer worker) spans stay the cheap host-only path, and no
+    # profiler session is started without the knob
+    import sys
+
     from difacto_tpu.obs import trace
     monkeypatch.delenv("DIFACTO_TRACE_DEVICE", raising=False)
-    assert trace._annotate is None
+    assert trace._device_on is False
+    monkeypatch.setitem(sys.modules, "jax.profiler", None)
+    assert trace._annotation("gate.host", {}) is None
+    with trace.span("gate.host") as sid:
+        pass
+    assert sid == 0 or trace.active()

@@ -975,11 +975,19 @@ def test_the_tree_is_clean(capsys):
     # the suite itself keeps the analyzer honest: suppressions in the
     # tree must stay EXACTLY this number — bump deliberately when
     # adding one, prune when a fix removes one. Inventory (the v4
-    # sweep re-justified every entry): 24 data-race (stop flags,
+    # sweep re-justified every entry): 22 data-race (stop flags,
     # monotonic #stats counters, atomic reference swaps, single-owner
-    # instances, pre-spawn publication, the write-once profiler handle
-    # in obs/trace.start_device, the ISSUE 18 client blacklist-refold
-    # fields and the router group's write-once accept-thread handle),
+    # instances, pre-spawn publication, the ISSUE 18 client
+    # blacklist-refold fields, the router group's write-once
+    # accept-thread handle and the Reporter's write-once monitor — PR 25
+    # took three away with obs/trace's ``_annotate`` global and the
+    # generator ``span`` whose reads of ``_active``/``_trace_id`` the
+    # call graph followed from the producer threads; ``span`` is a class
+    # now and ``with span(...)`` resolves to its ``__init__`` alone, a
+    # documented blind spot, so those two pragmas stay in the file
+    # unmatched. Deleting utils/profiling.py left ``Reporter.report``
+    # the only ``report`` method, so the serve batcher's call resolved
+    # precisely and the monitor's pre-spawn write needed its reason),
     # 6 wall-clock (cross-process file
     # timestamps x3, JSONL record stamps, trace-id entropy, run-dir
     # stamp), 2 lock-release (locktrace forwarding wrapper),
@@ -996,12 +1004,12 @@ def test_the_tree_is_clean(capsys):
     # leg jitted an unpinned donated-state program) was FIXED by
     # threading mesh -> state_shardings through build_step, and the
     # three shard rules run clean on the tree.
-    assert doc["counts"]["suppressed"] == 54
+    assert doc["counts"]["suppressed"] == 52
     import collections
     per_rule = collections.Counter(
         f["rule"] for f in doc["findings"] if f["suppressed"])
     assert dict(per_rule) == {
-        "data-race": 24,
+        "data-race": 22,
         "jax-recompile": 17,
         "wall-clock": 6,
         "jax-host-sync": 4,

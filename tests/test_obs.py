@@ -186,6 +186,101 @@ def test_chrome_trace_json_valid(tmp_path):
     assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1
 
 
+def _host_events(logdir) -> dict:
+    """{event name: count} over the host planes of the one ``.xplane.pb``
+    a profiler session left under ``logdir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(str(logdir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    seen = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                seen[e.name] = seen.get(e.name, 0) + 1
+    return seen
+
+
+def test_span_joins_a_profiler_session_started_elsewhere(tmp_path):
+    """A span annotates WHATEVER profiler session is live in the process
+    — here one started by the test, as a benchmark harness does, with no
+    ``DIFACTO_*`` variable set — under its own name, on the session's
+    clock; a ``stage`` does the same and counts its seconds; a span
+    opened when nothing is on records nothing anywhere."""
+    import jax
+
+    from difacto_tpu.obs import stage
+    assert not trace.active() and not trace._device_on
+    trace.drain_events()
+    reg = Registry(enabled=True)
+    with deadline(300):
+        with trace.span("obs_test.before_session"):
+            pass
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with trace.span("obs_test.outside_span", epoch=3):
+                time.sleep(0.002)
+            with stage(reg, "dispatch", also=("step",), epoch=3,
+                       step_num=5) as st:
+                time.sleep(0.002)
+            with stage(reg, "pack", part=1):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        with trace.span("obs_test.after_session"):
+            pass
+    seen = _host_events(tmp_path)
+    assert seen.get("obs_test.outside_span") == 1
+    assert seen.get("dispatch") == 1
+    assert seen.get("producer.pack") == 1      # the stage's older span name
+    assert "obs_test.before_session" not in seen
+    assert "obs_test.after_session" not in seen
+    assert st.seconds >= 0.002
+    assert reg.value("stage_seconds_total", stage="dispatch") == st.seconds
+    assert reg.value("stage_seconds_total", stage="step") == st.seconds
+    # no span file was asked for: nothing was buffered for one
+    assert trace.drain_events() == []
+
+
+def test_start_device_raises_on_unusable_logdir(tmp_path):
+    """``DIFACTO_TRACE_DEVICE`` is a trace that was asked for: where it
+    cannot start, ``start_device`` raises (it warned and carried on)."""
+    blocker = tmp_path / "a_file"
+    blocker.write_text("not a directory")
+    with deadline(120):
+        with pytest.raises(RuntimeError, match="DIFACTO_TRACE_DEVICE"):
+            trace.start_device(str(blocker / "logdir"))
+    assert trace._device_on is False
+    trace.stop_device()      # nothing was started: a no-op
+
+
+def test_span_begin_end_outlives_its_neighbours():
+    """The explicit form of a boundary that crosses functions: a span
+    begun inside another may end after it (``epoch_turn`` begins in one
+    ``epoch`` span and ends in the next); ``end`` is idempotent."""
+    trace.drain_events()
+    trace.start()
+    try:
+        with trace.span("outer.a"):
+            turn = trace.span("turn", epoch=1).begin()
+        with trace.span("outer.b"):
+            with trace.span("child"):
+                pass
+            first = turn.end()
+        assert turn.end() == first > 0
+    finally:
+        trace.stop()
+    evs = {e["name"]: e for e in trace.drain_events()}
+    assert set(evs) == {"outer.a", "outer.b", "child", "turn"}
+    assert evs["turn"]["args"]["parent"] == evs["outer.a"]["args"]["span_id"]
+    # the begun span is the innermost open one until it ends
+    assert evs["child"]["args"]["parent"] == evs["outer.b"]["args"]["span_id"]
+    assert evs["turn"]["ts"] + evs["turn"]["dur"] > evs["outer.b"]["ts"]
+
+
 # ------------------------------------------------------------ exporters
 
 def test_prometheus_render_and_flusher(tmp_path):
@@ -301,41 +396,62 @@ def test_serve_metrics_endpoint():
 
 # ------------------------------------------------------- overhead guard
 
-def _synthetic_step_loop(reg, steps: int = 200) -> float:
-    """A small training-step stand-in: real numpy work plus the per-step
-    metric traffic the instrumented hot paths actually issue."""
+def _min_us(fn, calls: int = 2000, repeats: int = 40) -> float:
+    """Microseconds a call of ``fn``: the minimum over many short
+    repeats. A burst of 2000 calls takes a few milliseconds, so some
+    repeat runs undisturbed even with every other xdist worker busy —
+    the old form compared two ~100 ms wall-clock loops and failed on
+    whichever one the scheduler hit."""
+    best = float("inf")
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            fn()
+        best = min(best, time.perf_counter() - t0)
+    return best / calls * 1e6
+
+
+def _inactive_span():
+    with trace.span("guard.span", part=3):
+        pass
+
+
+@pytest.mark.parametrize("case,limit_us", [
+    ("counter_inc", 5.0), ("inactive_span", 10.0),
+    ("inactive_stage", 20.0)])
+def test_metrics_overhead_bounded(case, limit_us):
+    """Acceptance guard: what the always-on instrumentation costs a call
+    when nothing is on (no ``DIFACTO_TRACE``, no profiler session),
+    against an absolute limit. Measured on the sandbox's CPU:
+    ``Counter.inc`` 0.1 us, an inactive ``span`` 1.0-1.6 us (two clock
+    reads and the profiler annotation's no-op), an inactive ``obs.stage``
+    with ``also=`` and two args 3.2-4.7 us. The limits leave 3-6x of
+    room: only a real hot-path regression (a lock on the inc path, an
+    allocation per observe, a profiler session probed per span) trips
+    them."""
+    from difacto_tpu.obs import stage
+    assert not trace.active()
+    reg = Registry(enabled=True)
     c = reg.counter("guard_seconds_total").labels(stage="step")
-    rows = reg.counter("guard_rows_total")
-    h = reg.histogram("guard_step_seconds")
-    x = np.random.RandomState(0).rand(192, 192).astype(np.float32)
-    t0 = time.perf_counter()
-    acc = 0.0
-    for _ in range(steps):
-        y = x @ x
-        acc += float(y[0, 0])
-        c.inc(1e-3)
-        rows.inc(256)
-        h.observe(1e-3)
-    assert acc != 0
-    return time.perf_counter() - t0
 
+    def inactive_stage():
+        with stage(reg, "dispatch", also=("step",), epoch=1, step_num=7):
+            pass
 
-def test_metrics_overhead_bounded():
-    """Acceptance guard: the enabled registry on a synthetic step loop
-    stays within noise of the DIFACTO_OBS=off no-op registry — cheap
-    enough to leave on by default. Best-of-3 each to damp scheduler
-    noise; the bound is generous (50% + 50ms) so only a real hot-path
-    regression (a lock on the inc path, an allocation per observe)
-    trips it."""
-    on = Registry(enabled=True)
-    off = Registry(enabled=False)
-    assert off.counter("guard_seconds_total") is not None
+    fn = {"counter_inc": lambda: c.inc(1e-3),
+          "inactive_span": _inactive_span,
+          "inactive_stage": inactive_stage}[case]
     with deadline(120):
-        _synthetic_step_loop(on, steps=20)   # warm both paths
-        _synthetic_step_loop(off, steps=20)
-        t_on = min(_synthetic_step_loop(on) for _ in range(3))
-        t_off = min(_synthetic_step_loop(off) for _ in range(3))
-    assert t_on <= t_off * 1.5 + 0.05, (t_on, t_off)
+        fn()
+        us = _min_us(fn)
+    assert us <= limit_us, (case, us)
+    # and it recorded nothing in the span file's buffer
+    assert trace.drain_events() == []
+    if case == "inactive_stage":
+        # the counter half is never off: same seconds in both stages
+        assert reg.value("stage_seconds_total", stage="dispatch") > 0
+        assert reg.value("stage_seconds_total", stage="step") == \
+            reg.value("stage_seconds_total", stage="dispatch")
 
 
 # ------------------------------------------------- learner stage source

@@ -27,7 +27,11 @@ from collections import defaultdict
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])  # repo root
 
-STAGE_ORDER = ("parse", "pack", "ring_wait", "transfer", "step")
+STAGE_ORDER = ("parse", "pack", "ring_wait", "transfer", "step",
+               "dispatch", "fetch_wait", "epoch_turn", "compile")
+# shown, not summed: ``step`` already holds dispatch + fetch_wait, and
+# compile seconds run under whichever stage triggered the compile
+STAGE_PARTS = ("dispatch", "fetch_wait", "compile")
 
 
 def load_last_snapshot(path: str) -> dict:
@@ -73,12 +77,14 @@ def report_stages(snap: dict) -> None:
         stage = dict(p.split("=", 1) for p in key.split(",")
                      if "=" in p).get("stage", key)
         vals[stage] = vals.get(stage, 0.0) + v
-    total = sum(vals.values()) or 1.0
-    print("== streamed stage table (seconds, % of accounted time) ==")
+    total = sum(v for k, v in vals.items()
+                if k not in STAGE_PARTS) or 1.0
+    print("== stage table (seconds, % of accounted time) ==")
     for stage in STAGE_ORDER + tuple(sorted(set(vals) - set(STAGE_ORDER))):
         if stage in vals:
             v = vals[stage]
-            print(f"  {stage:10s} {v:10.3f}s  {100 * v / total:5.1f}%")
+            name = ("  " + stage) if stage in STAGE_PARTS else stage
+            print(f"  {name:12s} {v:10.3f}s  {100 * v / total:5.1f}%")
     print()
 
 
