@@ -75,9 +75,9 @@ _FUNC_DEFS = (ast.FunctionDef, ast.AsyncFunctionDef)
 _LOOPS = (ast.For, ast.AsyncFor, ast.While)
 
 # calls that quantize a data-dependent value onto a bounded set: the
-# sticky shape caps (data/pack_stream.ShapeSchedule.cap, only grows,
-# log-many values) and the bucket rungs (ops/batch.bucket)
-_BOUNDING_CALLS = {"cap", "bucket"}
+# sticky shape caps (data/pack_stream.ShapeSchedule.cap and .row_cap,
+# only grow, log-many values) and the rungs (ops/batch.bucket, row_cap)
+_BOUNDING_CALLS = {"cap", "bucket", "row_cap"}
 # attribute segments that mark config-derived constants (difacto's
 # Param dataclasses): bounded for a run's lifetime
 _CONFIG_SEGMENTS = {"param", "uparam"}

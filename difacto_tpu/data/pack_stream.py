@@ -45,11 +45,13 @@ class ShapeSchedule:
         self._lock = mutex()
 
     def cap(self, key: str, n: int, minimum: int = 8,
-            exact: bool = False) -> int:
+            exact: bool = False, ladder=None) -> int:
         """``exact`` keeps a plain sticky max instead of bucketing — for
         dims that are naturally constant (panel width: criteo rows are
         always 39 wide; bucketing to 48 would inflate every panel cell
-        stream by ~23% and defeat the uniform-reshape fast path)."""
+        stream by ~23% and defeat the uniform-reshape fast path).
+        ``ladder`` is the rounding, ``bucket`` unless given
+        (:meth:`row_cap` gives the finer one)."""
         from ..ops.batch import bucket
         with self._lock:
             c = self._caps.get(key, 0)
@@ -57,9 +59,20 @@ class ShapeSchedule:
                 # floor degenerate dims like the bucket() it replaces
                 # (bucket(0) == minimum) — empty batches still need
                 # non-zero-sized device shapes
-                c = max(n, 1) if exact else bucket(n, minimum)
+                c = max(n, 1) if exact else (ladder or bucket)(n, minimum)
                 self._caps[key] = c
             return c
+
+    def row_cap(self, job: str, n: int) -> int:
+        """The sticky cap of ``job``'s unique-row dimension (key
+        ``<job>.u``), on ``ops.batch.row_cap``'s ladder of eighths: the
+        step program's legs are all sized by it, so its padding is
+        device time in every step. Every batch the learner packs takes
+        it; the request path (``serve.u``, and one-device ``task=pred``
+        through it) and the SPMD slot union keep ``bucket`` — see where
+        each takes its cap."""
+        from ..ops.batch import row_cap
+        return self.cap(job + ".u", n, ladder=row_cap)
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -114,6 +127,14 @@ def pack_payload(shapes: ShapeSchedule, cblk, n_lanes: int,
         cblk, n_lanes, padded, b_cap, nnz_cap, u_cap,
         counts=counts)
     return ("coo", i32, f32, binary, b_cap, nnz_cap, u_cap)
+
+
+def payload_rows(payload) -> int:
+    """The distinct table rows of a packed host payload: the
+    ``num_uniq`` word its packer left in the i32 buffer's meta tail
+    (ops/batch.pack_panel, pack_panel_raw: ``[b, nu]``; pack_batch:
+    ``[b, nu, nnz]``)."""
+    return int(payload[1][-2 if payload[0] == "coo" else -1])
 
 
 def chunk_host(i32: np.ndarray, f32: np.ndarray, b_cap: int,
@@ -192,7 +213,7 @@ def prepare_hashed(shapes: ShapeSchedule, hash_capacity: int, blk,
         width = panel_width(cblk, b_cap_raw)
         if width is not None:
             n_uniq = _count_distinct(tok, hash_capacity)
-            u_cap = shapes.cap(job + ".u", n_uniq + 1)
+            u_cap = shapes.row_cap(job, n_uniq + 1)
             width = shapes.cap(job + ".w", width, exact=True)
             i32, f32, binary = pack_panel_raw(cblk, n_uniq, b_cap_raw,
                                               width)
@@ -218,7 +239,7 @@ def prepare_hashed(shapes: ShapeSchedule, hash_capacity: int, blk,
     # +1 under admission: cells whose token was unadmitted reference
     # position n_uniq, which must exist as an OOB pad lane even when the
     # sticky cap is otherwise exactly full
-    u_cap = shapes.cap(job + ".u", n_uniq + (1 if admit is not None else 0))
+    u_cap = shapes.row_cap(job, n_uniq + (1 if admit is not None else 0))
     b_cap = b_cap or shapes.cap(job + ".b", blk.size, dim_min)
     padded = pad_slots_oob(slots.astype(np.int32), u_cap, hash_capacity)
     return pack_payload(shapes, cblk, n_uniq, padded, b_cap, dim_min,
@@ -242,7 +263,7 @@ def prepare_from_uniq(shapes: ShapeSchedule, hash_capacity: int, cblk,
     cblk = dataclasses.replace(
         cblk, index=remap[cblk.index].astype(np.uint32))
     n_lanes = len(slots)
-    u_cap = shapes.cap(job + ".u", n_lanes)
+    u_cap = shapes.row_cap(job, n_lanes)
     b_cap = b_cap or shapes.cap(job + ".b", cblk.size, dim_min)
     scounts = np.zeros(0, np.float32) if want_counts else None
     if fill_counts and counts is not None:

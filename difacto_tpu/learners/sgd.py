@@ -204,6 +204,7 @@ class _DeviceBatchCache:
 # boundary, and the packing helpers it governs are shared between the
 # learner's threads and the worker processes
 from ..data.pack_stream import ShapeSchedule as _ShapeSchedule  # noqa: E402
+from ..data.pack_stream import payload_rows  # noqa: E402
 
 
 @dataclass
@@ -446,6 +447,16 @@ class SGDLearner(Learner):
             "store_gather_bytes_total",
             "slot-table row bytes gathered+scattered per dispatched "
             "device program").labels(path="train")
+        # the fill of the step's unique-row dimension: rows / cap is the
+        # share of every cap-sized leg that is not padding (_enqueue)
+        cap_c = self.obs.counter(
+            "step_row_cap_total",
+            "unique-row cap (u_cap) of every dispatched step, summed")
+        rows_c = self.obs.counter(
+            "step_rows_total",
+            "distinct table rows of every dispatched step, summed")
+        self._fill_c = {train: (cap_c.labels(job=job), rows_c.labels(job=job))
+                        for train, job in ((True, "train"), (False, "eval"))}
         self._last_producer_mode = "thread"
         self._flusher = None
         self._shapes = _ShapeSchedule()
@@ -1094,16 +1105,18 @@ class SGDLearner(Learner):
             turn.end()
 
     @contextlib.contextmanager
-    def _enqueue(self, job_type: int, u_cap: int, n_steps: int = 1):
+    def _enqueue(self, job_type: int, u_cap: int, rows: int,
+                 n_steps: int = 1):
         """The one prologue and accounting of EVERY step-program enqueue
         (single, paired replay, mesh, SPMD): traverse the ``step.device``
         chaos point (step.py), count the table row traffic of
         ``n_steps`` steps (u_cap fused rows pulled, and pushed again
         when training — updaters.gather_bytes; the serve path counts
-        its own under path="serve"), close an open ``epoch_turn``, and
-        run the body under the ``dispatch`` stage (its seconds also land
-        in ``step``) with one ``train_step_seconds`` observation a
-        step."""
+        its own under path="serve") and the fill of their row cap
+        (``rows`` distinct rows in all, under ``n_steps`` caps of
+        ``u_cap``), close an open ``epoch_turn``, and run the body under
+        the ``dispatch`` stage (its seconds also land in ``step``) with
+        one ``train_step_seconds`` observation a step."""
         from ..step import fire_step_fault
         from ..updaters.sgd_updater import gather_bytes
         fire_step_fault()
@@ -1111,6 +1124,9 @@ class SGDLearner(Learner):
                                u_cap)
         self._gather_c.inc(
             per_dir * n_steps * (2 if job_type == K_TRAINING else 1))
+        cap_c, rows_c = self._fill_c[job_type == K_TRAINING]
+        cap_c.inc(u_cap * n_steps)
+        rows_c.inc(rows)
         self._end_turn()
         st = stage(self.obs, names.DISPATCH, also=(names.STEP,),
                    epoch=self._epoch, step_num=self._step_num)
@@ -1505,6 +1521,10 @@ class SGDLearner(Learner):
                     rank = np.empty(len(order), dtype=np.int64)
                     rank[order] = np.arange(len(order))
                 gu = len(slots_sorted)
+                # bucket's ladder, not row_cap's finer one: this cap is
+                # taken anew for every step's union (no sticky
+                # schedule), so every rung crossed, up or down, is a
+                # compile of the sharded step on every host
                 gu_cap = bucket(gu)
                 from ..store.local import pad_slots_oob
                 slots_g = pad_slots_oob(slots_sorted, gu_cap, cap_logical)
@@ -1611,13 +1631,13 @@ class SGDLearner(Learner):
                                             replicated(self.mesh)),
                     )
                 sent[0] += 1
-                yield batch, slots_dev, cts_dev, nrows_g, cblk, grow
+                yield batch, slots_dev, cts_dev, nrows_g, cblk, grow, gu
 
         pending: list = []
         # τ deepens the staging pipeline: the exchange thread may run up
         # to 2+τ steps ahead of the dispatch loop (τ=0 keeps the historic
         # depth-2 double-buffer, so that path is untouched)
-        for batch, slots_dev, cts_dev, nrows_g, cblk, grow in prefetch(
+        for batch, slots_dev, cts_dev, nrows_g, cblk, grow, gu in prefetch(
                 exchange(), depth=2 + tau):
             if grow is not None:
                 # deferred dictionary growth (see exchange()): applied in
@@ -1631,7 +1651,7 @@ class SGDLearner(Learner):
                     self.store.state, slots_dev, cts_dev)
             # the replicated global slot union is pulled once — and
             # pushed once when training — at the fused-row width
-            with self._enqueue(job_type, slots_dev.shape[0]):
+            with self._enqueue(job_type, slots_dev.shape[0], gu):
                 if job_type == K_TRAINING:
                     self.store.state, objv, auc = self._train_step(
                         self.store.state, batch, slots_dev)
@@ -1655,7 +1675,7 @@ class SGDLearner(Learner):
                 # global payloads, same device counts per host on a
                 # uniform mesh), so alive flips in lockstep
                 cache.add(part_idx,
-                          ("devbatch", batch, slots_dev, nrows_g),
+                          ("devbatch", batch, slots_dev, nrows_g, gu),
                           self._payload_nbytes((batch, slots_dev)),
                           capacity=self.store.state.capacity)
             pending.append((nrows_g, objv, auc))
@@ -2076,7 +2096,7 @@ class SGDLearner(Learner):
             # replay window runs
             pa = (a[1], a[2], a[3], a[4], a[5])
             pb = (b[1], b[2], b[3], b[4], b[5])
-            with self._enqueue(job_type, a[8], n_steps=2):
+            with self._enqueue(job_type, a[8], a[12] + b[12], n_steps=2):
                 self.store.state, o1, a1, o2, a2 = exec_(
                     self.store.state, pa, pb)
             pending.append((a[11], o1, a1))
@@ -2483,12 +2503,13 @@ class SGDLearner(Learner):
                          label=None) -> None:
         """Run the fused step on an already-staged packed batch. ``payload``
         = (layout, i32_dev, f32_dev, b_cap, dim2, u_cap, want_counts,
-        binary, nrows); dim2 is the panel width or the COO nnz_cap.
-        Prologue and accounting: :meth:`_enqueue`."""
+        binary, nrows, n_uniq); dim2 is the panel width or the COO
+        nnz_cap, n_uniq (last in every layout) the batch's distinct table
+        rows. Prologue and accounting: :meth:`_enqueue`."""
         u_cap = (payload[2].shape[0] if payload[0] == "devbatch"
                  else payload[8] if payload[0] == "panel_chunked"
                  else payload[5])
-        with self._enqueue(job_type, u_cap):
+        with self._enqueue(job_type, u_cap, payload[-1]):
             self._dispatch_packed_inner(job_type, payload, pending, label)
 
     def _dispatch_packed_inner(self, job_type: int, payload, pending: list,
@@ -2496,7 +2517,7 @@ class SGDLearner(Learner):
         is_train = job_type == K_TRAINING
         if payload[0] == "devbatch":
             # cached replay of a staged mesh/multi-host global batch
-            _, dev, slots, nrows = payload
+            _, dev, slots, nrows, _ = payload
             if is_train:
                 self.store.state, objv, auc = self._train_step(
                     self.store.state, dev, slots)
@@ -2509,7 +2530,7 @@ class SGDLearner(Learner):
             # cached replay fast path (train only): packed panel + the
             # staged chunked-run backward layout
             (_, i32, f32, ci, cl, cv, b_cap, d2, u_cap, want_counts,
-             binary, nrows) = payload
+             binary, nrows, _) = payload
             # lint: ok(jax-recompile) payload statics are ShapeSchedule
             # caps / bucket rungs recorded at pack or staging time —
             # bounded by the sticky-cap contract, which provenance
@@ -2520,7 +2541,7 @@ class SGDLearner(Learner):
             pending.append((nrows, objv, auc))
             return
         (layout, i32, f32, b_cap, d2, u_cap, want_counts, binary,
-         nrows) = payload
+         nrows, _) = payload
         if layout == "panel_raw":
             # device-dedup streamed payload (train-only by the
             # _iterate_parts gate): raw token lanes, slots + inverse
@@ -2595,7 +2616,7 @@ class SGDLearner(Learner):
                                     want_counts, pending, cache, part)
             return
         n_uniq = len(slots_np)
-        u_cap = self._shapes.cap(job + ".u", n_uniq)
+        u_cap = self._shapes.row_cap(job, n_uniq)
         b_cap = self._shapes.cap(job + ".b", blk.size, dim_min)
         nnz_cap = self._shapes.cap(job + ".nnz", blk.nnz, dim_min)
         slots = self.store.pad_slots(slots_np, u_cap)
@@ -2623,7 +2644,7 @@ class SGDLearner(Learner):
             c[:len(cnts)] = cnts
             self.store.state = self._apply_count(
                 self.store.state, slots, jnp.asarray(c))
-        with self._enqueue(job_type, u_cap):
+        with self._enqueue(job_type, u_cap, n_uniq):
             if job_type == K_TRAINING:
                 self.store.state, objv, auc = self._train_step(
                     self.store.state, dev, slots)
@@ -2631,7 +2652,7 @@ class SGDLearner(Learner):
                 pred, objv, auc = self._eval_step(self.store.state, dev,
                                                   slots)
         if cache is not None and cache.staging:
-            cache.add(part, ("devbatch", dev, slots, blk.size),
+            cache.add(part, ("devbatch", dev, slots, blk.size, n_uniq),
                       self._payload_nbytes((dev, slots)),
                       capacity=self.store.state.capacity)
         if job_type == K_PREDICTION and p.pred_out:
@@ -2654,7 +2675,7 @@ class SGDLearner(Learner):
         ids."""
         from ..store.local import pad_slots_oob
         n_uniq = len(slots_np)
-        u_cap = self._shapes.cap(job + ".u", n_uniq)
+        u_cap = self._shapes.row_cap(job, n_uniq)
         b_cap = self._shapes.cap(job + ".b", blk.size, dim_min)
         if want_counts:
             counts = cnts if push_cnt and cnts is not None \
@@ -2688,17 +2709,25 @@ class SGDLearner(Learner):
                     and payload[0] in ("panel", "coo"):
                 from ..capacity.tier import route_payload
                 payload = route_payload(self.store.tier, payload)
-            if payload[0] == "panel_chunked":
-                (_, i32, f32, (ci, cl, cv), binary, b_cap, d2,
-                 u_cap) = payload
-                return ("panel_chunked", jnp.asarray(i32),
-                        jnp.asarray(f32),
-                        (jnp.asarray(ci), jnp.asarray(cl),
-                         None if cv is None else jnp.asarray(cv)),
-                        binary, b_cap, d2, u_cap)
-            layout, i32, f32, binary, b_cap, d2, u_cap = payload
-            return (layout, jnp.asarray(i32), jnp.asarray(f32), binary,
-                    b_cap, d2, u_cap)
+            return self._payload_to_device(payload)
+
+    @staticmethod
+    def _payload_to_device(payload):
+        """A packed host payload with device arrays in place of the
+        numpy ones, and the batch's distinct-row count appended: it is
+        read here, while the i32 buffer's meta words are host memory
+        (_enqueue counts it against the row cap)."""
+        n_uniq = payload_rows(payload)
+        if payload[0] == "panel_chunked":
+            (_, i32, f32, (ci, cl, cv), binary, b_cap, d2,
+             u_cap) = payload
+            return ("panel_chunked", jnp.asarray(i32), jnp.asarray(f32),
+                    (jnp.asarray(ci), jnp.asarray(cl),
+                     None if cv is None else jnp.asarray(cv)),
+                    binary, b_cap, d2, u_cap, n_uniq)
+        layout, i32, f32, binary, b_cap, d2, u_cap = payload
+        return (layout, jnp.asarray(i32), jnp.asarray(f32), binary,
+                b_cap, d2, u_cap, n_uniq)
 
     def _dispatch_prepared(self, job_type: int, blk, payload,
                            push_cnt: bool, want_counts: bool,
@@ -2706,9 +2735,9 @@ class SGDLearner(Learner):
                            cache: Optional[_DeviceBatchCache],
                            part: int) -> None:
         """Stage + run one packed-payload batch (both store modes), then
-        hand the staged device buffers to the replay cache. Payload
-        arrays may be numpy (direct path) or already on device
-        (_stage_payload's double-buffered path)."""
+        hand the staged device buffers to the replay cache. The payload
+        is a packer's, numpy arrays and all (direct path), or already
+        on device (_stage_payload's double-buffered path)."""
         is_train = job_type == K_TRAINING
         if is_train and self._wal is not None and self._wal_skip > 0:
             # recovery fast-forward (durability/recover.py): this
@@ -2722,21 +2751,19 @@ class SGDLearner(Learner):
             self._wal_lo = self._wal_step
             return
         with stage(self.obs, names.TRANSFER, epoch=self._epoch):
-            if payload[0] == "panel_chunked":
-                # producer-side chunked layout (stream_chunks): the host
-                # sort already ran on the producer thread, so both
-                # streamed dispatch AND cache staging use these chunks
-                (_, i32, f32, (ci_np, cl_np, cv_np), binary, b_cap, d2,
-                 u_cap) = payload
-                layout = "panel"
-                i32, f32 = jnp.asarray(i32), jnp.asarray(f32)
-                ci, cl = jnp.asarray(ci_np), jnp.asarray(cl_np)
-                cv = None if cv_np is None else jnp.asarray(cv_np)
-                chunked = True
-            else:
-                layout, i32, f32, binary, b_cap, d2, u_cap = payload
-                i32, f32 = jnp.asarray(i32), jnp.asarray(f32)
-                chunked = False
+            if isinstance(payload[1], np.ndarray):
+                payload = self._payload_to_device(payload)
+        if payload[0] == "panel_chunked":
+            # producer-side chunked layout (stream_chunks): the host
+            # sort already ran on the producer thread, so both
+            # streamed dispatch AND cache staging use these chunks
+            (_, i32, f32, (ci, cl, cv), binary, b_cap, d2, u_cap,
+             n_uniq) = payload
+            layout = "panel"
+            chunked = True
+        else:
+            layout, i32, f32, binary, b_cap, d2, u_cap, n_uniq = payload
+            chunked = False
         wc = want_counts if is_train else False
         staging = (cache is not None and cache.staging
                    and layout == "panel" and is_train)
@@ -2753,10 +2780,10 @@ class SGDLearner(Learner):
             chunked = True
         if chunked:
             dev_payload = ("panel_chunked", i32, f32, ci, cl, cv, b_cap,
-                           d2, u_cap, wc, binary, blk.size)
+                           d2, u_cap, wc, binary, blk.size, n_uniq)
         else:
             dev_payload = (layout, i32, f32, b_cap, d2, u_cap, wc,
-                           binary, blk.size)
+                           binary, blk.size, n_uniq)
         self._dispatch_packed(job_type, dev_payload, pending,
                               label=blk.label)
         if is_train and self._wal is not None:
@@ -2777,7 +2804,7 @@ class SGDLearner(Learner):
                     0 if cv is None else cv.nbytes)
                 cache.add(part,
                           ("panel_chunked", i32, f32, ci, cl, cv, b_cap,
-                           d2, u_cap, wc, binary, blk.size),
+                           d2, u_cap, wc, binary, blk.size, n_uniq),
                           nbytes, capacity=self.store.state.capacity)
                 # start the pair-replay compile while this staging pass
                 # still streams (it has ~30s of host/transfer time to
@@ -2794,7 +2821,7 @@ class SGDLearner(Learner):
             else:
                 cache.add(part,
                           (layout, i32, f32, b_cap, d2, u_cap, wc,
-                           binary, blk.size),
+                           binary, blk.size, n_uniq),
                           nbytes, capacity=self.store.state.capacity)
 
     def _wal_touch(self, layout: str, i32, b_cap: int, d2: int,

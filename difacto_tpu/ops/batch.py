@@ -423,6 +423,37 @@ def bucket(n: int, minimum: int = 8) -> int:
     return b
 
 
+def row_cap(n: int, minimum: int = 8) -> int:
+    """Round the step's unique-row dimension (the ``<job>.u`` cap) up to
+    its rung: bucket()'s ladder with eighths between the powers of two.
+
+    Rungs are (8+i)/8 * m*2^j, i = 0..7, m = ``minimum``: at most 12.5% of
+    the cap is padding where bucket() leaves up to 33%. Every leg of the
+    step is sized by this dimension (or by chunk_cap of it), so its
+    padding is paid in device time by every step. A fine rung (i not 0
+    or 4) is used only where it is a multiple of 1024 rows and above
+    8192: the dimension is minor in the narrow row's transposed operand
+    and major elsewhere, and 8 sublanes x 128 lanes tile it both ways
+    without a pad. Up to 8192 the result is bucket()'s, and every rung of
+    bucket() stays a rung, so a cap taken from an older run is valid."""
+    b = minimum
+    while 2 * b < n:
+        b *= 2
+    if n <= b:
+        return b
+    for i in range(1, 8):
+        eighths = b * (8 + i)
+        if i == 4:
+            rung = b + b // 2
+        elif eighths % 8192 == 0 and eighths > 8 * 8192:
+            rung = eighths // 8
+        else:
+            continue
+        if n <= rung:
+            return rung
+    return 2 * b
+
+
 def mesh_dim_min(dp: int, floor: int = 8) -> int:
     """Bucket minimum that keeps every rung divisible by ``dp``: the
     smallest multiple of 2*dp that is >= floor. Needed because bucket()'s

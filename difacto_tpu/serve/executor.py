@@ -228,6 +228,9 @@ class PredictExecutor:
         n_uniq = len(uniq_slots)
         b_cap = self._shapes.cap("serve.b", blk.size)
         nnz_cap = self._shapes.cap("serve.nnz", blk.nnz)
+        # bucket's ladder, not the trainer's finer row_cap: each rung here
+        # is a compile on the request path and warm_bucket pre-compiles
+        # the set, so four times the rungs is four times the warm-up
         u_cap = self._shapes.cap("serve.u", n_uniq)
         padded = pad_slots_oob(uniq_slots.astype(np.int32), u_cap,
                                store.state.capacity)
