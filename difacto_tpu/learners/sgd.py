@@ -92,11 +92,16 @@ class _DeviceBatchCache:
     """
 
     def __init__(self, budget_mb: int, shared: Optional[dict] = None,
-                 stage_after_pass: int = 0, repadable: bool = False) -> None:
+                 stage_after_pass: int = 0, repadable: bool = False,
+                 placement: str = "one device") -> None:
         """``shared`` is a mutable ``{"used": bytes}`` pool: all caches of
         one learner (training + validation) draw from the SAME
         device_cache_mb budget, so actual HBM held never exceeds the
         configured cap however many job types cache.
+
+        ``placement`` says, for the log, where a staged batch's copies
+        live (the mesh shape: the budget is per HOST and a replicated
+        array is charged once a device, learner._payload_nbytes).
 
         ``repadable``: staged payloads' OOB slot padding can be rewritten
         for a grown table (the single-host dictionary path — slot
@@ -112,6 +117,12 @@ class _DeviceBatchCache:
         self.frozen = False       # True once the budget filled mid-pass
         self.stage_after_pass = stage_after_pass
         self.repadable = repadable
+        self.placement = placement
+        # the part the freeze dropped: {"part", "batches" seen, "bytes"
+        # they were or would have been charged} — the batches that no
+        # longer reach add() are counted (skipped) so the pass's end can
+        # say what budget would have held the part
+        self.dropped: Optional[dict] = None
         self.stale_pads = False   # some payloads padded at an older capacity
         self.passes = 0
         self.capacity: Optional[int] = None  # store capacity at staging
@@ -142,19 +153,35 @@ class _DeviceBatchCache:
         self.used = 0
         log.info("device batch cache invalidated (%s) — streaming", reason)
 
-    def _freeze(self, drop_part: int, reason: str) -> None:
+    def _freeze(self, drop_part: int, nbytes: int) -> None:
         """Budget filled: keep the fully-staged part prefix, drop the
         partially-staged part (a half-cached part can't replay — its
         remaining batches would be lost), stream everything else. Parts
         stage in canonical order, so the kept set is a prefix and
-        replay-then-stream preserves the canonical part order."""
+        replay-then-stream preserves the canonical part order. What the
+        dropped part needed is said when the pass ends (finish_pass)."""
         self.frozen = True
         dropped = self.part_bytes.pop(drop_part, 0)
-        self.entries.pop(drop_part, None)
+        seen = len(self.entries.pop(drop_part, ()))
         self.used -= dropped
         self.shared["used"] -= dropped
-        log.info("device batch cache frozen (%s): keeping %d staged "
-                 "part(s), streaming the rest", reason, len(self.entries))
+        self.dropped = {"part": drop_part, "batches": seen + 1,
+                        "bytes": dropped + nbytes}
+
+    def skipped(self, part: int) -> None:
+        """A batch of the staging pass that add() never saw: counted
+        while the pass is still inside the part the freeze dropped."""
+        d = self.dropped
+        if (d is not None and d["part"] == part
+                and self.passes == self.stage_after_pass):
+            d["bytes"] += d["bytes"] // d["batches"]   # a part's are alike
+            d["batches"] += 1
+
+    @property
+    def charged_bytes_needed(self) -> int:
+        """Bytes the dropped part would have been charged (replicas
+        included); 0 when nothing was dropped."""
+        return 0 if self.dropped is None else self.dropped["bytes"]
 
     def add(self, part: int, payload, nbytes: int,
             capacity: Optional[int] = None) -> None:
@@ -174,7 +201,7 @@ class _DeviceBatchCache:
                     self.invalidate("store capacity grew during staging")
                     return
         if self.shared["used"] + nbytes > self.budget:
-            self._freeze(part, f"budget {self.budget >> 20} MB filled")
+            self._freeze(part, nbytes)
             return
         self.used += nbytes
         self.shared["used"] += nbytes
@@ -184,10 +211,32 @@ class _DeviceBatchCache:
     def finish_pass(self) -> None:
         if self.alive and self.passes == self.stage_after_pass:
             self.ready = bool(self.entries)
+            if self.frozen:
+                self._say_frozen()
             if self.frozen and not self.entries:
                 # nothing fit — permanent streaming, stop probing
                 self.alive = False
         self.passes += 1
+
+    def _say_frozen(self) -> None:
+        """Once, at the end of the pass that froze: loud when nothing
+        is kept (every later epoch streams), quiet when a prefix
+        replays."""
+        need = self.dropped["bytes"]
+        mb = 1 << 20
+        would = -(-(self.shared["used"] + need) // mb)
+        said = ("part %d needed %.1f MB as charged (%d batches on %s), "
+                "device_cache_mb=%d is a per-host budget; "
+                "device_cache_mb>=%d would have held it")
+        args = (self.dropped["part"], need / mb, self.dropped["batches"],
+                self.placement, self.budget // mb, would)
+        if self.entries:
+            log.info("device batch cache frozen, %d staged part(s) "
+                     "replay and the rest streams: " + said,
+                     len(self.entries), *args)
+        else:
+            log.warning("device batch cache holds NOTHING, every epoch "
+                        "streams: " + said, *args)
 
     def iter_parts(self, shuffle: bool, seed: int):
         rng = np.random.RandomState(seed)
@@ -288,7 +337,12 @@ class SGDLearnerParam(Param):
     # HBM budget for the device-resident batch replay cache (0 disables).
     # Single-host hashed-store runs stage each packed batch once and replay
     # it from device memory every later epoch, with no host pack and no
-    # host->device transfer.
+    # host->device transfer. The budget is per HOST, and a staged array is
+    # charged one copy for every device that holds it (_payload_nbytes):
+    # under mesh_fs=4 the batch arrays are replicated over fs, so the same
+    # epoch needs four times the one-chip figure. A cache that cannot hold
+    # the first part says so at warning level and the run streams
+    # (device_cache_state{job} = 0; docs/observability.md).
     device_cache_mb: int = 2048
     # fault tolerance (parallel/fault.py): checkpoint every k epochs to
     # model_out WITH optimizer state (0 = only the final save), and resume
@@ -447,6 +501,27 @@ class SGDLearner(Learner):
             "store_gather_bytes_total",
             "slot-table row bytes gathered+scattered per dispatched "
             "device program").labels(path="train")
+        # what a feature-sharded step puts through the gather's
+        # all-reduce; stays 0 without a mesh (_enqueue)
+        self._exchange_c = self.obs.counter(
+            "store_exchange_bytes_total",
+            "bytes of the all-reduce operand of every dispatched "
+            "feature-sharded step: row cap x lanes x item size, the "
+            "padded operand every fs shard contributes to and receives"
+        ).labels(path="train")
+        self._fs_sharded = self.store.fs_count > 1
+        # what the replay cache came to (_finish_cache_pass)
+        self._cache_g = (
+            self.obs.gauge(
+                "device_cache_state",
+                "replay cache coverage: 2 complete, 1 partial (a part "
+                "prefix replays, the rest streams), 0 off (every epoch "
+                "streams)"),
+            self.obs.gauge(
+                "device_cache_staged_bytes",
+                "bytes the replay cache holds, as charged to "
+                "device_cache_mb (per host: a replicated array once a "
+                "device)"))
         # the fill of the step's unique-row dimension: rows / cap is the
         # share of every cap-sized leg that is not padding (_enqueue)
         cap_c = self.obs.counter(
@@ -1112,7 +1187,9 @@ class SGDLearner(Learner):
         chaos point (step.py), count the table row traffic of
         ``n_steps`` steps (u_cap fused rows pulled, and pushed again
         when training — updaters.gather_bytes; the serve path counts
-        its own under path="serve") and the fill of their row cap
+        its own under path="serve"; under a feature-sharded table the
+        pulled operand is also what the gather's all-reduce moves:
+        ``store_exchange_bytes_total``) and the fill of their row cap
         (``rows`` distinct rows in all, under ``n_steps`` caps of
         ``u_cap``), close an open ``epoch_turn``, and run the body under
         the ``dispatch`` stage (its seconds also land in ``step``) with
@@ -1124,6 +1201,10 @@ class SGDLearner(Learner):
                                u_cap)
         self._gather_c.inc(
             per_dir * n_steps * (2 if job_type == K_TRAINING else 1))
+        if self._fs_sharded:
+            # the pull alone crosses chips: every shard computes every
+            # update from the replicated batch and writes its own rows
+            self._exchange_c.inc(per_dir * n_steps)
         cap_c, rows_c = self._fill_c[job_type == K_TRAINING]
         cap_c.inc(u_cap * n_steps)
         rows_c.inc(rows)
@@ -1176,7 +1257,7 @@ class SGDLearner(Learner):
             # this job's data, so later streamed passes exchange slots
             self._dict_ids_done.add(job_type)
             if cache is not None and not cache.ready:
-                cache.finish_pass()
+                self._finish_cache_pass(job_type, cache)
             return
         self._iterate_parts(job_type, epoch, n_jobs, prog)
 
@@ -1678,6 +1759,8 @@ class SGDLearner(Learner):
                           ("devbatch", batch, slots_dev, nrows_g, gu),
                           self._payload_nbytes((batch, slots_dev)),
                           capacity=self.store.state.capacity)
+            elif cache is not None:
+                cache.skipped(part_idx)
             pending.append((nrows_g, objv, auc))
             if tau > 0:
                 # step done[0] is now in flight on the device — publish
@@ -1880,8 +1963,41 @@ class SGDLearner(Learner):
                 p.device_cache_mb, shared=self._dev_cache_pool,
                 stage_after_pass=0 if (self.store.hashed or dict_single)
                 else 1,
-                repadable=dict_single)
+                repadable=dict_single,
+                placement="one device" if self.mesh is None else
+                "a mesh of dp=%d x fs=%d, one copy of a replicated array "
+                "charged for each of this host's %d devices" % (
+                    p.mesh_dp, p.mesh_fs, len(self.mesh.local_devices)))
         return self._dev_caches[job_type]
+
+    def _finish_cache_pass(self, job_type: int,
+                           cache: _DeviceBatchCache) -> None:
+        """End a pass over the job's data for its replay cache and
+        publish what the cache came to: ``device_cache_state{job}``
+        (2 complete: later epochs replay from HBM; 1 partial: a part
+        prefix replays, the rest streams; 0 off: every epoch streams)
+        and the bytes it holds as charged."""
+        cache.finish_pass()
+        info = self._cache_info(cache)
+        job = "train" if job_type == K_TRAINING else "eval"
+        state_g, bytes_g = self._cache_g
+        state_g.labels(job=job).set(
+            2 if info["complete"] else 1 if info["frozen"] else 0)
+        bytes_g.labels(job=job).set(cache.used)
+
+    @staticmethod
+    def _cache_info(c: _DeviceBatchCache) -> dict:
+        return {
+            "complete": bool(c.ready and c.alive and not c.frozen),
+            # an invalidated cache keeps its frozen flag but holds no
+            # entries — that run is fully streaming, not mixed
+            "frozen": bool(c.frozen and c.entries),
+            "staged_parts": len(c.entries),
+            "staged_mb": round(c.used / (1 << 20), 1),
+            # what the part that the freeze dropped would have been
+            # charged, replicas included (0: nothing was dropped)
+            "charged_bytes_needed": c.charged_bytes_needed,
+        }
 
     def device_cache_info(self) -> dict:
         """Replay-cache coverage after a run, per job type: ``complete``
@@ -1890,17 +2006,8 @@ class SGDLearner(Learner):
         (the staged part prefix replays, the tail streams). Lets callers
         (bench.py e2e) label a "replay" rate honestly instead of assuming
         full coverage."""
-        out = {}
-        for jt, c in getattr(self, "_dev_caches", {}).items():
-            out[jt] = {
-                "complete": bool(c.ready and c.alive and not c.frozen),
-                # an invalidated cache keeps its frozen flag but holds no
-                # entries — that run is fully streaming, not mixed
-                "frozen": bool(c.frozen and c.entries),
-                "staged_parts": len(c.entries),
-                "staged_mb": round(c.used / (1 << 20), 1),
-            }
-        return out
+        return {jt: self._cache_info(c)
+                for jt, c in getattr(self, "_dev_caches", {}).items()}
 
     # ------------------------------------------------ streamed pipeline
     # the streamed-pipeline stages that stage_stats() reports, in its
@@ -2497,7 +2604,7 @@ class SGDLearner(Learner):
         # through the pool's obs snapshot channel — nothing to copy here
         self._report_part(job_type, before, prog)
         if cache is not None:
-            cache.finish_pass()
+            self._finish_cache_pass(job_type, cache)
 
     def _dispatch_packed(self, job_type: int, payload, pending: list,
                          label=None) -> None:
@@ -2655,6 +2762,8 @@ class SGDLearner(Learner):
             cache.add(part, ("devbatch", dev, slots, blk.size, n_uniq),
                       self._payload_nbytes((dev, slots)),
                       capacity=self.store.state.capacity)
+        elif cache is not None:
+            cache.skipped(part)
         if job_type == K_PREDICTION and p.pred_out:
             # stream predictions per batch (SavePred,
             # sgd_learner.cc:231-238) — don't buffer the dataset
@@ -2823,6 +2932,8 @@ class SGDLearner(Learner):
                           (layout, i32, f32, b_cap, d2, u_cap, wc,
                            binary, blk.size, n_uniq),
                           nbytes, capacity=self.store.state.capacity)
+        elif cache is not None:
+            cache.skipped(part)
 
     def _wal_touch(self, layout: str, i32, b_cap: int, d2: int,
                    u_cap: int) -> None:
