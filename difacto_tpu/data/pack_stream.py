@@ -74,6 +74,30 @@ class ShapeSchedule:
         from ..ops.batch import row_cap
         return self.cap(job + ".u", n, ladder=row_cap)
 
+    def chunk_cap(self, job: str, n: int, u_cap: int, cells: int,
+                  dp_div: int = 1) -> int:
+        """The sticky cap of ``job``'s chunk dimension (key ``<job>.c``)
+        for a batch that needs ``n`` chunks (ops.batch.chunks_needed), on
+        the ladder of ``<job>.u``: the backward gathers L rows and adds
+        one partial a chunk, used or not, so its padding is device time
+        in every step. Never above the static bound
+        ops.batch.chunk_cap(u_cap, cells), which no batch of the shape
+        can pass; rounded up to a multiple of ``dp_div`` so the chunk
+        arrays shard evenly over a mesh's dp axis.
+
+        Shapes whose static bound is at most ``STATIC_CHUNKS`` keep it:
+        there the padding is microseconds, while every rung the sticky
+        cap climbs is three compiles (the chunker, the step, the pair
+        program) and a staged batch that replays unpaired."""
+        from ..ops.batch import chunk_cap, row_cap
+        c = chunk_cap(u_cap, cells)
+        if c > self.STATIC_CHUNKS:
+            c = min(self.cap(job + ".c", n, ladder=row_cap), c)
+        return -(-c // dp_div) * dp_div
+
+    # the largest static chunk bound that is used as it is (chunk_cap)
+    STATIC_CHUNKS = 8192
+
     def snapshot(self) -> dict:
         with self._lock:
             return dict(self._caps)
@@ -119,7 +143,8 @@ def pack_payload(shapes: ShapeSchedule, cblk, n_lanes: int,
             counts=counts)
         if stream_chunk:
             return ("panel_chunked", i32, f32,
-                    chunk_host(i32, f32, b_cap, width, u_cap, binary),
+                    chunk_host(shapes, job, i32, f32, b_cap, width, u_cap,
+                               binary),
                     binary, b_cap, width, u_cap)
         return ("panel", i32, f32, binary, b_cap, width, u_cap)
     nnz_cap = shapes.cap(job + ".nnz", cblk.nnz, dim_min)
@@ -137,18 +162,39 @@ def payload_rows(payload) -> int:
     return int(payload[1][-2 if payload[0] == "coo" else -1])
 
 
-def chunk_host(i32: np.ndarray, f32: np.ndarray, b_cap: int,
-               width: int, u_cap: int, binary: bool):
-    """Producer-side chunked-run layout for a packed panel (the host twin
-    of the learner's staging-time device chunker): streamed runs then
-    dispatch the fast chunked step instead of the unsorted scatter.
+def payload_chunks(payload, count: bool = False):
+    """The chunks a packed host payload's lanes need, or None where
+    nobody asked: a producer-chunked payload's used chunks are a prefix
+    of its ``chunk_lane``; a plain panel's are counted from its lanes
+    (ops.batch.chunks_needed) when ``count`` says a two-tier layout will
+    be staged from it."""
+    if payload[0] == "panel_chunked":
+        u_cap = payload[7]
+        return int(np.count_nonzero(payload[3][1] < u_cap))
+    if count and payload[0] == "panel":
+        from ..ops.batch import chunks_needed
+        b_cap, width, u_cap = payload[4:7]
+        return chunks_needed(payload[1][:b_cap * width], u_cap)
+    return None
+
+
+def chunk_host(shapes: ShapeSchedule, job: str, i32: np.ndarray,
+               f32: np.ndarray, b_cap: int, width: int, u_cap: int,
+               binary: bool):
+    """Producer-side two-tier chunked-run layout for a packed panel (the
+    host twin of the learner's staging-time device chunker): streamed
+    runs then dispatch the fast chunked step instead of the unsorted
+    scatter. The chunk cap is the sticky ``<job>.c`` of ``shapes``.
     Ragged panels always carry explicit values (zero on pad cells,
     ops/batch._panel_arrays), so pad tokens contribute nothing through
-    chunk_vals; uniform binary panels have no pad cells."""
-    from ..ops.batch import panel_chunk_tokens_np
+    chunk_vals or head_vals; uniform binary panels have no pad cells."""
+    from ..ops.batch import chunks_needed, panel_chunk_tokens_np
     cells = b_cap * width
     fv = None if binary else f32[:cells]
-    return panel_chunk_tokens_np(i32[:cells], fv, u_cap, b_cap, width)
+    C = shapes.chunk_cap(job, chunks_needed(i32[:cells], u_cap), u_cap,
+                         cells)
+    return panel_chunk_tokens_np(i32[:cells], fv, u_cap, b_cap, width,
+                                 C=C, head=True)
 
 
 def _count_distinct(tok: np.ndarray, hash_capacity: int) -> int:

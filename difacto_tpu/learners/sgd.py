@@ -253,7 +253,7 @@ class _DeviceBatchCache:
 # boundary, and the packing helpers it governs are shared between the
 # learner's threads and the worker processes
 from ..data.pack_stream import ShapeSchedule as _ShapeSchedule  # noqa: E402
-from ..data.pack_stream import payload_rows  # noqa: E402
+from ..data.pack_stream import payload_chunks, payload_rows  # noqa: E402
 
 
 @dataclass
@@ -525,12 +525,22 @@ class SGDLearner(Learner):
         # the fill of the step's unique-row dimension: rows / cap is the
         # share of every cap-sized leg that is not padding (_enqueue)
         cap_c = self.obs.counter(
-            "step_row_cap_total",
+            names.STEP_ROW_CAP,
             "unique-row cap (u_cap) of every dispatched step, summed")
         rows_c = self.obs.counter(
-            "step_rows_total",
+            names.STEP_ROWS,
             "distinct table rows of every dispatched step, summed")
-        self._fill_c = {train: (cap_c.labels(job=job), rows_c.labels(job=job))
+        # the fill of the chunked backward's chunk dimension, likewise
+        ccap_c = self.obs.counter(
+            names.STEP_CHUNK_CAP,
+            "chunk cap (C) of every dispatched step that carries a "
+            "chunked-run backward layout, summed")
+        chunks_c = self.obs.counter(
+            names.STEP_CHUNKS,
+            "chunks the lanes of every such step need, summed")
+        self._fill_c = {train: (cap_c.labels(job=job), rows_c.labels(job=job),
+                                ccap_c.labels(job=job),
+                                chunks_c.labels(job=job))
                         for train, job in ((True, "train"), (False, "eval"))}
         self._last_producer_mode = "thread"
         self._flusher = None
@@ -756,41 +766,48 @@ class SGDLearner(Learner):
                                                static_argnums=(3, 4, 5, 6))
 
         # chunked-run variant for cached replays: the backward's per-token
-        # scatter becomes a dense chunk gather+reduce plus a ~U + B*F/L row
-        # scatter (1.35x over the sorted path, 2.0x over unsorted at bench
-        # shapes). The layout is computed on device
-        # ONCE at staging time (_panel_chunk_packed) and replayed with the
-        # cached buffers — streaming epoch 0 keeps the unsorted step, so
-        # this adds exactly one extra compile per run.
-        def panel_chunk_packed(i32, f32, b_cap, width, u_cap, binary):
-            # the chunk arrays are staged PRECOMPUTED (ci+cl+cv): like the
-            # earlier sorted order, deriving them inside every replayed
+        # scatter becomes one gathered head row a lane, a dense chunk
+        # gather+reduce over the rest of each lane's run, and a scatter of
+        # one partial a chunk (ops/batch.PanelBatch). The layout is
+        # computed on device ONCE at staging time (_panel_chunk_packed)
+        # and replayed with the cached buffers — streaming epoch 0 keeps
+        # the unsorted step, so this adds exactly one extra compile per
+        # run.
+        def panel_chunk_packed(i32, f32, b_cap, width, u_cap, binary,
+                               c_cap=None):
+            # the layout is staged PRECOMPUTED (ci+cl+cv+hr+hv): like the
+            # earlier sorted order, deriving it inside every replayed
             # step would break XLA's fusion around the reduction and pay
-            # the argsort per step. Footprint: ~2x the packed i32 per
-            # cached train batch; a budget overflow degrades gracefully
-            # to streaming (cache.add kills the cache), so tight
-            # device_cache_mb budgets lose the replay, not correctness.
+            # the argsort per step. ``c_cap`` is the sticky <job>.c the
+            # host took from the batch's counted chunks (None: the static
+            # bound). Footprint: 64 B a chunk and 4 B a lane, about half
+            # the packed i32 again per cached train batch at the cells'
+            # traffic, never more than 2x it; a budget overflow degrades
+            # gracefully to streaming (cache.add kills the cache), so
+            # tight device_cache_mb budgets lose the replay, not
+            # correctness.
             from ..ops.batch import panel_chunk_tokens_flat
             cells = b_cap * width
             flat = i32[:cells]
             vals = None if binary else f32[:cells]
-            return panel_chunk_tokens_flat(flat, vals, u_cap, b_cap, width)
+            return panel_chunk_tokens_flat(flat, vals, u_cap, b_cap, width,
+                                           C=c_cap, head=True)
 
-        self._panel_chunk_packed = jaxtrace.jit(panel_chunk_packed,
-                                                static_argnums=(2, 3, 4, 5))
+        self._panel_chunk_packed = jaxtrace.jit(
+            panel_chunk_packed, static_argnums=(2, 3, 4, 5, 6))
 
-        def packed_panel_train_chunked(state, i32, f32, ci, cl, cv, b_cap,
+        def packed_panel_train_chunked(state, i32, f32, chunks, b_cap,
                                        width, u_cap, has_cnt, binary):
+            # ``chunks``: the layout as its builder returned it
             pb, slots, counts = unpack_panel(i32, f32, b_cap, width, u_cap,
                                              has_cnt, binary)
             if counts is not None:
                 state = fns.apply_count(state, slots, counts)
-            pb = pb._replace(chunk_idx=ci, chunk_lane=cl, chunk_vals=cv)
-            return train_step(state, pb, slots)
+            return train_step(state, pb.with_chunks(chunks), slots)
 
         self._packed_panel_train_chunked = jaxtrace.jit(
             packed_panel_train_chunked, donate_argnums=0,
-            static_argnums=(6, 7, 8, 9, 10))
+            static_argnums=(4, 5, 6, 7, 8))
 
         def packed_panel_train_raw(state, i32, f32, b_cap, width, u_cap,
                                    binary):
@@ -1181,7 +1198,8 @@ class SGDLearner(Learner):
 
     @contextlib.contextmanager
     def _enqueue(self, job_type: int, u_cap: int, rows: int,
-                 n_steps: int = 1):
+                 n_steps: int = 1, chunks: Optional[int] = None,
+                 chunk_cap: int = 0):
         """The one prologue and accounting of EVERY step-program enqueue
         (single, paired replay, mesh, SPMD): traverse the ``step.device``
         chaos point (step.py), count the table row traffic of
@@ -1189,9 +1207,13 @@ class SGDLearner(Learner):
         when training — updaters.gather_bytes; the serve path counts
         its own under path="serve"; under a feature-sharded table the
         pulled operand is also what the gather's all-reduce moves:
-        ``store_exchange_bytes_total``) and the fill of their row cap
+        ``store_exchange_bytes_total``), the fill of their row cap
         (``rows`` distinct rows in all, under ``n_steps`` caps of
-        ``u_cap``), close an open ``epoch_turn``, and run the body under
+        ``u_cap``) and, where the steps carry a chunked-run backward
+        layout, of their chunk cap (``chunks`` needed in all, under
+        ``n_steps`` caps of ``chunk_cap``; None: no such layout, or one
+        whose chunks nobody counted), close an open ``epoch_turn``, and
+        run the body under
         the ``dispatch`` stage (its seconds also land in ``step``) with
         one ``train_step_seconds`` observation a step."""
         from ..step import fire_step_fault
@@ -1205,9 +1227,13 @@ class SGDLearner(Learner):
             # the pull alone crosses chips: every shard computes every
             # update from the replicated batch and writes its own rows
             self._exchange_c.inc(per_dir * n_steps)
-        cap_c, rows_c = self._fill_c[job_type == K_TRAINING]
+        cap_c, rows_c, ccap_c, chunks_c = self._fill_c[
+            job_type == K_TRAINING]
         cap_c.inc(u_cap * n_steps)
         rows_c.inc(rows)
+        if chunks is not None:
+            ccap_c.inc(chunk_cap * n_steps)
+            chunks_c.inc(chunks)
         self._end_turn()
         st = stage(self.obs, names.DISPATCH, also=(names.STEP,),
                    epoch=self._epoch, step_num=self._step_num)
@@ -1756,7 +1782,9 @@ class SGDLearner(Learner):
                 # global payloads, same device counts per host on a
                 # uniform mesh), so alive flips in lockstep
                 cache.add(part_idx,
-                          ("devbatch", batch, slots_dev, nrows_g, gu),
+                          # no chunk count: the SPMD layout keeps the
+                          # static chunk bound
+                          ("devbatch", batch, slots_dev, nrows_g, None, gu),
                           self._payload_nbytes((batch, slots_dev)),
                           capacity=self.store.state.capacity)
             elif cache is not None:
@@ -2047,15 +2075,17 @@ class SGDLearner(Learner):
         if item[0] != "ready":
             return
         payload = item[2]
+        caps = {}
         if payload[0] == "panel_chunked":
             b_cap, d2, u_cap = payload[5], payload[6], payload[7]
             wkey = job + ".w"
+            caps[job + ".c"] = self._chunk_cap_of(payload)
         else:
             b_cap, d2, u_cap = payload[4], payload[5], payload[6]
             wkey = job + (".w" if payload[0] in ("panel", "panel_raw")
                           else ".nnz")
-        self._shapes.absorb({job + ".b": b_cap, wkey: d2,
-                             job + ".u": u_cap})
+        caps.update({job + ".b": b_cap, wkey: d2, job + ".u": u_cap})
+        self._shapes.absorb(caps)
 
     def _repad_cache(self, cache: _DeviceBatchCache) -> None:
         """Rewrite every staged payload's OOB slot padding for the LIVE
@@ -2081,12 +2111,12 @@ class SGDLearner(Learner):
         for items in cache.entries.values():
             for i, p in enumerate(items):
                 if p[0] == "panel_chunked":
-                    off = p[6] * p[7]
+                    off = p[4] * p[5]
                     # lint: ok(jax-recompile) statics are the staged
                     # payload's sticky pack-time caps plus the table
                     # capacity — one recompile per GROWTH event, not
                     # per batch (growth is log-bounded by design)
-                    items[i] = (p[0], self._repad_i32(p[1], off, p[8], cap),
+                    items[i] = (p[0], self._repad_i32(p[1], off, p[6], cap),
                                 *p[2:])
                 elif p[0] == "panel":
                     _, i32, f32, b_cap, d2, u_cap = p[:6]
@@ -2108,6 +2138,21 @@ class SGDLearner(Learner):
         cache.stale_pads = False
         log.info("device cache repadded to capacity %d", cap)
 
+    @staticmethod
+    def _chunk_cap_of(payload) -> int:
+        """The chunk cap a ``panel_chunked`` payload (a packer's or a
+        staged one: the layout tuple is its fourth member in both) was
+        laid out at: the length of its ``chunk_lane``."""
+        return payload[3][1].shape[0]
+
+    @classmethod
+    def _pair_statics(cls, payload) -> tuple:
+        """What two staged ``panel_chunked`` batches must share to run as
+        one pair, and (with the table capacity) the key of the executable
+        that runs them: (b_cap, width, u_cap, has_cnt, binary) and the
+        chunk cap the layout was staged at."""
+        return payload[4:9] + (cls._chunk_cap_of(payload),)
+
     def _warm_pair_exec(self, arrays, statics) -> None:
         """Background-compile the two-batches-per-dispatch replay variant
         (packed_panel_train_chunked2) for this payload shape. Launched
@@ -2127,11 +2172,15 @@ class SGDLearner(Learner):
         already handles. unpack_panel with has_counts=False simply never
         reads the (zeroed) tail of the staged f32 buffer.
 
-        The exec key includes the TABLE CAPACITY: a dictionary store can
-        grow between the warm and the replay (an exec compiled at an
-        intermediate capacity would fail the AOT shape check), so a
-        stale-capacity exec is simply never found and the replay entry
-        re-warms at the live capacity."""
+        ``statics`` is :meth:`_pair_statics` of the payload: its static
+        arguments and its chunk cap, which no other static determines (a
+        batch staged before the sticky ``<job>.c`` grew has fewer chunks
+        than its neighbours and must never meet an executable compiled
+        for their shape). The exec key also includes the TABLE CAPACITY:
+        a dictionary store can grow between the warm and the replay (an
+        exec compiled at an intermediate capacity would fail the AOT
+        shape check), so a stale-capacity exec is simply never found and
+        the replay entry re-warms at the live capacity."""
         key = statics + (self.store.state.capacity,)
         if key in self._pair_execs or self.mesh is not None:
             return
@@ -2147,8 +2196,8 @@ class SGDLearner(Learner):
                                                                x.dtype)
 
         state_s = jax.tree_util.tree_map(sds, self.store.state)
-        pa = tuple(sds(t) for t in arrays)
-        b_cap, width, u_cap, _, binary = statics
+        pa = jax.tree_util.tree_map(sds, arrays)
+        b_cap, width, u_cap, _, binary, _ = statics
 
         def build():
             try:
@@ -2201,13 +2250,13 @@ class SGDLearner(Learner):
             # the same prologue and accounting as a single step
             # (_enqueue), for two steps: this is the path a steady
             # replay window runs
-            pa = (a[1], a[2], a[3], a[4], a[5])
-            pb = (b[1], b[2], b[3], b[4], b[5])
-            with self._enqueue(job_type, a[8], a[12] + b[12], n_steps=2):
+            with self._enqueue(job_type, a[6], a[11] + b[11], n_steps=2,
+                               chunks=a[10] + b[10],
+                               chunk_cap=self._chunk_cap_of(a)):
                 self.store.state, o1, a1, o2, a2 = exec_(
-                    self.store.state, pa, pb)
-            pending.append((a[11], o1, a1))
-            pending.append((b[11], o2, a2))
+                    self.store.state, a[1:4], b[1:4])
+            pending.append((a[9], o1, a1))
+            pending.append((b[9], o2, a2))
             self._paired_dispatches = getattr(
                 self, "_paired_dispatches", 0) + 1
         with trace.span(names.TURN_ITER_PARTS, epoch=epoch):
@@ -2226,7 +2275,7 @@ class SGDLearner(Learner):
                                           auc=prog.auc)
                 exec_ = None
                 if is_train and payload[0] == "panel_chunked":
-                    statics = payload[6:11]
+                    statics = self._pair_statics(payload)
                     key = statics + (self.store.state.capacity,)
                     if key not in self._pair_execs:
                         # no exec for this shape AT THIS CAPACITY yet —
@@ -2234,7 +2283,7 @@ class SGDLearner(Learner):
                         # (a resumed process), or the dictionary grew
                         # past the warm-time capacity: compile in the
                         # background, pair from the NEXT epoch on
-                        self._warm_pair_exec(payload[1:6], statics)
+                        self._warm_pair_exec(payload[1:4], statics)
                     exec_ = self._pair_execs.get(key)
                     if isinstance(exec_, Exception):
                         raise RuntimeError(
@@ -2243,11 +2292,12 @@ class SGDLearner(Learner):
                 if exec_ is not None:
                     if held is None:
                         held = payload
-                    elif held[6:11] == payload[6:11]:
+                    elif self._pair_statics(held) == statics:
                         a, held = held, None
                         dispatch_pair(a, payload, exec_)
                     else:
-                        # statics differ (e.g. a ragged-tail shape):
+                        # statics differ (a ragged-tail shape, or a
+                        # batch staged at an older, smaller chunk cap):
                         # dispatch the held one alone, hold this one
                         a, held = held, payload
                         self._dispatch_packed(job_type, a, pending)
@@ -2577,7 +2627,8 @@ class SGDLearner(Learner):
             lease = pool.pop_lease() if use_process else None
             span = pool.last_producer_span if use_process else 0
             if item[0] == "ready":
-                staged = ("ready", item[1], self._stage_payload(item[2]))
+                staged = ("ready", item[1], self._stage_payload(
+                    item[2], self._stages_chunks(job_type, cache)))
                 lookahead.append((part, staged, lease, span))
                 while len(lookahead) > 1:
                     dispatch_entry(lookahead.popleft())
@@ -2612,11 +2663,22 @@ class SGDLearner(Learner):
         = (layout, i32_dev, f32_dev, b_cap, dim2, u_cap, want_counts,
         binary, nrows, n_uniq); dim2 is the panel width or the COO
         nnz_cap, n_uniq (last in every layout) the batch's distinct table
-        rows. Prologue and accounting: :meth:`_enqueue`."""
-        u_cap = (payload[2].shape[0] if payload[0] == "devbatch"
-                 else payload[8] if payload[0] == "panel_chunked"
-                 else payload[5])
-        with self._enqueue(job_type, u_cap, payload[-1]):
+        rows. ``panel_chunked`` carries its chunk layout (the builder's
+        tuple) after f32_dev and the chunks its lanes need before
+        n_uniq; ``devbatch`` = (layout, batch, slots, nrows, chunks,
+        n_uniq). Prologue and accounting: :meth:`_enqueue`."""
+        chunks, chunk_cap = None, 0
+        if payload[0] == "devbatch":
+            u_cap, chunks = payload[2].shape[0], payload[4]
+            if chunks is not None:
+                chunk_cap = payload[1].chunk_lane.shape[0]
+        elif payload[0] == "panel_chunked":
+            u_cap, chunks = payload[6], payload[10]
+            chunk_cap = self._chunk_cap_of(payload)
+        else:
+            u_cap = payload[5]
+        with self._enqueue(job_type, u_cap, payload[-1], chunks=chunks,
+                           chunk_cap=chunk_cap):
             self._dispatch_packed_inner(job_type, payload, pending, label)
 
     def _dispatch_packed_inner(self, job_type: int, payload, pending: list,
@@ -2624,7 +2686,7 @@ class SGDLearner(Learner):
         is_train = job_type == K_TRAINING
         if payload[0] == "devbatch":
             # cached replay of a staged mesh/multi-host global batch
-            _, dev, slots, nrows, _ = payload
+            _, dev, slots, nrows, _, _ = payload
             if is_train:
                 self.store.state, objv, auc = self._train_step(
                     self.store.state, dev, slots)
@@ -2636,14 +2698,14 @@ class SGDLearner(Learner):
         if payload[0] == "panel_chunked":
             # cached replay fast path (train only): packed panel + the
             # staged chunked-run backward layout
-            (_, i32, f32, ci, cl, cv, b_cap, d2, u_cap, want_counts,
-             binary, nrows, _) = payload
+            (_, i32, f32, chunks, b_cap, d2, u_cap, want_counts,
+             binary, nrows, _, _) = payload
             # lint: ok(jax-recompile) payload statics are ShapeSchedule
             # caps / bucket rungs recorded at pack or staging time —
             # bounded by the sticky-cap contract, which provenance
             # cannot follow through the payload tuple and device cache
             self.store.state, objv, auc = self._packed_panel_train_chunked(
-                self.store.state, i32, f32, ci, cl, cv, b_cap, d2, u_cap,
+                self.store.state, i32, f32, chunks, b_cap, d2, u_cap,
                 want_counts, binary)
             pending.append((nrows, objv, auc))
             return
@@ -2723,6 +2785,7 @@ class SGDLearner(Learner):
                                     want_counts, pending, cache, part)
             return
         n_uniq = len(slots_np)
+        n_chunks, chunk_cap = None, 0
         u_cap = self._shapes.row_cap(job, n_uniq)
         b_cap = self._shapes.cap(job + ".b", blk.size, dim_min)
         nnz_cap = self._shapes.cap(job + ".nnz", blk.nnz, dim_min)
@@ -2738,7 +2801,11 @@ class SGDLearner(Learner):
             dev = self._panel_host_batch(
                 cblk, n_uniq, b_cap, width, u_cap,
                 dp_div=self.param.mesh_dp,
-                with_chunks=is_train)
+                with_chunks=is_train, chunk_job=job)
+            if is_train:
+                # used chunks are a prefix; the rest carry lane u_cap
+                n_chunks = int(np.count_nonzero(dev.chunk_lane < u_cap))
+                chunk_cap = len(dev.chunk_lane)
             self._mesh_panel_steps = getattr(
                 self, "_mesh_panel_steps", 0) + 1
         else:
@@ -2751,7 +2818,8 @@ class SGDLearner(Learner):
             c[:len(cnts)] = cnts
             self.store.state = self._apply_count(
                 self.store.state, slots, jnp.asarray(c))
-        with self._enqueue(job_type, u_cap, n_uniq):
+        with self._enqueue(job_type, u_cap, n_uniq, chunks=n_chunks,
+                           chunk_cap=chunk_cap):
             if job_type == K_TRAINING:
                 self.store.state, objv, auc = self._train_step(
                     self.store.state, dev, slots)
@@ -2759,7 +2827,8 @@ class SGDLearner(Learner):
                 pred, objv, auc = self._eval_step(self.store.state, dev,
                                                   slots)
         if cache is not None and cache.staging:
-            cache.add(part, ("devbatch", dev, slots, blk.size, n_uniq),
+            cache.add(part,
+                      ("devbatch", dev, slots, blk.size, n_chunks, n_uniq),
                       self._payload_nbytes((dev, slots)),
                       capacity=self.store.state.capacity)
         elif cache is not None:
@@ -2799,7 +2868,7 @@ class SGDLearner(Learner):
         return self._pack_payload(cblk, n_uniq, padded, b_cap, dim_min,
                                   job, counts=counts)
 
-    def _stage_payload(self, payload):
+    def _stage_payload(self, payload, count_chunks: bool = False):
         """Issue a packed payload's host->device copies NOW (an async
         enqueue on accelerator backends) and return the payload with
         device arrays in place of the numpy ones — the staging half of
@@ -2818,25 +2887,28 @@ class SGDLearner(Learner):
                     and payload[0] in ("panel", "coo"):
                 from ..capacity.tier import route_payload
                 payload = route_payload(self.store.tier, payload)
-            return self._payload_to_device(payload)
+            return self._payload_to_device(payload, count_chunks)
 
     @staticmethod
-    def _payload_to_device(payload):
+    def _payload_to_device(payload, count_chunks: bool = False):
         """A packed host payload with device arrays in place of the
-        numpy ones, and the batch's distinct-row count appended: it is
-        read here, while the i32 buffer's meta words are host memory
-        (_enqueue counts it against the row cap)."""
+        numpy ones, and two counts appended that are read here, while
+        the buffers are host memory: the chunks the batch's lanes need
+        (pack_stream.payload_chunks; a plain panel's are counted only
+        when ``count_chunks`` says the cache will stage a chunk layout
+        from it, else None) and the batch's distinct rows (_enqueue
+        counts both against their caps)."""
+        n_chunks = payload_chunks(payload, count_chunks)
         n_uniq = payload_rows(payload)
         if payload[0] == "panel_chunked":
-            (_, i32, f32, (ci, cl, cv), binary, b_cap, d2,
-             u_cap) = payload
+            (_, i32, f32, chunks, binary, b_cap, d2, u_cap) = payload
             return ("panel_chunked", jnp.asarray(i32), jnp.asarray(f32),
-                    (jnp.asarray(ci), jnp.asarray(cl),
-                     None if cv is None else jnp.asarray(cv)),
-                    binary, b_cap, d2, u_cap, n_uniq)
+                    tuple(None if x is None else jnp.asarray(x)
+                          for x in chunks),
+                    binary, b_cap, d2, u_cap, n_chunks, n_uniq)
         layout, i32, f32, binary, b_cap, d2, u_cap = payload
         return (layout, jnp.asarray(i32), jnp.asarray(f32), binary,
-                b_cap, d2, u_cap, n_uniq)
+                b_cap, d2, u_cap, n_chunks, n_uniq)
 
     def _dispatch_prepared(self, job_type: int, blk, payload,
                            push_cnt: bool, want_counts: bool,
@@ -2861,35 +2933,42 @@ class SGDLearner(Learner):
             return
         with stage(self.obs, names.TRANSFER, epoch=self._epoch):
             if isinstance(payload[1], np.ndarray):
-                payload = self._payload_to_device(payload)
+                payload = self._payload_to_device(
+                    payload, self._stages_chunks(job_type, cache))
+        chunks = None
         if payload[0] == "panel_chunked":
             # producer-side chunked layout (stream_chunks): the host
             # sort already ran on the producer thread, so both
             # streamed dispatch AND cache staging use these chunks
-            (_, i32, f32, (ci, cl, cv), binary, b_cap, d2, u_cap,
+            (_, i32, f32, chunks, binary, b_cap, d2, u_cap, n_chunks,
              n_uniq) = payload
             layout = "panel"
-            chunked = True
         else:
-            layout, i32, f32, binary, b_cap, d2, u_cap, n_uniq = payload
-            chunked = False
+            (layout, i32, f32, binary, b_cap, d2, u_cap, n_chunks,
+             n_uniq) = payload
         wc = want_counts if is_train else False
-        staging = (cache is not None and cache.staging
-                   and layout == "panel" and is_train)
-        if staging and not chunked:
+        if chunks is None and layout == "panel" \
+                and self._stages_chunks(job_type, cache):
             # cache-eligible panel training: build the chunked-run
             # layout ONCE at staging time and dispatch epoch 0 through
             # the SAME chunked step the replays use — one compiled
             # train variant per run, and every epoch takes the chunked
-            # backward
+            # backward. Its chunk cap is the sticky <job>.c, from the
+            # chunks counted while the lanes were host memory (a batch
+            # nobody counted, staged on device before the cache came to
+            # stage, takes the static bound: jit's default)
+            c_cap = None
+            if n_chunks is not None:
+                c_cap = self._shapes.chunk_cap("train", n_chunks, u_cap,
+                                               b_cap * d2)
             # lint: ok(jax-recompile) statics are this batch's sticky
             # pack-time caps — same bounded set the packed step uses
-            ci, cl, cv = self._panel_chunk_packed(i32, f32, b_cap, d2,
-                                                  u_cap, binary)
-            chunked = True
+            chunks = self._panel_chunk_packed(i32, f32, b_cap, d2, u_cap,
+                                              binary, c_cap)
+        chunked = chunks is not None
         if chunked:
-            dev_payload = ("panel_chunked", i32, f32, ci, cl, cv, b_cap,
-                           d2, u_cap, wc, binary, blk.size, n_uniq)
+            dev_payload = ("panel_chunked", i32, f32, chunks, b_cap, d2,
+                           u_cap, wc, binary, blk.size, n_chunks, n_uniq)
         else:
             dev_payload = (layout, i32, f32, b_cap, d2, u_cap, wc,
                            binary, blk.size, n_uniq)
@@ -2904,36 +2983,38 @@ class SGDLearner(Learner):
             if wc and push_cnt:
                 # lint: ok(jax-recompile) u_cap is a sticky pack-time cap
                 f32 = self._zero_counts(f32, u_cap)
+                dev_payload = dev_payload[:2] + (f32,) + dev_payload[3:]
             nbytes = i32.nbytes + f32.nbytes
+            if chunked:
+                nbytes += sum(x.nbytes for x in chunks if x is not None)
             # capacity recorded for the dictionary store: its staged OOB
             # slot padding is only truthful while the table keeps the
             # staging capacity (constant in hashed mode)
-            if chunked and is_train:
-                nbytes += ci.nbytes + cl.nbytes + (
-                    0 if cv is None else cv.nbytes)
-                cache.add(part,
-                          ("panel_chunked", i32, f32, ci, cl, cv, b_cap,
-                           d2, u_cap, wc, binary, blk.size, n_uniq),
-                          nbytes, capacity=self.store.state.capacity)
-                # start the pair-replay compile while this staging pass
-                # still streams (it has ~30s of host/transfer time to
-                # hide the ~18s compile behind) — unless that add just
-                # froze or invalidated the cache (no replay will ever
-                # use the executable), or the cache is repadable (the
-                # dictionary table is still growing this pass: an exec
-                # compiled now would be keyed at a soon-stale capacity;
-                # the replay entry warms it at the frozen capacity and
-                # pairs from epoch 2 on)
-                if cache.staging and not cache.repadable:
-                    self._warm_pair_exec((i32, f32, ci, cl, cv),
-                                         (b_cap, d2, u_cap, wc, binary))
-            else:
-                cache.add(part,
-                          (layout, i32, f32, b_cap, d2, u_cap, wc,
-                           binary, blk.size, n_uniq),
-                          nbytes, capacity=self.store.state.capacity)
+            cache.add(part, dev_payload, nbytes,
+                      capacity=self.store.state.capacity)
+            # start the pair-replay compile while this staging pass
+            # still streams (it has ~30s of host/transfer time to hide
+            # the ~18s compile behind) — unless that add just froze or
+            # invalidated the cache (no replay will ever use the
+            # executable), or the cache is repadable (the dictionary
+            # table is still growing this pass: an exec compiled now
+            # would be keyed at a soon-stale capacity; the replay entry
+            # warms it at the frozen capacity and pairs from epoch 2 on)
+            if chunked and cache.staging and not cache.repadable:
+                self._warm_pair_exec(dev_payload[1:4],
+                                     self._pair_statics(dev_payload))
         elif cache is not None:
             cache.skipped(part)
+
+    @staticmethod
+    def _stages_chunks(job_type: int,
+                       cache: Optional[_DeviceBatchCache]) -> bool:
+        """Whether a panel batch of this job will have a chunk layout
+        built for it on the device: training batches while the replay
+        cache stages. (Asked when the payload goes to the device, to
+        have its chunks counted, and again at dispatch.)"""
+        return (job_type == K_TRAINING and cache is not None
+                and cache.staging)
 
     def _wal_touch(self, layout: str, i32, b_cap: int, d2: int,
                    u_cap: int) -> None:
@@ -2997,7 +3078,8 @@ class SGDLearner(Learner):
                           b_fill: Optional[int] = None,
                           num_rows: Optional[int] = None,
                           force_vals: bool = False,
-                          with_chunks: bool = True):
+                          with_chunks: bool = True,
+                          chunk_job: Optional[str] = None):
         """Host-side (numpy) PanelBatch for the mesh paths — the SAME
         panel + chunked-run layout the single-host packed path stages on
         device (round-4 verdict #1: the mesh step must not fall back to
@@ -3005,12 +3087,21 @@ class SGDLearner(Learner):
         SPMD host ships an all-pad batch so the synchronized schedule
         holds); chunk row ids address the GLOBAL dp row space via
         ``row_base``/``b_fill``; the chunk count rounds up to a multiple
-        of ``dp_div`` so the [C, L] arrays shard evenly over dp."""
-        from ..ops.batch import (PanelBatch, _panel_arrays, chunk_cap,
+        of ``dp_div`` so the [C, L] arrays shard evenly over dp.
+
+        ``chunk_job`` names the sticky-schedule job of a single-process
+        caller, which sees the whole batch: its layout is the two-tier
+        one (head rows where the lane dimension shards evenly over dp)
+        at the sticky ``<job>.c`` cap. Without it (the multi-host SPMD
+        engine, whose hosts must ship identical shapes with no shared
+        schedule, and whose per-host lane blocks concatenate over dp so
+        that lane u's head would not be row u) every token is chunked at
+        the static bound."""
+        from ..ops.batch import (CHUNK_L, PanelBatch, _panel_arrays,
+                                 chunk_cap, chunks_needed,
                                  panel_chunk_tokens_np)
         if b_fill is None:
             b_fill = b_cap
-        C = -(-chunk_cap(u_cap, b_cap * width) // dp_div) * dp_div
         if cblk is not None:
             idx, vals, labels, rweight, row_mask = _panel_arrays(
                 cblk, b_cap, width)
@@ -3027,26 +3118,34 @@ class SGDLearner(Learner):
             labels = np.zeros(b_cap, dtype=np.float32)
             rweight = np.zeros(b_cap, dtype=np.float32)
             row_mask = np.zeros(b_cap, dtype=np.float32)
-        ci = cl = cv = None
-        if with_chunks:
+        chunks = ()
+        if with_chunks and chunk_job is not None:
+            head = u_cap % dp_div == 0
+            flat = idx.reshape(-1)
+            C = self._shapes.chunk_cap(
+                chunk_job, chunks_needed(flat, u_cap, head=head), u_cap,
+                b_cap * width, dp_div)
+            chunks = panel_chunk_tokens_np(
+                flat, None if vals is None else vals.reshape(-1), u_cap,
+                b_fill, width, C=C, row_base=row_base, head=head)
+        elif with_chunks:
+            C = -(-chunk_cap(u_cap, b_cap * width) // dp_div) * dp_div
             if cblk is not None:
                 fv = None if vals is None else vals.reshape(-1)
-                ci, cl, cv = panel_chunk_tokens_np(
+                chunks = panel_chunk_tokens_np(
                     idx.reshape(-1), fv, u_cap, b_fill, width,
                     C=C, row_base=row_base)
             else:
-                from ..ops.batch import CHUNK_L
-                ci = np.full((C, CHUNK_L), b_fill, dtype=np.int32)
-                cl = np.full(C, u_cap, dtype=np.int32)
-                cv = (np.zeros((C, CHUNK_L), dtype=np.float32)
-                      if force_vals else None)
+                chunks = (np.full((C, CHUNK_L), b_fill, dtype=np.int32),
+                          np.full(C, u_cap, dtype=np.int32),
+                          (np.zeros((C, CHUNK_L), dtype=np.float32)
+                           if force_vals else None))
         return PanelBatch(
             idx=idx, vals=vals, labels=labels, rweight=rweight,
             row_mask=row_mask,
             num_rows=np.int32(num_rows if num_rows is not None
                               else (cblk.size if cblk is not None else 0)),
-            num_uniq=np.int32(n_uniq),
-            chunk_idx=ci, chunk_lane=cl, chunk_vals=cv)
+            num_uniq=np.int32(n_uniq)).with_chunks(chunks)
 
     def _save_pred(self, pred: np.ndarray, label) -> None:
         """SavePred (sgd_learner.h:72-83); per-rank output file. The batch

@@ -173,47 +173,78 @@ def _fm_grad_panel_chunked(params: FMParams, pb, p: jnp.ndarray,
     serial per-token update loop (~10 ns/row — half the fused step at
     bench shapes, the round-4 trace's fusion.9); here the per-lane sums
     are computed as a dense vectorised gather+reduce over fixed-L chunks
-    of each lane's token run, and the scatter shrinks to ~U + B*F/L
-    partial rows. Measured 53.3 -> 39.4 ms full-step (1.35x faster than
-    the sorted path it replaced) at bench shapes.
+    of each lane's token run, and the scatter shrinks to one partial row
+    a chunk.
 
-    Padded chunk cells gather row b_cap (out of bounds -> 0); padded
-    chunks carry lane u_cap (out of bounds -> dropped).
+    Two tiers when the batch carries ``head_row``: lane u's first token
+    is gathered straight into row u (no chunk, no partial: a lane touched
+    once costs one gathered row), only tokens 2.. of a run are chunked,
+    and the partials are scatter-added INTO the gathered head rows. A
+    batch without head arrays has every token in a chunk and the partials
+    land in zeros. The same float32 terms either way, added in another
+    order.
+
+    ONE gather serves both tiers: the head rows ride behind the chunk
+    cells as ceil(U/L) more rows of L indices, so the row quantities are
+    read once, from wherever the compiler keeps them (measured on a v5e:
+    a second, separate gather of the U head rows read them from HBM at
+    10 ns a row, 3.0 ms a step, where the chunk gather pays 1.8). The
+    quantities carry one zero row at the end and every index is clipped
+    onto it: padded chunk cells and the heads of untouched lanes point
+    at row b_cap or beyond and so read zeros, with no mask to apply
+    after the gather. Padded chunks carry lane u_cap (out of bounds ->
+    dropped).
 
     ``sorted_chunks`` declares chunk_lane globally ascending — true for
     host-local/single-shard layouts, FALSE for dp-sharded mesh batches
     (each shard's block is sorted but the concatenation is not; lying to
     XLA's scatter lowering would be undefined behavior)."""
     U = params.w.shape[0]
+    C, L = pb.chunk_idx.shape
+    idx, vals = pb.chunk_idx, pb.chunk_vals
+    if pb.head_row is not None:
+        def behind(cells, heads, fill):
+            # the heads as rows of L behind the chunk cells
+            heads = jnp.pad(heads, (0, -U % L), constant_values=fill)
+            return jnp.concatenate([cells, heads.reshape(-1, L)])
+        idx = behind(idx, pb.head_row, p.shape[0])
+        if vals is not None:
+            vals = behind(vals, pb.head_vals, 0)
+
+    def lane_sums(*terms):
+        """Per-cell terms [C (+ ceil(U/L)), L, n_i] -> the lanes' sums
+        [U, sum n_i]: the chunks' partial sums scatter-added into the
+        lanes' own (head) rows, or into zeros without the head tier."""
+        partial = jnp.concatenate([jnp.sum(t, axis=1)[:C] for t in terms],
+                                  axis=1)
+        if pb.head_row is None:
+            own = jnp.zeros((U, partial.shape[1]), jnp.float32)
+        else:
+            own = jnp.concatenate(
+                [t[C:].reshape(-1, t.shape[-1])[:U] for t in terms], axis=1)
+        return own.at[pb.chunk_lane].add(
+            partial, indices_are_sorted=sorted_chunks, mode="drop")
+
     if params.V is None or params.V.shape[1] == 0:
-        toks = p.at[pb.chunk_idx].get(mode="fill", fill_value=0)  # [C, L]
-        if pb.chunk_vals is not None:
-            toks = toks * pb.chunk_vals
-        gw = jnp.zeros((U,), jnp.float32).at[pb.chunk_lane].add(
-            jnp.sum(toks, axis=1), indices_are_sorted=sorted_chunks,
-            mode="drop")
-        return gw, None
+        toks = jnp.pad(p, (0, 1)).at[idx].get(mode="clip")  # [C', L]
+        if vals is not None:
+            toks = toks * vals
+        return lane_sums(toks[:, :, None])[:, 0], None
     k = params.V.shape[1]
     vm = _vmask(params)
     Vm = (params.V * vm.astype(params.V.dtype)[:, None]).astype(jnp.float32)
     row_q = jnp.concatenate([p[:, None] * XV, p[:, None]], axis=1)  # [B,k+1]
-    toks = row_q.at[pb.chunk_idx].get(mode="fill",
-                                      fill_value=0)       # [C, L, k+1]
-    if pb.chunk_vals is None:
+    toks = jnp.pad(row_q, ((0, 1), (0, 0))).at[idx].get(
+        mode="clip")                                       # [C', L, k+1]
+    if vals is None:
         # binary panel: gw == xxp (x == x^2), k+1 columns serve both
-        partial = jnp.sum(toks, axis=1)                    # [C, k+1]
-        red = jnp.zeros((U, k + 1), jnp.float32).at[pb.chunk_lane].add(
-            partial, indices_are_sorted=sorted_chunks, mode="drop")
+        red = lane_sums(toks)
         t1, gw = red[:, :k], red[:, k]
         xxp = gw
     else:
-        v = pb.chunk_vals[:, :, None]                      # [C, L, 1]
-        partial = jnp.concatenate([
-            jnp.sum(toks * v, axis=1),                     # t1 | gw (x v)
-            jnp.sum(toks[:, :, k:] * (v * v), axis=1),     # xxp   (x v^2)
-        ], axis=1)                                         # [C, k+2]
-        red = jnp.zeros((U, k + 2), jnp.float32).at[pb.chunk_lane].add(
-            partial, indices_are_sorted=sorted_chunks, mode="drop")
+        v = vals[:, :, None]                               # [C', L, 1]
+        red = lane_sums(toks * v,                          # t1 | gw (x v)
+                        toks[:, :, k:] * (v * v))          # xxp   (x v^2)
         t1, gw, xxp = red[:, :k], red[:, k], red[:, k + 1]
     gV = (t1 - xxp[:, None] * Vm) * vm[:, None]
     return gw, gV

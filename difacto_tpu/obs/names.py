@@ -1,6 +1,6 @@
 """The names of the tracing system, fixed in one place.
 
-Three vocabularies, each read by somebody outside the program
+Four vocabularies, each read by somebody outside the program
 (docs/observability.md, PERF.md section 3, ``perfbench/spans.py``), so a
 rename here is a change to what a metric reads:
 
@@ -19,6 +19,8 @@ rename here is a change to what a metric reads:
   of the same name with the same start and end.
 - **spans** — host spans that have no counter (children of a stage, or
   boundaries nobody sums).
+- **step fill counters** — what the dispatched steps' padded dimensions
+  hold, summed by ``SGDLearner._enqueue``, label ``job=train|eval``.
 """
 
 from __future__ import annotations
@@ -60,6 +62,17 @@ STAGES = (PARSE, PACK, RING_WAIT, TRANSFER, DISPATCH, FETCH_WAIT, STEP,
 # predates the stage's and dashboards know it
 STAGE_SPAN = {PARSE: "producer.parse", PACK: "producer.pack",
               RING_WAIT: "producer.ring_wait"}
+
+# --------------------------------------------------- step fill counters
+# trace/#metrics only: no benchmark metric reads them yet (PERF.md 7 (3))
+STEP_ROW_CAP = "step_row_cap_total"  # sum of the steps' unique-row caps
+STEP_ROWS = "step_rows_total"        # sum of their distinct table rows
+# of the steps that carry a chunked-run backward layout (ops/batch.py):
+# rows / cap is the fill of every u_cap-sized leg, chunks / cap that of
+# the backward's chunk gather and partial scatter; a sticky cap that
+# grew mid-run shows as a step in the ratio
+STEP_CHUNK_CAP = "step_chunk_cap_total"  # sum of the steps' chunk caps
+STEP_CHUNKS = "step_chunks_total"        # sum of the chunks they need
 
 # ----------------------------------------------------------------- spans
 EPOCH = "epoch"

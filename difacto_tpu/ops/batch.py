@@ -75,15 +75,28 @@ class PanelBatch(NamedTuple):
     row_mask: jnp.ndarray  # f32[B] 1 for real rows
     num_rows: jnp.ndarray  # i32[]
     num_uniq: jnp.ndarray  # i32[]
-    # chunked-run layout (panel_chunk_tokens): the fastest backward. Each
-    # lane's token run is padded into fixed-L gather chunks; the per-token
-    # sorted scatter (a serial ~10 ns/row update loop, half the fused step
-    # at bench shapes) becomes a dense vectorised gather+reduce to per-chunk
-    # partials plus a scatter of only ~U + B*F/L rows. Staged once per
-    # batch like sorted_*.
+    # chunked-run layout (panel_chunk_tokens): the fastest backward. The
+    # per-token sorted scatter (a serial ~10 ns/row update loop, half the
+    # fused step at bench shapes) becomes a dense vectorised gather+reduce
+    # to per-chunk partials plus a scatter of one partial row a chunk.
+    # Two tiers when the head arrays are present: the FIRST token of every
+    # lane's run is read straight into the lane's own row (``head_row``,
+    # no chunk, no partial), and only tokens 2.. of a run are padded into
+    # fixed-L gather chunks, so a batch needs sum(ceil((len - 1) / L))
+    # chunks and a lane touched once costs one gathered row. Without the
+    # head arrays every token is chunked (sum(ceil(len / L)) chunks: one
+    # padded chunk at least a lane). Staged once per batch.
     chunk_idx: Optional[jnp.ndarray] = None   # i32[C, L] token row ids
     chunk_lane: Optional[jnp.ndarray] = None  # i32[C] ascending lanes
     chunk_vals: Optional[jnp.ndarray] = None  # f32[C, L] (None if binary)
+    head_row: Optional[jnp.ndarray] = None    # i32[U] first token's row id
+    head_vals: Optional[jnp.ndarray] = None   # f32[U] (None if binary)
+
+    def with_chunks(self, layout) -> "PanelBatch":
+        """This batch carrying a chunk layout as the builders return it:
+        ``(chunk_idx, chunk_lane, chunk_vals)``, or those and
+        ``(head_row, head_vals)``."""
+        return self._replace(**dict(zip(CHUNK_FIELDS, layout)))
 
     @property
     def batch_cap(self) -> int:
@@ -92,6 +105,10 @@ class PanelBatch(NamedTuple):
     @property
     def width(self) -> int:
         return self.idx.shape[1]
+
+
+CHUNK_FIELDS = ("chunk_idx", "chunk_lane", "chunk_vals", "head_row",
+                "head_vals")
 
 
 def panel_width(blk: RowBlock, batch_cap: int) -> Optional[int]:
@@ -282,84 +299,134 @@ def unpack_panel_raw(i32, f32, batch_cap: int, width: int,
 
 
 # Chunk length of the run-chunked backward layout. L=16 measured fastest at
-# bench shapes (L=8: more chunks to scatter; L=32/64: more gather padding
-# on the zipf run-length distribution).
+# bench shapes when every token was chunked (L=8: more chunks to scatter;
+# L=32/64: more gather padding on the zipf run-length distribution). With
+# the head tier a lane touched once needs no chunk at all, whatever L.
 CHUNK_L = 16
 
 
 def chunk_cap(u_cap: int, cells: int, L: int = CHUNK_L) -> int:
-    """Static chunk-count bound: every one of the <= u_cap lane runs wastes
-    less than one chunk of padding, plus cells/L full chunks."""
+    """Static chunk-count bound, from shapes alone: every one of the
+    <= u_cap lane runs wastes less than one chunk of padding, plus cells/L
+    full chunks. It holds for ANY batch of the shape, with or without the
+    head tier; what a batch needs is counted by :func:`chunks_needed`, and
+    the sticky ``<job>.c`` cap (data/pack_stream.ShapeSchedule.chunk_cap)
+    follows that count and never passes this bound."""
     return u_cap + cells // L + 2
+
+
+def chunks_needed(flat_idx: np.ndarray, u_cap: int, L: int = CHUNK_L,
+                  head: bool = True) -> int:
+    """The chunks a batch's lanes need (host side, one bincount over the
+    panel's cells): sum over lanes of ceil((len - 1) / L) with the head
+    tier, of ceil(len / L) without. A batch of lanes touched once each
+    needs none."""
+    return int(_lane_chunks(np.bincount(flat_idx, minlength=u_cap), L,
+                            head).sum())
+
+
+def _lane_chunks(cnt, L: int, head: bool):
+    """Chunks per lane from tokens per lane (0 for an untouched lane,
+    and with the head tier for a lane touched once)."""
+    return (cnt + (L - 1 - bool(head))) // L
+
+
+def _chunk_layout(xp, order, cnt, vals_sorted, u_cap: int, b_fill: int,
+                  width: int, L: int, C: int, row_base: int, head: bool):
+    """The layout from the lane-sorted token order, gathers and cumsums
+    alone; ``xp`` is numpy or jax.numpy (one definition, so the host and
+    the device builder cannot drift apart). ``order[i]`` is the panel
+    cell of the i-th token in lane order, ``cnt[u]`` lane u's tokens."""
+    cells = order.shape[0]
+    skip = 1 if head else 0
+    run_end = xp.cumsum(cnt)
+    run_start = run_end - cnt                   # lane u's first token
+    n_chunks = _lane_chunks(cnt, L, head)
+    chunk_end = xp.cumsum(n_chunks)
+    cidx = xp.arange(C)
+    # the lane that owns chunk c; u_cap (out of bounds) past the last
+    cl = xp.searchsorted(chunk_end, cidx, side="right")
+    used = cl < u_cap
+    lc = xp.minimum(cl, u_cap - 1)
+    pos = (run_start[lc] + skip
+           + (cidx - (chunk_end - n_chunks)[lc]) * L)[:, None] \
+        + xp.arange(L)[None, :]                 # [C, L] sorted positions
+    valid = (pos < run_end[lc][:, None]) & used[:, None]
+    pos = xp.minimum(pos, cells - 1)
+    ci = xp.where(valid, order[pos] // width + row_base, b_fill)
+    cv = None
+    if vals_sorted is not None:
+        cv = xp.where(valid, vals_sorted[pos], 0).astype(vals_sorted.dtype)
+    out = (ci.astype(xp.int32), xp.where(used, cl, u_cap).astype(xp.int32),
+           cv)
+    if not head:
+        return out
+    first = xp.minimum(run_start, cells - 1)
+    hr = xp.where(cnt > 0, order[first] // width + row_base, b_fill)
+    hv = None
+    if vals_sorted is not None:
+        hv = xp.where(cnt > 0, vals_sorted[first],
+                      0).astype(vals_sorted.dtype)
+    return out + (hr.astype(xp.int32), hv)
 
 
 def panel_chunk_tokens_flat(flat_idx: jnp.ndarray,
                             flat_vals: Optional[jnp.ndarray],
                             u_cap: int, b_cap: int, width: int,
-                            L: int = CHUNK_L):
+                            L: int = CHUNK_L, C: Optional[int] = None,
+                            head: bool = False):
     """Chunked-run backward layout from flat panel lanes (jit-traceable;
     run ONCE per batch at device-cache staging time).
 
-    Tokens are lane-sorted; each lane's contiguous run is split into
-    ceil(len/L) chunks of exactly L gather slots (pad -> ``b_cap``, an
-    out-of-bounds row that gather-fills 0). Returns
+    Tokens are lane-sorted. With ``head`` the first token of each lane's
+    run goes to the head tier and tokens 2.. are split into
+    ceil((len - 1)/L) chunks of exactly L gather slots; without it the
+    whole run is split into ceil(len/L) chunks (pad -> ``b_cap``, past
+    the batch's rows: the backward reads zeros there). Returns
 
       chunk_idx  i32[C, L]  token row ids per chunk,
       chunk_lane i32[C]     ascending output lane per chunk (pad -> u_cap,
                             dropped by the reduction's mode="drop"),
       chunk_vals f32[C, L]  per-token values (None when ``flat_vals`` is),
 
-    with C = chunk_cap(u_cap, cells, L) — a function of static shapes only,
-    so one jit signature serves every batch of a shape schedule. Used
-    chunks form a prefix and their lanes are ascending; runs split across
-    chunks simply scatter-add multiple partials into the same lane."""
+    and with ``head`` also
+
+      head_row   i32[u_cap] the row id of lane u's first token (lanes the
+                            batch does not touch -> ``b_cap``),
+      head_vals  f32[u_cap] its value (None when ``flat_vals`` is).
+
+    ``C`` defaults to chunk_cap(u_cap, cells, L), a function of static
+    shapes only; a caller that has counted the batch's chunks on the host
+    (:func:`chunks_needed`) passes its sticky cap, and chunks past ``C``
+    would be lost. Used chunks form a prefix and their lanes are
+    ascending; runs split across chunks simply scatter-add multiple
+    partials into the same lane."""
     cells = flat_idx.shape[0]
-    C = chunk_cap(u_cap, cells, L)
-    order = jnp.argsort(flat_idx)
-    lane = flat_idx[order].astype(jnp.int32)             # ascending
-    rows = (order // width).astype(jnp.int32)
-    ari = jnp.arange(cells, dtype=jnp.int32)
-    prev = jnp.concatenate([jnp.full((1,), -1, lane.dtype), lane[:-1]])
-    start = lane != prev                                  # run-start flags
-    rid = jnp.cumsum(start.astype(jnp.int32)) - 1         # [cells] run ids
-    RC = u_cap + 1                                        # lanes < u_cap
-    run_start = jnp.full((RC,), cells, jnp.int32).at[rid].min(
-        jnp.where(start, ari, cells), mode="drop")
-    run_len = jnp.zeros((RC,), jnp.int32).at[rid].add(1, mode="drop")
-    n_chunks = (run_len + L - 1) // L
-    chunk_base = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(n_chunks)[:-1]])
-    q = ari - run_start[rid]                              # pos within run
-    c = chunk_base[rid] + q // L                          # ascending
-    cell = c * L + q % L                                  # ascending unique
-    ci = jnp.full((C * L,), b_cap, jnp.int32).at[cell].set(
-        rows, indices_are_sorted=True, unique_indices=True, mode="drop")
-    cl = jnp.full((C,), u_cap, jnp.int32).at[c].set(
-        lane, indices_are_sorted=True, mode="drop")
-    cv = None
-    if flat_vals is not None:
-        cv = jnp.zeros((C * L,), flat_vals.dtype).at[cell].set(
-            flat_vals[order], indices_are_sorted=True, unique_indices=True,
-            mode="drop").reshape(C, L)
-    return ci.reshape(C, L), cl, cv
+    if C is None:
+        C = chunk_cap(u_cap, cells, L)
+    order = jnp.argsort(flat_idx).astype(jnp.int32)
+    cnt = jnp.bincount(flat_idx, length=u_cap)
+    vs = None if flat_vals is None else flat_vals[order]
+    return _chunk_layout(jnp, order, cnt, vs, u_cap, b_cap, width, L, C,
+                         0, head)
 
 
 def panel_chunk_tokens_np(flat_idx: np.ndarray,
                           flat_vals: Optional[np.ndarray],
                           u_cap: int, b_fill: int, width: int,
                           L: int = CHUNK_L, C: Optional[int] = None,
-                          row_base: int = 0):
+                          row_base: int = 0, head: bool = False):
     """Host-side (numpy) twin of :func:`panel_chunk_tokens_flat`, for the
-    mesh/SPMD paths where the chunk layout is built per host at batch-prep
-    time rather than on device at cache-staging time:
+    mesh/SPMD paths and the streamed producers, where the chunk layout is
+    built at batch-prep time rather than on device at cache-staging time:
 
     - ``row_base`` offsets token row ids into the GLOBAL dp-concatenated
       row space (this host's rows live at [row_base, row_base + b_local));
     - ``b_fill`` is the out-of-bounds pad row (the GLOBAL batch cap for
-      sharded batches), so pad cells gather 0 under mode="fill";
-    - ``C`` pins the chunk count explicitly — mesh callers round it up to
-      a multiple of the dp axis so the [C, L] arrays shard evenly and
-      every host ships identical shapes.
+      sharded batches), so pad cells read zeros in the backward;
+    - ``C`` pins the chunk count explicitly — the sticky cap of a
+      schedule, rounded up by mesh callers to a multiple of the dp axis
+      so the [C, L] arrays shard evenly; a batch that needs more raises.
 
     Tokens are lane-sorted per host, so each host's chunk_lane block is
     ascending — but the dp-concatenation of blocks is NOT globally
@@ -368,44 +435,26 @@ def panel_chunk_tokens_np(flat_idx: np.ndarray,
     cells = len(flat_idx)
     if C is None:
         C = chunk_cap(u_cap, cells, L)
+    cnt = np.bincount(flat_idx, minlength=u_cap)[:u_cap]
+    need = int(_lane_chunks(cnt, L, head).sum())
+    if need > C:
+        raise ValueError(f"chunk count {need} exceeds cap {C}")
     order = np.argsort(flat_idx, kind="stable")
-    lane = flat_idx[order].astype(np.int32)
-    rows = (order // width).astype(np.int32) + row_base
-    start = np.empty(cells, dtype=bool)
-    if cells:
-        start[0] = True
-        start[1:] = lane[1:] != lane[:-1]
-    rid = np.cumsum(start) - 1                       # run ids per token
-    run_start = np.nonzero(start)[0]                 # first token of run
-    q = np.arange(cells, dtype=np.int64) - run_start[rid]  # pos in run
-    run_len = np.diff(np.append(run_start, cells))
-    n_chunks = (run_len + L - 1) // L
-    chunk_base = np.concatenate([[0], np.cumsum(n_chunks)[:-1]])
-    c = chunk_base[rid] + q // L
-    cell = c * L + q % L
-    if len(c) and c[-1] >= C:
-        raise ValueError(f"chunk count {c[-1] + 1} exceeds cap {C}")
-    ci = np.full(C * L, b_fill, dtype=np.int32)
-    ci[cell] = rows
-    cl = np.full(C, u_cap, dtype=np.int32)
-    cl[c] = lane
-    cv = None
-    if flat_vals is not None:
-        cv = np.zeros(C * L, dtype=flat_vals.dtype)
-        cv[cell] = flat_vals[order]
-        cv = cv.reshape(C, L)
-    return ci.reshape(C, L), cl, cv
+    vs = None if flat_vals is None else flat_vals[order]
+    return _chunk_layout(np, order, cnt, vs, u_cap, b_fill, width, L, C,
+                         row_base, head)
 
 
-def panel_chunk_tokens(pb: PanelBatch, u_cap: int,
-                       L: int = CHUNK_L) -> PanelBatch:
+def panel_chunk_tokens(pb: PanelBatch, u_cap: int, L: int = CHUNK_L,
+                       C: Optional[int] = None,
+                       head: bool = False) -> PanelBatch:
     """Attach the chunked-run backward layout to a panel batch. ``u_cap``
     is the batch's lane-space size (its slot vector length)."""
     B, F = pb.idx.shape
     flat = pb.idx.reshape(B * F)
     fv = None if pb.vals is None else pb.vals.reshape(B * F)
-    ci, cl, cv = panel_chunk_tokens_flat(flat, fv, u_cap, B, F, L)
-    return pb._replace(chunk_idx=ci, chunk_lane=cl, chunk_vals=cv)
+    return pb.with_chunks(
+        panel_chunk_tokens_flat(flat, fv, u_cap, B, F, L, C, head))
 
 
 def bucket(n: int, minimum: int = 8) -> int:

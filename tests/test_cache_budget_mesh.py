@@ -25,8 +25,10 @@ from difacto_tpu.updaters.sgd_updater import gather_bytes
 ROWS, BATCH, EPOCHS = 2048, 256, 3
 STEPS = ROWS // BATCH
 FS = 4
-# one device is charged 3.8 MB for the epoch, four replicas 14.9 MB
-FITS_ONE, FITS_FOUR = 4, 16
+# one device is charged 4.0 MB for the epoch (3.8 MB before ISSUE 30: a
+# staged batch now carries its head rows, 4 bytes a lane, and shapes
+# this small keep the static chunk bound), four replicas 15.6 MB
+FITS_ONE, FITS_FOUR = 5, 16
 
 
 @pytest.fixture(scope="module")
@@ -125,7 +127,7 @@ def test_a_kept_prefix_is_said_at_info(data, caplog):
     """Two parts, a budget for one: the first replays, the second streams;
     no warning."""
     ln, _, said = _run(data, caplog, num_jobs_per_epoch=2,
-                       device_cache_mb=2)
+                       device_cache_mb=3)
     info = ln.device_cache_info()[K_TRAINING]
     assert info["frozen"] and not info["complete"]
     assert info["staged_parts"] == 1

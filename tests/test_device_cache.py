@@ -360,8 +360,9 @@ def test_stream_chunks_binary_panel(tmp_path):
                                  dim_min=8, job="train", b_cap=128,
                                  stream_chunk=True)
     assert payload[0] == "panel_chunked"
-    ci, cl, cv = payload[3]
-    assert cv is None and payload[4] is True  # binary
+    ci, cl, cv, hr, hv = payload[3]
+    assert cv is None and hv is None and payload[4] is True  # binary
+    assert hr.shape == (payload[7],)
 
 
 def test_non_repadable_cache_invalidates_on_growth_mid_staging():
@@ -391,3 +392,158 @@ def test_stale_non_repadable_cache_invalidates_at_replay(rcv1_path):
                                setup=setup)
     assert not learner._dev_caches[K_TRAINING].alive
     np.testing.assert_allclose(seen, ref, rtol=1e-6, atol=1e-6)
+
+
+# ------------------------------------------- ISSUE 30: the sticky <job>.c
+def _write_two_cap_rows(path: str, batch: int = 256, width: int = 40,
+                        hot_batches: int = 4) -> int:
+    """Binary uniform rows whose FIRST batch touches every lane once or
+    twice (next to no chunk needed: the sticky chunk cap starts low) and
+    whose later batches draw from ``width`` ids only (each lane's run is
+    ``batch`` tokens long: the cap must grow). The shapes are large
+    enough (static chunk bound > ShapeSchedule.STATIC_CHUNKS) for the
+    sticky cap to be in use. Returns the row count."""
+    with open(path, "w") as f:
+        for r in range(batch):
+            ids = range(1000 + r * width, 1000 + (r + 1) * width)
+            f.write(f"{r % 2} " + " ".join(f"{j}:1" for j in ids) + "\n")
+        for r in range(hot_batches * batch):
+            f.write(f"{r % 2} " + " ".join(f"{j}:1" for j in range(
+                500_000, 500_000 + width)) + "\n")
+    return (1 + hot_batches) * batch
+
+
+def _run_two_caps(path, cache_mb, epochs=4):
+    args = dict(data_in=path, data_format="libsvm", loss="fm", V_dim=4,
+                V_threshold=0, lr=0.1, l1=0.01, l2=0, batch_size=256,
+                shuffle=0, max_num_epochs=epochs, num_jobs_per_epoch=1,
+                report_interval=0, stop_rel_objv=0,
+                hash_capacity=1 << 18, producer_mode="thread",
+                device_cache_mb=cache_mb)
+    ln = Learner.create("sgd")
+    assert ln.init([(k, str(v)) for k, v in args.items()]) == []
+    ends = []
+
+    def on_end(epoch, train, _val):
+        import threading
+        for t in threading.enumerate():
+            if t.name == "pair-exec-compile":
+                t.join()
+        ends.append({
+            "rows": train.nrows, "loss": train.loss,
+            "paired": getattr(ln, "_paired_dispatches", 0),
+            "ccap": ln.obs.value("step_chunk_cap_total", job="train"),
+            "chunks": ln.obs.value("step_chunks_total", job="train")})
+
+    ln.add_epoch_end_callback(on_end)
+    ln.run()
+    return ends, ln
+
+
+def test_replay_with_two_chunk_caps_dispatches_the_odd_batch_alone(
+        tmp_path):
+    """A batch staged before the sticky chunk cap grew keeps its smaller
+    layout; the pair-replay executable is keyed by the chunk cap too, so
+    that batch is never handed to a program compiled for its neighbours'
+    shape: it runs alone (as a ragged tail does), the rest pair, every
+    epoch reports all its rows, and the trajectory is the streamed one."""
+    from difacto_tpu.data.pack_stream import ShapeSchedule
+    from difacto_tpu.ops.batch import chunk_cap
+    path = str(tmp_path / "two_caps.libsvm")
+    rows = _write_two_cap_rows(path)
+    ref, _ = _run_two_caps(path, 0)
+    got, ln = _run_two_caps(path, 64)
+    cache = ln._dev_caches[K_TRAINING]
+    assert cache.ready
+    items = [pl for part in cache.entries.values() for pl in part]
+    assert [pl[0] for pl in items] == ["panel_chunked"] * 5
+    b_cap, width, u_cap = items[0][4:7]
+    assert chunk_cap(u_cap, b_cap * width) > ShapeSchedule.STATIC_CHUNKS
+    caps = [pl[3][1].shape[0] for pl in items]
+    need = [pl[10] for pl in items]
+    # the first batch needs a chunk only where two ids share a table
+    # row; the others sixteen a hot lane
+    assert need[0] < 0.1 * b_cap * width / 16
+    assert len(set(need[1:])) == 1 and need[1] >= 16 * (width - 1)
+    assert caps[0] < caps[1] and len(set(caps[1:])) == 1
+    assert caps[1] == ln._shapes.snapshot()["train.c"] < chunk_cap(
+        u_cap, b_cap * width)
+    for pl, c, n in zip(items, caps, need):
+        assert n <= c and pl[3][0].shape == (c, 16)
+        assert int((np.asarray(pl[3][1]) < u_cap).sum()) == n
+    # all other statics agree: the chunk cap alone keeps the odd one out
+    assert len({pl[4:9] for pl in items}) == 1
+    assert len({ln._pair_statics(pl) for pl in items}) == 2
+    # two executables, one a chunk cap, each fed only its own shape
+    keys = [k for k, v in ln._pair_execs.items() if v is not None]
+    assert sorted(k[-2] for k in keys) == sorted(set(caps))
+    for before, after in zip(got[1:], got[2:]):
+        # a replayed epoch: batches 2-5 in two pairs, batch 1 alone
+        assert after["paired"] - before["paired"] == 2
+        assert after["ccap"] - before["ccap"] == sum(caps)
+        assert after["chunks"] - before["chunks"] == sum(need)
+    assert [e["rows"] for e in got] == [rows] * len(got)
+    np.testing.assert_allclose([e["loss"] for e in got],
+                               [e["loss"] for e in ref], rtol=2e-5)
+
+
+def test_stream_chunks_payloads_carry_heads(tmp_path):
+    """stream_chunks=1 (no cache): the producer-side builder ships the
+    two-tier layout (at the static bound: the shapes are small), and the
+    chunk counters see every streamed step."""
+    from conftest import write_uniform_libsvm
+    data = write_uniform_libsvm(str(tmp_path / "u.libsvm"), rows=300,
+                                width=8, id_space=500)
+    _, ln = run_hashed(data, epochs=2, device_cache_mb=0, stream_chunks=1)
+    from difacto_tpu.data import BatchReader
+    from difacto_tpu.ops.batch import chunk_cap, chunks_needed
+    blk = next(iter(BatchReader(data, "libsvm", batch_size=25)))
+    payload = ln._prepare_hashed(blk, want_counts=True, fill_counts=False,
+                                 dim_min=8, job="train", b_cap=32,
+                                 stream_chunk=True)
+    assert payload[0] == "panel_chunked" and len(payload[3]) == 5
+    ci, cl, cv, hr, hv = payload[3]
+    b_cap, width, u_cap = payload[5:8]
+    assert hr.shape == (u_cap,) and (hv is None) == (cv is None)
+    c_cap = chunk_cap(u_cap, b_cap * width)
+    assert cl.shape == (c_cap,)
+    need = chunks_needed(payload[1][:b_cap * width], u_cap)
+    assert int((cl < u_cap).sum()) == need <= c_cap
+    # every lane the batch touches has its head, and no other
+    lanes = np.unique(payload[1][:b_cap * width])
+    assert (np.flatnonzero(hr < b_cap) == lanes).all()
+    ccap = ln.obs.value("step_chunk_cap_total", job="train")
+    chunks = ln.obs.value("step_chunks_total", job="train")
+    steps = 2 * 12                       # 300 rows in batches of 25
+    assert ccap == steps * c_cap and 0 < chunks <= ccap
+
+
+def test_stream_chunks_worker_caps_reach_the_consumer():
+    """A producer-chunked payload packed at a grown chunk cap (a worker
+    process's own schedule) leaves that cap in the consumer's."""
+    from difacto_tpu.data.pack_stream import (ShapeSchedule, chunk_host,
+                                              payload_chunks)
+    from difacto_tpu.learners.sgd import SGDLearner
+    rng = np.random.RandomState(3)
+    b_cap, width, u_cap = 256, 40, 9216
+    cells = b_cap * width
+    lanes = rng.randint(0, 600, cells).astype(np.int32)
+    i32 = np.concatenate([lanes, np.zeros(u_cap + 2, np.int32)])
+    worker = ShapeSchedule()
+    chunks = chunk_host(worker, "train", i32, np.zeros(1, np.float32),
+                        b_cap, width, u_cap, True)
+    c_cap = worker.snapshot()["train.c"]
+    assert chunks[1].shape == (c_cap,) and chunks[3].shape == (u_cap,)
+    payload = ("panel_chunked", i32, None, chunks, True, b_cap, width,
+               u_cap)
+    assert payload_chunks(payload) == int((chunks[1] < u_cap).sum()) > 0
+    ln = SGDLearner.__new__(SGDLearner)
+    ln._shapes = ShapeSchedule()
+    ln._absorb_payload_caps("train", ("ready", None, payload))
+    assert ln._shapes.snapshot() == {"train.b": b_cap, "train.w": width,
+                                     "train.u": u_cap, "train.c": c_cap}
+    # a plain panel's chunks are counted only when somebody will stage
+    # a layout from it
+    plain = ("panel", i32, None, True, b_cap, width, u_cap)
+    assert payload_chunks(plain) is None
+    assert payload_chunks(plain, count=True) == payload_chunks(payload)

@@ -391,3 +391,243 @@ def test_numpy_chunker_and_unsorted_chunks_match():
                                rtol=2e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(gV_s), np.asarray(gV_u),
                                rtol=2e-5, atol=1e-6)
+
+
+# ------------------------------------------------ ISSUE 30: the head tier
+# the first token of a lane's run is read straight into the lane's row,
+# tokens 2.. are chunked, and the chunk cap follows the counted chunks
+_TT_B, _TT_U = 48, 96
+
+
+def _tt_panel(kind: str):
+    """(flat lanes i32[B*F], flat values or None, F): a binary panel with
+    zipf-skewed lanes (hot lanes' runs far longer than CHUNK_L, most
+    lanes touched once or never), a valued one with zero-valued pad
+    cells on lane 0, or one whose lanes are all touched at most once."""
+    import numpy as np
+    rng = np.random.RandomState(30)
+    if kind == "binary":
+        F = 7
+        return (((rng.zipf(1.3, _TT_B * F) - 1) % _TT_U).astype(np.int32),
+                None, F)
+    if kind == "valued":
+        F = 6
+        flat = ((rng.zipf(1.2, _TT_B * F) - 1) % _TT_U).astype(np.int32)
+        vals = rng.rand(_TT_B * F).astype(np.float32)
+        pad = rng.rand(_TT_B * F) < 0.2          # ragged rows' pad cells
+        flat[pad], vals[pad] = 0, 0.0
+        return flat, vals, F
+    assert kind == "singletons"
+    return rng.permutation(_TT_U).astype(np.int32), None, 2
+
+
+def _tt_build(builder: str, flat, vals, F, C, head):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from difacto_tpu.ops.batch import (CHUNK_L, panel_chunk_tokens_flat,
+                                       panel_chunk_tokens_np)
+    if builder == "np":
+        return panel_chunk_tokens_np(flat, vals, _TT_U, _TT_B, F, C=C,
+                                     head=head)
+    out = jax.jit(panel_chunk_tokens_flat,
+                  static_argnums=(2, 3, 4, 5, 6, 7))(
+        jnp.asarray(flat), None if vals is None else jnp.asarray(vals),
+        _TT_U, _TT_B, F, CHUNK_L, C, head)
+    return tuple(None if x is None else np.asarray(x) for x in out)
+
+
+@pytest.mark.parametrize("builder", ["np", "jit"])
+@pytest.mark.parametrize("kind", ["binary", "valued", "singletons"])
+@pytest.mark.parametrize("head", [True, False])
+def test_two_tier_layout_invariants(builder, kind, head):
+    """Both builders: every token's row id appears exactly once over the
+    head and the chunk cells of its lane, chunk lanes ascend, used chunks
+    are a prefix, pads point out of bounds, and the chunks used are the
+    chunks counted: sum over lanes of ceil((len - 1) / L) with the head
+    tier (none at all for lanes touched once), ceil(len / L) without."""
+    import numpy as np
+    from difacto_tpu.ops.batch import CHUNK_L, chunk_cap, chunks_needed
+    flat, vals, F = _tt_panel(kind)
+    B, U, L = len(flat) // F, _TT_U, CHUNK_L
+    lens = np.bincount(flat, minlength=U)
+    want = int(sum(-(-(n - head) // L) for n in lens if n > 0))
+    need = chunks_needed(flat, U, head=head)
+    assert need == want
+    if kind == "singletons" and head:
+        assert need == 0
+    C = need + 3
+    assert C <= chunk_cap(U, B * F)
+    out = _tt_build(builder, flat, vals, F, C, head)
+    assert len(out) == (5 if head else 3)
+    ci, cl, cv = out[:3]
+    assert ci.shape == (C, L) and cl.shape == (C,)
+    assert ci.dtype == np.int32 and cl.dtype == np.int32
+    used = cl < U
+    assert used.sum() == need and used[:need].all()
+    assert (np.diff(cl[used]) >= 0).all()
+    assert (cl[~used] == U).all() and (ci[~used] == B).all()
+    if head:
+        hr, hv = out[3:]
+        assert hr.shape == (U,) and hr.dtype == np.int32
+        assert ((hr == B) == (lens == 0)).all()
+    assert (cv is None) == (vals is None)
+    rows = np.arange(B * F) // F
+    for lane in range(U):
+        cells = ci[cl == lane]
+        got = cells[cells < B]
+        gv = None if cv is None else cv[cl == lane][cells < B]
+        if head and lens[lane]:
+            got = np.append(got, hr[lane])
+            gv = None if gv is None else np.append(gv, hv[lane])
+        mine = flat == lane
+        np.testing.assert_array_equal(np.sort(got), np.sort(rows[mine]))
+        if gv is not None:
+            # the values travel with their tokens
+            np.testing.assert_allclose(np.sort(got * 2.0 + gv),
+                                       np.sort(rows[mine] * 2.0
+                                               + vals[mine]))
+    # a cap the batch does not fit is an error on the host, never a
+    # silently shorter layout
+    if builder == "np" and need:
+        with pytest.raises(ValueError, match="exceeds cap"):
+            _tt_build("np", flat, vals, F, need - 1, head)
+
+
+def _tt_grads(kind, V_dim, layout_of, sorted_chunks=True):
+    """(unsorted scatter backward, chunked backward under the layout
+    ``layout_of(flat, vals, F)``) of one panel and one parameter set."""
+    import jax.numpy as jnp
+    import numpy as np
+    from difacto_tpu.losses import FMParams, fm_grad_panel, fm_predict_panel
+    from difacto_tpu.ops.batch import PanelBatch
+    flat, vals, F = _tt_panel(kind)
+    B, U = len(flat) // F, _TT_U
+    rng = np.random.RandomState(31)
+    params = FMParams(
+        w=jnp.asarray(rng.randn(U).astype(np.float32)),
+        V=(jnp.asarray(rng.randn(U, V_dim).astype(np.float32) * 0.1)
+           if V_dim else None),
+        v_mask=(jnp.asarray((rng.rand(U) > 0.3).astype(np.float32))
+                if V_dim else None))
+    pb = PanelBatch(
+        idx=jnp.asarray(flat.reshape(B, F)),
+        vals=None if vals is None else jnp.asarray(vals.reshape(B, F)),
+        labels=jnp.asarray(rng.choice([0.0, 1.0], B).astype(np.float32)),
+        rweight=jnp.asarray(rng.rand(B).astype(np.float32)),
+        row_mask=jnp.asarray((np.arange(B) < B - 5).astype(np.float32)),
+        num_rows=jnp.asarray(B - 5, jnp.int32),
+        num_uniq=jnp.asarray(U, jnp.int32))
+    pred = fm_predict_panel(params, pb)
+    plain = fm_grad_panel(params, pb, pred)
+    chunked = fm_grad_panel(params, layout_of(pb, flat, vals, F), pred,
+                            sorted_chunks=sorted_chunks)
+    return plain, chunked
+
+
+def _tt_close(plain, chunked, V_dim):
+    import numpy as np
+    np.testing.assert_allclose(np.asarray(plain[0]), np.asarray(chunked[0]),
+                               rtol=2e-5, atol=1e-6)
+    if V_dim:
+        np.testing.assert_allclose(np.asarray(plain[1]),
+                                   np.asarray(chunked[1]),
+                                   rtol=2e-5, atol=1e-6)
+    else:
+        assert plain[1] is None and chunked[1] is None
+
+
+@pytest.mark.parametrize("builder", ["np", "jit"])
+@pytest.mark.parametrize("kind", ["binary", "valued"])
+@pytest.mark.parametrize("V_dim", [6, 0])
+@pytest.mark.parametrize("sorted_chunks", [True, False])
+def test_two_tier_backward_matches_unsorted(builder, kind, V_dim,
+                                            sorted_chunks):
+    """Head rows + chunked rest == the unsorted scatter backward, to
+    float32 rounding: the same terms added in another order."""
+    import jax.numpy as jnp
+    from difacto_tpu.ops.batch import chunks_needed
+
+    def layout_of(pb, flat, vals, F):
+        C = chunks_needed(flat, _TT_U) + 2
+        out = _tt_build(builder, flat, vals, F, C, True)
+        pbc = pb.with_chunks(tuple(None if x is None else jnp.asarray(x)
+                                   for x in out))
+        assert pbc.head_row is not None
+        assert (pbc.head_vals is None) == (vals is None)
+        return pbc
+
+    _tt_close(*_tt_grads(kind, V_dim, layout_of, sorted_chunks), V_dim)
+
+
+@pytest.mark.parametrize("kind", ["binary", "valued"])
+@pytest.mark.parametrize("V_dim", [6, 0])
+def test_headless_panel_batch_is_todays_layout(kind, V_dim):
+    """The contract the benchmark's compile test rests on:
+    ``panel_chunk_tokens_flat`` with five positional arguments returns a
+    3-tuple at the static ``chunk_cap``, and a PanelBatch built with
+    chunk_idx, chunk_lane, chunk_vals and NO head arrays gives the same
+    gradients through the same backward."""
+    from difacto_tpu.ops.batch import (CHUNK_L, PanelBatch, chunk_cap,
+                                       panel_chunk_tokens_flat)
+    assert PanelBatch._field_defaults["head_row"] is None
+    assert PanelBatch._field_defaults["head_vals"] is None
+
+    def layout_of(pb, flat, vals, F):
+        import jax.numpy as jnp
+        out = panel_chunk_tokens_flat(
+            jnp.asarray(flat), None if vals is None else jnp.asarray(vals),
+            _TT_U, _TT_B, F)
+        assert len(out) == 3
+        ci, cl, cv = out
+        assert ci.shape == (chunk_cap(_TT_U, len(flat)), CHUNK_L)
+        pbc = pb._replace(chunk_idx=ci, chunk_lane=cl, chunk_vals=cv)
+        assert pbc.head_row is None and pbc.head_vals is None
+        return pbc
+
+    _tt_close(*_tt_grads(kind, V_dim, layout_of), V_dim)
+
+
+def test_two_tier_terms_are_float32():
+    """No term is narrowed: the head rows and the partials come from one
+    float32 gather of the float32 ``row_q``, whatever the table's
+    dtype."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from difacto_tpu.losses import FMParams
+    from difacto_tpu.losses.fm import _fm_grad_panel_chunked
+    from difacto_tpu.ops.batch import (PanelBatch, chunks_needed,
+                                       panel_chunk_tokens_np)
+    flat, _, F = _tt_panel("binary")
+    B, U, k = len(flat) // F, _TT_U, 4
+    out = panel_chunk_tokens_np(flat, None, U, B, F,
+                                C=chunks_needed(flat, U), head=True)
+    z = jnp.zeros((B,), jnp.float32)
+    pb = PanelBatch(idx=jnp.asarray(flat.reshape(B, F)), vals=None,
+                    labels=z, rweight=z, row_mask=z,
+                    num_rows=jnp.asarray(B, jnp.int32),
+                    num_uniq=jnp.asarray(U, jnp.int32)).with_chunks(
+        tuple(None if x is None else jnp.asarray(x) for x in out))
+    params = FMParams(w=jnp.zeros((U,), jnp.bfloat16),
+                      V=jnp.zeros((U, k), jnp.bfloat16),
+                      v_mask=jnp.ones((U,), jnp.float32))
+    jaxpr = jax.make_jaxpr(
+        lambda p, xv: _fm_grad_panel_chunked(params, pb, p, xv))(
+        z, jnp.zeros((B, k), jnp.float32))
+    gathers = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "gather"]
+    scatters = [e for e in jaxpr.jaxpr.eqns
+                if e.primitive.name == "scatter-add"]
+    # one gather reads the row quantities for both tiers: the chunk cells
+    # and, behind them, the head rows in rows of L
+    assert len(gathers) == 1 and len(scatters) == 1
+    for e in gathers + scatters:
+        assert all(v.aval.dtype == np.float32 for v in e.outvars)
+    C, L = out[0].shape
+    assert U % L == 0
+    assert gathers[0].outvars[0].aval.shape == (C + U // L, L, k + 1)
+    # ... from B rows and one of zeros, which every pad is clipped onto
+    assert gathers[0].invars[0].aval.shape == (B + 1, k + 1)
+    # the partials are added INTO the gathered head rows [U, k+1]
+    assert scatters[0].invars[0].aval.shape == (U, k + 1)
+    assert scatters[0].invars[2].aval.shape == (C, k + 1)

@@ -158,7 +158,7 @@ def _staged(ln, blk: RowBlock, u_cap: int):
     slots = i32[B * WIDTH:B * WIDTH + n_uniq]
     i32, f32 = jnp.asarray(i32), jnp.asarray(f32)
     chunks = ln._panel_chunk_packed(i32, f32, B, WIDTH, u_cap, binary)
-    return (i32, f32, *chunks), binary, slots
+    return (i32, f32, chunks), binary, slots
 
 
 def _copy(state):
@@ -223,6 +223,58 @@ def test_schedule_gives_the_row_dimension_the_fine_ladder():
     assert s.row_cap("train", 294_913) == 327_680
 
 
+def test_schedule_gives_the_chunk_dimension_its_own_sticky_cap():
+    """ISSUE 30: ``<job>.c`` follows the chunks a batch needs, on the
+    ladder of ``<job>.u``, under the static bound."""
+    cells = 65536 * 39
+    # the static bound is what it was: any batch of the shape fits it
+    assert chunk_cap(294_912, cells) == 454_658
+    s = ShapeSchedule()
+    assert s.row_cap("train", 279_000) == 294_912
+    # the cells' traffic: 199,7xx-199,8xx chunks a batch
+    assert s.chunk_cap("train", 199_800, 294_912, cells) == 212_992
+    assert s.snapshot() == {"train.u": 294_912, "train.c": 212_992}
+    # sticky: fewer chunks stay, more climb one fine rung
+    assert s.chunk_cap("train", 150_000, 294_912, cells) == 212_992
+    assert s.chunk_cap("train", 0, 294_912, cells) == 212_992
+    assert s.chunk_cap("train", 212_993, 294_912, cells) == 229_376
+    # a worker seeded from a snapshot packs at the consumer's cap
+    w = ShapeSchedule()
+    w.absorb(s.snapshot())
+    assert w.chunk_cap("train", 10, 294_912, cells) == 229_376
+    # another job's cap is its own; a batch that needs none still gets a
+    # non-empty dimension
+    assert s.chunk_cap("eval", 0, 9216, 256 * 40) == 8
+    # never above the static bound, whatever the rung
+    assert s.chunk_cap("train", 454_000, 294_912, cells) == 454_658
+    assert row_cap(454_000) == 458_752
+    # small shapes keep the static bound, whatever they need, and leave
+    # no sticky key: no rung to climb, nothing to recompile
+    tiny = ShapeSchedule()
+    assert tiny.chunk_cap("train", 3, 64, 16) == chunk_cap(64, 16) == 67
+    assert tiny.chunk_cap("train", 3, 4096, 65536 - 32) == 8192
+    assert tiny.snapshot() == {}
+    assert chunk_cap(4096, 65536) == 8194
+    assert tiny.chunk_cap("train", 3, 4096, 65536) == 8
+    assert tiny.snapshot() == {"train.c": 8}
+    # a mesh's dp axis divides it
+    for dp in (1, 2, 3, 4, 8):
+        c = ShapeSchedule().chunk_cap("train", 199_800, 294_912, cells, dp)
+        assert c % dp == 0 and 212_992 <= c < 212_992 + dp
+
+
+@pytest.mark.parametrize("lo,hi", [b for b in BANDS if b[0] >= FINE_ABOVE])
+def test_chunk_cap_rides_row_caps_ladder_under_the_bound(lo, hi):
+    """For every point of the doubling: the sticky chunk cap of a fresh
+    schedule is ``row_cap`` of the count, cut at the static bound."""
+    cells = 65536 * 39
+    for n in _band(lo, hi):
+        for u_cap in (row_cap(n), 294_912):
+            got = ShapeSchedule().chunk_cap("train", n, u_cap, cells)
+            assert got == min(row_cap(n), chunk_cap(u_cap, cells))
+            assert got >= min(n, chunk_cap(u_cap, cells))
+
+
 def test_absorbed_old_ladder_cap_is_kept():
     s = ShapeSchedule()
     s.absorb({"train.u": 393_216})
@@ -255,6 +307,8 @@ def _wait_pair_compile():
 def _fill(ln) -> dict:
     return {"cap": ln.obs.value("step_row_cap_total", job="train"),
             "rows": ln.obs.value("step_rows_total", job="train"),
+            "ccap": ln.obs.value("step_chunk_cap_total", job="train"),
+            "chunks": ln.obs.value("step_chunks_total", job="train"),
             "paired": getattr(ln, "_paired_dispatches", 0)}
 
 
@@ -281,11 +335,11 @@ def test_counters_advance_by_rows_and_cap_per_step(tmp_path):
     cache = ln._dev_caches[K_TRAINING]
     items = [pl for part in cache.entries.values() for pl in part]
     assert len(items) == steps
-    u_cap = items[0][8]
+    u_cap = items[0][6]
     rows = sum(pl[-1] for pl in items)
     # what each cached batch says of itself is what its buffer holds
     for pl in items:
-        assert pl[0] == "panel_chunked" and pl[8] == u_cap
+        assert pl[0] == "panel_chunked" and pl[6] == u_cap
         assert pl[-1] == int(np.asarray(pl[1])[-1]) <= u_cap
     assert u_cap == ln._shapes.snapshot()["train.u"] == row_cap(
         max(pl[-1] for pl in items))
@@ -299,6 +353,25 @@ def test_counters_advance_by_rows_and_cap_per_step(tmp_path):
     assert after["rows"] - before["rows"] == rows
     assert 0 < after["rows"] / after["cap"] <= 1
     assert ln.obs.value("step_row_cap_total", job="eval") == 0
+    # ISSUE 30: the chunk dimension is counted the same way, from what
+    # each cached batch says of itself: the chunks its lanes need (a
+    # prefix of its chunk_lane) under the cap it was staged at
+    from difacto_tpu.ops.batch import chunks_needed
+    caps = [pl[3][1].shape[0] for pl in items]
+    need = [pl[10] for pl in items]
+    for pl, c, n in zip(items, caps, need):
+        lanes = np.asarray(pl[1])[:pl[4] * pl[5]]
+        assert n == chunks_needed(lanes, u_cap) <= c
+        assert n == int((np.asarray(pl[3][1]) < u_cap).sum())
+        assert len(pl[3]) == 5 and pl[3][3].shape == (u_cap,)
+    # shapes this small keep the static bound
+    assert caps == [chunk_cap(u_cap, items[0][4] * items[0][5])] * steps
+    assert "train.c" not in ln._shapes.snapshot()
+    assert at_end[0]["ccap"] == sum(caps)
+    assert at_end[0]["chunks"] == sum(need) > 0
+    assert after["ccap"] - before["ccap"] == sum(caps)
+    assert after["chunks"] - before["chunks"] == sum(need)
+    assert ln.obs.value("step_chunk_cap_total", job="eval") == 0
 
 
 def test_counters_on_the_coo_and_eval_paths(rcv1_path):

@@ -75,7 +75,7 @@ def test_compiled_step_carries_every_leg(tmp_path, program):
         chunks = jax.eval_shape(
             lambda a, c: ln._panel_chunk_packed(a, c, b, w, u, False),
             i32, f32)
-        pa = (i32, f32, *chunks)
+        pa = (i32, f32, chunks)
         low = ln._packed_panel_train_chunked2.lower(state, pa, pa, b, w, u,
                                                     False, False)
     # the legs are in the program text too (frontend attributes), so the
