@@ -2,7 +2,7 @@
 # (Reference analog: Makefile `make test` + .travis.yml.)
 #
 #   make test   - full pytest suite on a virtual 8-device CPU mesh
-#   make smoke  - bench.py + driver entry smoke (catches broken artifacts)
+#   make smoke  - driver entry smoke (catches a broken artifact)
 #   make ci     - both
 
 PY ?= python
@@ -17,20 +17,19 @@ DATA_OUT ?= $(basename $(DATA_IN)).rec
 
 .PHONY: test smoke ci lint lint-changed lint-baseline lockmap jitmap \
 	hlomap chaos fleet-chaos online-chaos durability-chaos obs-report \
-	convert stream-bench multichip-bench kernel-parity online-bench \
-	capacity-bench durability-bench
+	convert
 
 test:
 	$(PY) -m pytest tests/ -x -q
 
 # difacto-lint (docs/static_analysis.md): compileall as a cheap syntax
 # pass, then the AST analyzer — concurrency/JAX/registry-drift rules
-# over difacto_tpu/, tools/, launch.py, bench.py. Exit 0 = no
+# over difacto_tpu/, tools/, launch.py. Exit 0 = no
 # unsuppressed, non-baselined findings. LINT_FORMAT=github emits PR
 # annotations (ci.yml uses it).
 LINT_FORMAT ?= text
 lint:
-	$(PY) -m compileall -q difacto_tpu tests tools bench.py launch.py
+	$(PY) -m compileall -q difacto_tpu tests tools launch.py
 	$(PY) tools/lint.py --format=$(LINT_FORMAT)
 
 # fast local loop: local rules only on files changed vs the merge-base
@@ -103,16 +102,7 @@ online-chaos:
 durability-chaos:
 	$(PY) -m pytest tests/ -m chaos -q -k "wal or replica or durab"
 
-# fused-kernel acceptance (ISSUE 13): byte-identical trajectories across fused_kernel={off, jnp,
-# pallas-if-available} at fs=1 and fs=4, on-device dedup parity vs the
-# host np.unique, and the pallas gather/scatter kernels bit-for-bit vs
-# the jnp contract (interpret mode off-TPU) — tier-1 time budget
-kernel-parity:
-	$(PY) -m pytest tests/test_fused.py -q -m 'not slow'
-
 smoke:
-	$(PY) bench.py --device-only --steps 2 --batch-size 128 --uniq 256 --capacity 1024 --vdim 4
-	$(PY) bench.py --e2e --e2e-rows 2000 --e2e-batch 256 --capacity 4096 --vdim 4
 	$(PY) -c "import jax, __graft_entry__; \
 	fn, args = __graft_entry__.entry(); \
 	jax.block_until_ready(jax.jit(fn)(*args)); \
@@ -132,32 +122,3 @@ obs-report:
 convert:
 	$(PY) -m difacto_tpu task=convert data_in=$(DATA_IN) \
 	  data_format=$(DATA_FORMAT) data_out=$(DATA_OUT) data_out_format=rec
-
-# streamed-regime bench alone (convert + replay + streamed epochs, with
-# the per-stage breakdown)
-stream-bench:
-	$(PY) bench.py --e2e
-
-# fs-sharded capacity-scaling legs alone: table = base*fs rows per fs
-# rung in {1,2,4,8}, ex/s + per-device bytes per leg (the MULTICHIP
-# metric)
-multichip-bench:
-	$(PY) bench.py --multichip
-
-# serve→log→train→reload steady state (the online.* BENCH section:
-# rows_per_s, train_behind_serve_s_p99, reload_count, label_join_rate)
-online-bench:
-	$(PY) bench.py --online
-
-# table-capacity levers (ISSUE 19):
-# quantized-slot AUC legs at 2x/4x/8x effective capacity vs the fp32
-# baseline + cold-tier hit-rate across zipf skews
-capacity-bench:
-	$(PY) bench.py --capacity
-
-# durability cost/benefit (ISSUE 20; docs/serving.md "Durability &
-# recovery"): wal_overhead_pct (target <=5%), recovery_s for the
-# checkpoint+replay ladder climb, rpo_batches after a simulated
-# mid-window crash (bounded by wal_flush_batches)
-durability-bench:
-	$(PY) bench.py --durability

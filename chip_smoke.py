@@ -235,21 +235,6 @@ def same_loss(tag: str, loss: list, ref: list) -> None:
           f"{tag} loss differs from one chip by {rel:g}")
 
 
-def pallas() -> str:
-    """fused_kernel=pallas on this backend: Mosaic refused both kernels
-    (ops/fused._MOSAIC_REFUSAL), so the knob must raise typed here —
-    never compile-crash mid-run, never fall back to interpret mode."""
-    from difacto_tpu.ops import fused
-    check(not fused.interpret_mode(), "interpret mode on a TPU backend")
-    try:
-        fused.resolve_backend("pallas", V_dim=64)
-    except fused.PallasRefused as e:
-        return f"refused, typed ({type(e).__name__}): {e}"
-    raise SystemExit("chip_smoke: FAILED: fused_kernel=pallas resolved "
-                     "on a TPU backend; ops/fused.py says Mosaic "
-                     "refuses it")
-
-
 def placement() -> None:
     """Four chips, checked FIRST (peak_bytes_in_use is a high-water
     mark): build the fs=4 store at 2^23 rows — the one-chip table's 1 GiB
@@ -302,7 +287,6 @@ def main() -> int:
         say("compile_seconds", round(compiles.seconds, 1))
         say("compile_cache", f"{compiles.hits} hits, "
                              f"{compiles.writes} writes")
-        say("pallas", pallas())
         if not four:
             say("four_chip", f"not run ({dev['count']} device)")
         else:

@@ -41,9 +41,8 @@ Layout:
   view).
 - :mod:`shardflow`   — sharding-flow analysis (``jax-shard-break``
   fs-scoped programs must pin their output layout / no capacity-axis
-  breakers, ``jax-shard-replicate`` no table-sized replication,
-  ``jax-shard-pallas`` pallas kernels only behind the resolve_backend
-  typed guard); the static half of the sharding sentinel
+  breakers, ``jax-shard-replicate`` no table-sized replication); the
+  static half of the sharding sentinel
   (utils/hloscan.py — the compiled-HLO collective/memory scan — is
   the runtime half, tools/hlomap.py the merged view).
 - :mod:`cli`         — ``python -m difacto_tpu.analysis`` /

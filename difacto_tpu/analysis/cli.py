@@ -2,7 +2,7 @@
 ``tools/lint.py`` / ``make lint``.
 
 Defaults match this repo's layout: lint ``difacto_tpu/ tools/
-launch.py bench.py`` against the checked-in baseline at
+launch.py`` against the checked-in baseline at
 ``.lint-baseline.json`` (when present). ``tests/`` and ``docs/`` are
 *reference corpora* for the cross-file registry rules, not lint
 targets — the test suite deliberately tears sockets and swallows
@@ -19,7 +19,7 @@ from pathlib import Path
 
 from . import core
 
-DEFAULT_PATHS = ["difacto_tpu", "tools", "launch.py", "bench.py"]
+DEFAULT_PATHS = ["difacto_tpu", "tools", "launch.py"]
 DEFAULT_BASELINE = ".lint-baseline.json"
 
 

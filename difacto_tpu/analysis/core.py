@@ -198,7 +198,7 @@ class Project:
                  sender_files: Tuple[str, ...] = (
                      "difacto_tpu/serve/client.py",
                      "difacto_tpu/serve/fleet.py",
-                     "tools/", "bench.py", "launch.py"),
+                     "tools/", "launch.py"),
                  kinds_file: str = "difacto_tpu/utils/faultinject.py",
                  metrics_doc: str = "docs/observability.md",
                  metrics_impl_files: Tuple[str, ...] = (

@@ -114,15 +114,6 @@ def _is_jit_name(cn: str) -> bool:
         or cn.endswith(".pjit")
 
 
-def _is_pallas_name(cn: str) -> bool:
-    """``pallas_call`` / ``pl.pallas_call`` / ``jaxtrace.pallas_call``:
-    kernel-invocation sites tracked under the same relpath:lineno
-    identity as jit/pjit (utils/jaxtrace.pallas_call), so the fused
-    table kernels (ops/fused.py) show up in ``make jitmap`` and the
-    runtime gate can match what a traced run observed."""
-    return cn == "pallas_call" or cn.endswith(".pallas_call")
-
-
 def _jit_call_parts(call: ast.Call):
     """(is_jit, keywords) for a ``jit(...)`` / ``partial(jit, ...)``
     call — the partial form carries the jit kwargs on the partial."""
@@ -154,7 +145,6 @@ class JitSite:
     statics: Tuple[int, ...] = ()
     donates: Tuple[int, ...] = ()
     owner: str = ""                 # qual of the function holding the jit()
-    kind: str = "jit"               # "jit" | "pallas" (pallas_call site)
     call_sites: List[ast.Call] = field(default_factory=list)
     unbounded: List[Tuple[ast.Call, int, str]] = field(default_factory=list)
 
@@ -253,28 +243,6 @@ class JaxModel:
                                    node.name, node.name, node,
                                    owner=owner)
                     self.sites[site.site_id] = site
-        # pallas_call kernel sites (ops/fused.py via jaxtrace.pallas_call):
-        # modeled like jit sites — same relpath:lineno identity as the
-        # runtime tracer — so jitmap shows them and a traced run's
-        # observed pallas sites are statically known. No static_argnums
-        # surface (every pallas parameter is a trace-time constant of
-        # the ENCLOSING jit program, whose own statics the compile-key
-        # model already checks), so the sites are warm by construction.
-        for call in sf.call_nodes():
-            if not _is_pallas_name(call_name(call)):
-                continue
-            tname = "<unknown>"
-            if call.args:
-                a0 = call.args[0]
-                if isinstance(a0, ast.Name):
-                    tname = a0.id
-                elif isinstance(a0, ast.Attribute):
-                    tname = dotted(a0)
-            owner = self.cg.owner_of.get(id(call), sf.rel + "::<module>")
-            site = JitSite(f"{sf.rel}:{call.lineno}", sf, call, None,
-                           tname, None, owner=owner, kind="pallas")
-            self.sites[site.site_id] = site
-            self._call_to_site[id(call)] = site
         # declared sync points: utils.jaxtrace.fetch(...)
         for call in sf.call_nodes():
             if _is_fetch_call(call_name(call)):
@@ -673,13 +641,6 @@ class JaxModel:
         out = self._findings["jax-recompile"]
         for sid in sorted(self.sites):
             site = self.sites[sid]
-            if site.kind == "pallas":
-                # a pallas_call is (re)built per TRACE of its enclosing
-                # jit program — immediate invocation and construction
-                # inside traced loops are the API's normal shape; the
-                # compile cache that matters belongs to the enclosing
-                # jit site, which this rule checks on its own
-                continue
             call = site.node
             # jit(f)(x): a fresh wrapper (and compile-cache entry) per
             # invocation — bind the wrapper once instead
@@ -1080,7 +1041,6 @@ class JaxModel:
                 sid: {
                     "target": site.target_name,
                     "bound": site.bound,
-                    "kind": site.kind,
                     "static_argnums": list(site.statics),
                     "donate_argnums": list(site.donates),
                     "call_sites": sorted(

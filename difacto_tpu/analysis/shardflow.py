@@ -3,20 +3,18 @@ provenance through the state-carrying programs.
 
 PRs 12-13 made the slot table mesh-sharded — contiguous fs key ranges
 pinned inside every state-returning program via ``step.state_constrainer``
-(``jax.lax.with_sharding_constraint``) — and made the table kernels
-explicit (``ops/fused.py`` pallas DMA backends behind the
-``resolve_backend`` typed guard). Nothing checked those invariants: one
-jit program that returns state WITHOUT the pin, one op that reorders or
-re-materializes the sharded capacity axis, or one ``pallas_call`` reached
-with a sharded operand silently reintroduces the single-device memory
-wall the key-range sharding exists to avoid (PAPER.md §2). This pass is
+(``jax.lax.with_sharding_constraint``). Nothing checked those
+invariants: one jit program that returns state WITHOUT the pin, or one
+op that reorders or re-materializes the sharded capacity axis, silently
+reintroduces the single-device memory wall the key-range sharding
+exists to avoid (PAPER.md §2). This pass is
 the static half of that guarantee; ``utils/hloscan.py`` (the compiled-HLO
 collective/memory scan) is the runtime half and ``tools/hlomap.py`` the
 merged view — the same static model + runtime tracer + tier-1
 dynamic⊆static pattern as locks (v2), races (v3) and compile/transfer
 flow (v4).
 
-Three rules, all cross-file (they read the call graph + jaxflow model):
+Two rules, both cross-file (they read the call graph + jaxflow model):
 
 - ``jax-shard-break`` — (a) every fs-scoped jit/pjit program that
   donates state must PIN its output layout: ``out_shardings=`` on the
@@ -31,12 +29,6 @@ Three rules, all cross-file (they read the call graph + jaxflow model):
   (non-replicated) sharding in fs-aware code, and donated arguments fed
   from a replicating coercion at an exact call edge (donating a fresh
   replicated copy silently forfeits the sharded in-place update).
-- ``jax-shard-pallas`` — ``pallas_call`` targets reachable outside the
-  typed-error guard: an unguarded exact call edge into a kernel
-  function, or a backend-dispatch argument that did not come from
-  ``ops.fused.resolve_backend`` (the one place that fails typed on
-  ``pallas`` + mesh) or a non-``"pallas"`` literal.
-
 Honest blind spots (docs/static_analysis.md v5 catalog): provenance is
 lexical (scope-chain bindings, one assignment hop) — values laundered
 through containers or object attributes are invisible; fs-scoping keys
@@ -56,7 +48,7 @@ from typing import Dict, List, Optional, Set, Tuple
 from .callgraph import CallGraph, get_callgraph
 from .core import (Finding, Project, SourceFile, call_name, dotted,
                    enclosing_function, rule)
-from .jaxflow import JitSite, _is_pallas_name, get_jax_model
+from .jaxflow import JitSite, get_jax_model
 
 _FUNC_DEFS = (ast.FunctionDef, ast.AsyncFunctionDef)
 
@@ -143,18 +135,14 @@ class ShardModel:
         self.cg: CallGraph = get_callgraph(project)
         self.jax = get_jax_model(project)
         self._findings: Dict[str, List[Finding]] = {
-            "jax-shard-break": [], "jax-shard-replicate": [],
-            "jax-shard-pallas": []}
+            "jax-shard-break": [], "jax-shard-replicate": []}
         self._fn_pins_memo: Dict[int, bool] = {}
         self.pinning_builders: Set[str] = set()       # bare def names
         self.state_programs: Dict[str, dict] = {}     # site_id -> verdict
-        self.kernel_funcs: Set[str] = set()           # quals
-        self.guarded_dispatchers: Dict[str, int] = {} # qual -> param idx
         self._find_pinning_builders()
         self._check_state_programs()
         self._check_axis_breaks()
         self._check_replication()
-        self._check_pallas_reach()
 
     # ------------------------------------------------- pinning builders
     def _find_pinning_builders(self) -> None:
@@ -162,7 +150,7 @@ class ShardModel:
         a layout kwarg (``state_shardings``/``mesh``) and reaches a
         ``state_constrainer``/``with_sharding_constraint`` call, either
         directly or by forwarding the kwarg into another pinning
-        builder (``bench.build_step`` -> ``step.make_step_fns``)."""
+        builder (a wrapper around ``step.make_step_fns``)."""
         defs: Dict[str, List[ast.AST]] = {}
         for sf in self._sources():
             for n in sf.walk():
@@ -388,7 +376,7 @@ class ShardModel:
 
     def _check_state_programs(self) -> None:
         for sid, site in sorted(self.jax.sites.items()):
-            if site.kind != "jit" or not site.donates:
+            if not site.donates:
                 continue
             scope = enclosing_function(site.node) or site.sf.tree
             if not self._fs_aware(scope):
@@ -568,209 +556,15 @@ class ShardModel:
                 return sf
         return site.sf
 
-    # ------------------------------------------ rule 3: pallas guards
-    def _check_pallas_reach(self) -> None:
-        # kernel functions: contain a pallas_call (ops/fused.py DMA
-        # kernels); grown by unguarded exact edges from callers
-        kern: Set[str] = set()
-        for qual, fi in self.cg.funcs.items():
-            if fi.node is None or fi.sf.rel.endswith("utils/jaxtrace.py"):
-                continue
-            for n in ast.walk(fi.node):
-                if isinstance(n, ast.Call) \
-                        and _is_pallas_name(call_name(n)):
-                    kern.add(qual)
-                    break
-        guarded_edges: List[Tuple[str, object]] = []
-        changed = True
-        while changed:
-            changed = False
-            for caller, csites in self.cg.calls.items():
-                if caller in kern or caller.endswith("::<module>"):
-                    continue
-                for cs in csites:
-                    if cs.kind != "call" or cs.fuzzy:
-                        continue
-                    if not any(t in kern for t in cs.targets):
-                        continue
-                    if not self._pallas_guarded(cs.node):
-                        kern.add(caller)
-                        changed = True
-                        break
-                if changed:
-                    break
-        self.kernel_funcs = kern
-        # dispatchers: non-kernel functions whose kernel edges sit under
-        # a `backend == "pallas"` guard on one of their own parameters
-        for caller, csites in self.cg.calls.items():
-            fi = self.cg.funcs.get(caller)
-            if fi is None or fi.node is None or caller in kern:
-                continue
-            for cs in csites:
-                if cs.kind != "call" or cs.fuzzy \
-                        or not any(t in kern for t in cs.targets):
-                    continue
-                idx = self._guard_param_index(cs.node, fi.node)
-                if idx is not None:
-                    self.guarded_dispatchers[caller] = idx
-        # every exact caller of a dispatcher must pass a backend that
-        # went through resolve_backend (or a safe literal)
-        for caller, csites in self.cg.calls.items():
-            for cs in csites:
-                if cs.kind != "call" or cs.fuzzy:
-                    continue
-                for t in cs.targets:
-                    if t in self.guarded_dispatchers:
-                        self._check_dispatch_arg(caller, cs, t)
-
-    def _pallas_guarded(self, node) -> bool:
-        cur = getattr(node, "parent", None)
-        while cur is not None and not isinstance(cur, _FUNC_DEFS):
-            if isinstance(cur, (ast.If, ast.IfExp)) and any(
-                    isinstance(k, ast.Constant) and k.value == "pallas"
-                    for k in ast.walk(cur.test)):
-                return True
-            cur = getattr(cur, "parent", None)
-        return False
-
-    def _guard_param_index(self, call_node, func) -> Optional[int]:
-        """Param index of the dispatcher's own backend guard: the
-        enclosing ``if <name> == "pallas"`` test names a parameter."""
-        cur = getattr(call_node, "parent", None)
-        while cur is not None and cur is not func:
-            if isinstance(cur, (ast.If, ast.IfExp)):
-                for cmp in ast.walk(cur.test):
-                    if not isinstance(cmp, ast.Compare):
-                        continue
-                    sides = [cmp.left] + list(cmp.comparators)
-                    if not any(isinstance(s, ast.Constant)
-                               and s.value == "pallas" for s in sides):
-                        continue
-                    for s in sides:
-                        if isinstance(s, ast.Name):
-                            params = _params_of(func)
-                            if s.id in params:
-                                return params.index(s.id)
-            cur = getattr(cur, "parent", None)
-        return None
-
-    def _check_dispatch_arg(self, caller: str, cs, target: str) -> None:
-        fi = self.cg.funcs.get(target)
-        if fi is None or fi.node is None:
-            return
-        idx = self.guarded_dispatchers[target]
-        params = _params_of(fi.node)
-        pname = params[idx]
-        from .jaxflow import _self_shift
-        shift = _self_shift(fi.node, fi)
-        arg = None
-        pos = idx - shift
-        if 0 <= pos < len(cs.node.args):
-            arg = cs.node.args[pos]
-        for kw in cs.node.keywords:
-            if kw.arg == pname:
-                arg = kw.value
-        if arg is None:
-            # parameter left to its default: safe iff the default is
-            # not the literal "pallas"
-            defaults = fi.node.args.defaults
-            dpos = idx - (len(params) - len(defaults))
-            if 0 <= dpos < len(defaults):
-                d = defaults[dpos]
-                if isinstance(d, ast.Constant) and d.value == "pallas":
-                    arg = d
-            if arg is None:
-                return
-        if self._backend_arg_safe(arg, cs.node):
-            return
-        if self._under_resolved_guard(cs.node):
-            # `if backend == "pallas": ...fm_update_rows(backend="pallas")`
-            # where `backend` itself came from resolve_backend: the
-            # literal is re-stating a proven resolution, not bypassing it
-            return
-        csf = self.cg.funcs[caller].sf if caller in self.cg.funcs \
-            else fi.sf
-        self._findings["jax-shard-pallas"].append(csf.finding(
-            "jax-shard-pallas", cs.node,
-            f"`{fi.node.name}` can reach a pallas_call kernel, but the "
-            f"backend argument `{pname}` did not come from "
-            f"ops.fused.resolve_backend — the one guard that fails "
-            f"typed on pallas + sharded table; route the knob through "
-            f"resolve_backend(knob, mesh=...) so a mesh run cannot "
-            f"reach the GSPMD-opaque kernel"))
-
-    def _under_resolved_guard(self, node) -> bool:
-        """True when ``node`` sits under an ``if <x> == "pallas"`` guard
-        whose tested name is itself resolve_backend-derived (scope-chain
-        binding) — the one sanctioned way to hand a dispatcher the
-        literal backend it already proved."""
-        cur = getattr(node, "parent", None)
-        while cur is not None and not isinstance(cur, _FUNC_DEFS + (
-                ast.Module,)):
-            if isinstance(cur, (ast.If, ast.IfExp)):
-                for cmp in ast.walk(cur.test):
-                    if not isinstance(cmp, ast.Compare):
-                        continue
-                    sides = [cmp.left] + list(cmp.comparators)
-                    if not any(isinstance(s, ast.Constant)
-                               and s.value == "pallas" for s in sides):
-                        continue
-                    for s in sides:
-                        if isinstance(s, ast.Name):
-                            bcall, _ = self._binding_of(node, s.id)
-                            if bcall is not None and _last(call_name(
-                                    bcall)) == "resolve_backend":
-                                return True
-                        if isinstance(s, ast.Attribute) \
-                                and self._backend_arg_safe(s, node):
-                            return True
-            cur = getattr(cur, "parent", None)
-        return False
-
-    def _backend_arg_safe(self, arg, anchor) -> bool:
-        if isinstance(arg, ast.Constant):
-            return arg.value != "pallas"
-        name = None
-        if isinstance(arg, ast.Name):
-            name = arg.id
-        elif isinstance(arg, ast.Attribute):
-            # attribute backends (self._backend): resolve by the
-            # node_key convention — any `.attr = resolve_backend(...)`
-            # binding in the same file sanctions every `.attr` read
-            attr = arg.attr
-            for sf in self._sources():
-                for n in sf.walk():
-                    if isinstance(n, ast.Assign) \
-                            and isinstance(n.value, ast.Call) \
-                            and _last(call_name(n.value)) == \
-                            "resolve_backend" \
-                            and any(isinstance(t, ast.Attribute)
-                                    and t.attr == attr
-                                    for t in n.targets):
-                        return True
-            return False
-        if name is None:
-            return False
-        bcall, _ = self._binding_of(anchor, name)
-        if bcall is None:
-            return False
-        if _last(call_name(bcall)) == "resolve_backend":
-            return True
-        return False
-
     # ----------------------------------------------------------- views
     def to_json(self) -> dict:
         """The static model hlomap and the tier-1 gate consume: the
-        fs-scoped state programs with their pin verdicts, the pallas
-        reachability sets, and the full jit-site universe (dynamic
-        hloscan sites must be a subset)."""
+        fs-scoped state programs with their pin verdicts and the full
+        jit-site universe (dynamic hloscan sites must be a subset)."""
         return {
             "state_programs": {sid: dict(rec) for sid, rec in
                                sorted(self.state_programs.items())},
             "pinning_builders": sorted(self.pinning_builders),
-            "kernel_functions": sorted(self.kernel_funcs),
-            "guarded_dispatchers": {q: i for q, i in sorted(
-                self.guarded_dispatchers.items())},
             "sites": sorted(self.jax.sites),
         }
 
@@ -800,10 +594,3 @@ def check_jax_shard_break(project: Project) -> List[Finding]:
 def check_jax_shard_replicate(project: Project) -> List[Finding]:
     return list(
         get_shard_model(project)._findings["jax-shard-replicate"])
-
-
-@rule("jax-shard-pallas",
-      "pallas_call kernels reachable only through the resolve_backend "
-      "typed guard (pallas is GSPMD-opaque)", cross=True)
-def check_jax_shard_pallas(project: Project) -> List[Finding]:
-    return list(get_shard_model(project)._findings["jax-shard-pallas"])

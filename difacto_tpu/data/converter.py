@@ -185,7 +185,7 @@ class Converter:
     def __init__(self) -> None:
         self.param: ConverterParam | None = None
         # filled by run(): rows, eps, parse_s, write_s, procs, members —
-        # the per-stage convert accounting bench.py reports (convert.*)
+        # the per-stage convert accounting
         self.stats: dict = {}
         self._stage_lock = mutex()
 

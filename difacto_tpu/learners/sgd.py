@@ -463,9 +463,9 @@ class SGDLearner(Learner):
                 "(expected auto|thread|process)")
         # observability (difacto_tpu/obs): each learner instance keeps its
         # OWN registry so stage totals are attributable to this run (two
-        # learners in one process — bench's replay + streamed windows —
-        # must not blur together); producer worker processes report into
-        # it through the pool's snapshot channel (obs/proc.py). The
+        # learners in one process must not blur together); producer
+        # worker processes report into it through the pool's snapshot
+        # channel (obs/proc.py). The
         # stage decomposition lives in stage_seconds_total{stage}, every
         # stage timed by obs.stage (a span and its counter at ONE
         # boundary; the names and their meaning: obs/names.py):
@@ -477,8 +477,7 @@ class SGDLearner(Learner):
         #   epoch_turn = an epoch's final fetch returned -> the next
         #                epoch's first enqueue
         #   compile    = backend-compile seconds (jax.monitoring)
-        # bench.py's e2e.streamed.stages is stage_stats() over this
-        # registry — no private timers.
+        # stage_stats() reads this registry — no private timers.
         from ..obs import Registry, watch_compiles
         from ..obs.stage import stage_counter
         self.obs = Registry()
@@ -2032,14 +2031,14 @@ class SGDLearner(Learner):
         means steady epochs replay entirely from HBM; ``frozen`` means the
         budget filled mid-staging and steady epochs are a MIXED regime
         (the staged part prefix replays, the tail streams). Lets callers
-        (bench.py e2e) label a "replay" rate honestly instead of assuming
-        full coverage."""
+        label a "replay" rate honestly instead of assuming full
+        coverage."""
         return {jt: self._cache_info(c)
                 for jt, c in getattr(self, "_dev_caches", {}).items()}
 
     # ------------------------------------------------ streamed pipeline
     # the streamed-pipeline stages that stage_stats() reports, in its
-    # legacy "<stage>_s" form (bench.py and tests/test_obs.py read them)
+    # legacy "<stage>_s" form (tests/test_obs.py reads them)
     _STAGE_KEYS = ("parse_s", "pack_s", "ring_wait_s", "transfer_s",
                    "step_s")
 
@@ -2048,9 +2047,8 @@ class SGDLearner(Learner):
         read from THE OBS REGISTRY (stage_seconds_total{stage}), so the
         numbers include what producer worker processes reported across
         the process boundary (obs/proc.py) — plus the producer transport
-        that ran. bench.py emits this as ``e2e.streamed.stages`` so a
-        streamed regression localizes to a stage instead of hiding in
-        the headline rate."""
+        that ran, so a streamed regression localizes to a stage instead
+        of hiding in the headline rate."""
         snap = self.obs.snapshot()
         series = snap.get("counters", {}).get("stage_seconds_total", {})
         vals = {dict(k).get("stage", ""): v for k, v in series.items()}

@@ -3,8 +3,8 @@
 The point of key-range sharding the table (mesh.py fs axis; the
 reference's KVStoreDist server sharding) is CAPACITY: an fs-way mesh
 holds an fs-times-larger table at the same per-device HBM. This module
-is the one measurement of that claim, shared by ``bench.py --multichip``
-and the driver's ``__graft_entry__.dryrun_multichip`` leg — for each
+is the one measurement of that claim, driven by the driver's
+``__graft_entry__.dryrun_multichip`` leg and ``tools/hlomap.py`` — for each
 ``fs`` rung it builds a table of ``base_capacity * fs`` rows sharded
 over ``fs`` devices, runs the SAME fused train step the product
 dispatches (panel + chunked backward at dp=1), and reports throughput
@@ -33,8 +33,8 @@ def capacity_scaling_report(fs_values: Optional[Sequence[int]] = None,
     """One leg per fs rung: {fs, hash_capacity, table_bytes_per_device,
     examples_per_sec} plus the cross-rung scaling summary. Rungs that
     exceed the visible device count are skipped (reported in
-    ``skipped_fs``), so the same call works on the 8-chip bench box and
-    a 1-device CPU host."""
+    ``skipped_fs``), so the same call works on an 8-chip host and a
+    1-device CPU host."""
     import jax
     import numpy as np
 
@@ -171,7 +171,7 @@ def bounded_delay_report(hosts_values: Sequence[int] = (1, 2, 4),
                          straggle_factor: float = 1.5,
                          auc_legs: bool = True,
                          seed: int = 0) -> dict:
-    """Bounded-delay (τ) pipelining legs for ``bench.py --multichip``.
+    """Bounded-delay (τ) pipelining legs.
 
     One REAL fs-sharded fused train step (the same compiled program as
     the capacity sweep) is driven through the real windowed pipeline

@@ -24,8 +24,7 @@ a thin router balances rows with power-of-two-choices and retries
 unanswered tails on a peer (router.py), and a shared advisory-locked
 blacklist file propagates one client's endpoint ejection to the whole
 fleet (fleethealth.py). ``task=serve`` (__main__.py) is the CLI entry;
-tools/loadgen.py drives it open-loop; bench.py --serve tracks the
-latency/throughput/resilience trajectory; tests/test_chaos.py proves the
+tools/loadgen.py drives it open-loop; tests/test_chaos.py proves the
 failure paths under injected faults (utils/faultinject.py).
 """
 
@@ -94,7 +93,7 @@ class ServeParam(Param):
     # directory; the tailing trainer (task=online) consumes it. Empty =
     # no logging. NOTE: one log instance per directory — CLI replicas
     # need per-replica directories (or share one in-process OnlineLog
-    # built by the embedding harness, as bench/tests do).
+    # built by the embedding harness, as the tests do).
     online_log_dir: str = ""
     # rows per sealed rec2 segment
     online_segment_rows: int = field(default=256, metadata=dict(lo=1))

@@ -15,7 +15,7 @@ limit.
 shed counters, published through the utils/reporter.py contract (the
 reference's out-of-band progress channel) on a time throttle, and
 snapshot-able on demand (the server's ``#stats`` control line,
-bench.py --serve, tools/loadgen.py).
+tools/loadgen.py).
 """
 
 from __future__ import annotations

@@ -41,8 +41,7 @@ executor, recompiled buckets, and a dropped listening socket. The
   recorded buckets no longer stretches the swap window by compiling
   them one at a time. The swap itself stays atomic and any worker
   failure aborts the whole swap with blue serving; ``last_warm_ms``
-  records the wall-clock warm cost (``bench.py --serve`` emits it as
-  ``serve.warm_parallel_ms``).
+  records the wall-clock warm cost.
 """
 
 from __future__ import annotations
@@ -61,7 +60,7 @@ log = logging.getLogger("difacto_tpu")
 class ModelReloader:
     def __init__(self, executor, model_uri: str, poll_s: float = 0.0,
                  kwargs=(), server=None, warm_workers: int = 4):
-        # server=None (bench/unit use): same-geometry swaps only — there
+        # server=None (unit use): same-geometry swaps only — there
         # is no batcher whose executor reference a blue/green swap could
         # retarget, so a geometry change stays a reload failure
         self._executor = executor

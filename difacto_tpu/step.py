@@ -4,8 +4,8 @@ One device program replaces the reference's 3-thread worker pipeline
 (src/sgd/sgd_learner.h:85-102): gather [w, V] rows from the slot table
 ("Pull"), FM/logit forward, objective + AUC, backward, FTRL/AdaGrad scatter
 update ("Push"). The learner (learners/sgd.py), the driver entry
-(__graft_entry__.py) and the benchmark (bench.py) all build their steps here
-so they can never drift apart.
+(__graft_entry__.py) and the benchmark (perfbench/, through the learner)
+all build their steps here so they can never drift apart.
 
 Batches address the sorted-unique slot vector directly: in-batch collision
 dedup happens on the HOST (store.map_keys_dedup / the producer-thread
@@ -71,19 +71,19 @@ def make_step_fns(fns, loss: LossSpec, train_auc: str = "binned",
     ``state_shardings`` (mesh runs) pins the returned state to the
     table's fs key-range layout — see :func:`state_constrainer`.
 
-    With a fused table backend (``fns.fused`` — fused_kernel=jnp or
-    pallas, ops/fused.py) the train step takes the fused dataflow:
-    ONE row gather whose result is THREADED from the pull to the push
-    (apply_grad_rows), so the push never re-gathers — the composed
-    ("off") path instead relies on XLA CSE to merge its two gathers.
-    Identical primitives either way: trajectories are byte-identical
-    across backends (tests/test_fused.py).
+    The one fork is the table's data format. A fused-row table
+    (``fns.fused``: ``V_dim > 0``) takes ONE row gather whose result is
+    THREADED from the pull to the push (apply_grad_rows), so the push
+    never re-gathers; threading equals re-gathering bit for bit
+    (tests/test_fused.py). A flat ``V_dim = 0`` table has no fused row
+    and composes ``get_rows`` + ``apply_grad`` over its w/z/sqrt_g
+    arrays.
     """
     constrain = state_constrainer(state_shardings)
-    fused = bool(getattr(fns, "fused", False))
+    fused = fns.fused
 
     def pull(state, batch, slots):
-        """(params, slot_vmask, rows-or-None): the fused backends keep
+        """(params, slot_vmask, rows-or-None): a fused-row table keeps
         the gathered rows so train_step can hand them to the push."""
         if fused:
             rows = fns.pull_rows(state, slots)

@@ -84,8 +84,7 @@ def collision_stats(ids: np.ndarray, hash_capacity: int) -> dict:
     fixed capacity (SURVEY §7 hard part (d)). This quantifies the trade:
     ``collided_frac`` is the fraction of distinct ids that share their
     slot with at least one other id (those features' gradients merge
-    permanently). tools/collision_study.py turns this into measured AUC
-    at varying load factors.
+    permanently).
     """
     ids = np.unique(np.asarray(ids, dtype=FEAID_DTYPE))
     slots = hash_slots(reverse_bytes(ids), hash_capacity)
@@ -150,9 +149,7 @@ class SlotStore:
                  initial_capacity: Optional[int] = None, mesh=None,
                  read_only: bool = False):
         self.param = param
-        # the mesh gates fused_kernel backend resolution: the pallas
-        # table kernels require an unsharded table (ops/fused.py)
-        self.fns = make_fns(param, mesh=mesh)
+        self.fns = make_fns(param)
         self.mesh = mesh
         # read-only stores serve inference (serve/, task=pred): lookups
         # never insert into the dictionary, push/apply paths raise, and
@@ -663,8 +660,7 @@ class SlotStore:
         return n
 
     def capacity_stats(self) -> dict:
-        """Effective-capacity accounting of the three levers
-        (bench.py --capacity):
+        """Effective-capacity accounting of the three levers:
         logical addressable rows vs what an fp32/no-tier table of the
         SAME per-device byte budget would hold."""
         import dataclasses

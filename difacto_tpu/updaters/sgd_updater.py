@@ -88,19 +88,6 @@ class SGDUpdaterParam(Param):
     # the compact layout.
     pad_v_rows: bool = True
     pad_v_rows_max_mb: int = 1536
-    # table-kernel backend of the fused SGD hot path (ops/fused.py):
-    # "off" = the composed gather/scatter ops (pull and push gathers
-    # merged only by XLA CSE); "jnp" = the fused single-program path
-    # (the step threads the gathered rows from pull to push and the
-    # FTRL/AdaGrad epilogue scatters once — byte-identical
-    # trajectories, guaranteed single gather); "pallas" = the same
-    # dataflow as pl.pallas_call DMA kernels with the row update folded
-    # into the scatter's epilogue (unsharded tables, interpret mode
-    # off-TPU only: on a TPU backend Mosaic refuses the kernels and
-    # the knob raises ops/fused.PallasRefused); "auto" = jnp.
-    fused_kernel: str = field(default="auto",
-                              metadata=dict(enum=["auto", "pallas",
-                                                  "jnp", "off"]))
     # ---- table-capacity levers (difacto_tpu/capacity/). All default
     # OFF: fp32 + admit-all + no tier is
     # byte-identical to the pre-capacity trajectory.
@@ -350,8 +337,8 @@ def state_bytes(param: SGDUpdaterParam, capacity: int) -> int:
     fs-sharding capacity story is about: per-device residency is
     ``state_bytes / fs`` (parallel/mesh.py fs_shard_bounds), so an
     fs-way mesh holds an fs-times-larger table in the same per-chip
-    HBM. One definition shared by bench.py's multichip capacity legs
-    and the store's shard stats."""
+    HBM. One definition shared by parallel/capacity.py's legs and the
+    store's shard stats."""
     if param.V_dim == 0:
         # four f32 columns (w, z, sqrt_g, cnt) + bool v_live
         return capacity * (4 * 4 + 1)
@@ -478,8 +465,8 @@ def grow_state(param: SGDUpdaterParam, state: SGDState, new_capacity: int
 
 def ftrl_w(w, z, sg, gw, l1: float, l2: float, lr: float, lr_beta: float):
     """The FTRL-proximal w update (UpdateW, sgd_updater.cc:105-131),
-    identical math in both layouts. Module-level so every fused_kernel
-    backend traces the SAME op sequence (ops/fused.py)."""
+    identical math in both layouts (flat ``V_dim = 0`` arrays and
+    fused rows)."""
     g = gw + l2 * w
     sg_new = jnp.sqrt(sg * sg + g * g)
     z_new = z - (g - (sg_new - sg) / lr * w)
@@ -496,10 +483,8 @@ def row_epilogue(param: SGDUpdaterParam, capacity: int, rows: jnp.ndarray,
                  pull_vmask: Optional[jnp.ndarray]) -> jnp.ndarray:
     """The per-row FTRL(w) + AdaGrad(V) update on gathered fused rows
     [n, Wx] -> new rows, WITHOUT the surrounding gather/scatter: the
-    single source of the push math for every fused_kernel backend —
-    the off/jnp paths scatter its result, the pallas kernel traces it
-    per R-row VMEM tile as the scatter's epilogue (ops/fused.py
-    fm_update_rows). ``pull_vmask`` gates AdaGrad to rows whose
+    single source of the push math (apply_grad_rows scatters its
+    result). ``pull_vmask`` gates AdaGrad to rows whose
     embedding was PULLED this batch (lens[i] > 1 semantics,
     sgd_updater.cc:91-96); padded OOB lanes compute garbage that the
     scatter drops."""
@@ -561,31 +546,29 @@ def make_fns(param: SGDUpdaterParam, mesh=None):
     as compile-time constants. Returns a namespace of jit-ready callables
     (not yet jit-wrapped; the store/learner composes and jits them).
 
-    ``mesh`` (the store's SPMD mesh, or None) gates the fused_kernel
-    backend resolution: the pallas kernels require an unsharded table
-    (ops/fused.py resolve_backend)."""
+    ``mesh`` is unused: the functions are the same plain XLA ops with or
+    without a mesh (GSPMD partitions them). The keyword stays only
+    because tests/perfbench/test_perfbench_compile.py, which the
+    benchmark holds, still passes it (ROADMAP D16)."""
+    del mesh
 
     from ..ops import fused
 
     l1, l2 = param.l1, param.l2
     lr, lr_beta = param.lr, param.lr_beta
     has_V = param.V_dim > 0
-    # table-kernel backend of the V>0 hot path ("off" on flat tables —
-    # there is no fused row to kernel over); see SGDUpdaterParam.
     # V_l2 / V_lr / V_lr_beta are read by row_epilogue from ``param``.
-    backend = fused.resolve_backend(param.fused_kernel, mesh=mesh,
-                                    V_dim=param.V_dim)
 
     def _gather(arr, slots):
         # the store guarantees sorted unique slots (map_keys_dedup) with
         # out-of-bounds ASCENDING padding (pad_slots) — the gather-flag
         # contract lives in ops/fused.gather_rows (measured ~20% off
         # the fused step); padded lanes read as zeros (mode=fill)
-        return fused.gather_rows(arr, slots, "jnp")
+        return fused.gather_rows(arr, slots)
 
     def _scatter(arr, slots, rows):
         # padded (out-of-bounds) entries are dropped, real rows are unique
-        return fused.scatter_rows(arr, slots, rows, "jnp")
+        return fused.scatter_rows(arr, slots, rows)
 
     thr = float(param.V_threshold)
 
@@ -597,15 +580,13 @@ def make_fns(param: SGDUpdaterParam, mesh=None):
         return ftrl_w(w, z, sg, gw, l1, l2, lr, lr_beta)
 
     def pull_rows(state: SGDState, slots: jnp.ndarray) -> jnp.ndarray:
-        """ONE full fused-row gather of the batch's unique slots,
-        backend-dispatched (ops/fused.py). The fused train step
-        (step.py) threads the result from pull to push so the push
-        never re-gathers — the "off" path instead relies on XLA CSE
-        merging its two gathers. A partial-row gather (VVg[slots, :k])
-        would lower to a strided gather ~8x slower. V keeps its
-        STORAGE dtype (param.V_dtype) so the loss's per-token gather
-        can ride bf16."""
-        return fused.gather_rows(state.VVg, slots, backend)
+        """ONE full fused-row gather of the batch's unique slots. The
+        train step (step.py) threads the result from pull to push so
+        the push never re-gathers. A partial-row gather
+        (VVg[slots, :k]) would lower to a strided gather ~8x slower. V
+        keeps its STORAGE dtype (param.V_dtype) so the loss's
+        per-token gather can ride bf16."""
+        return _gather(state.VVg, slots)
 
     @names.leg(names.FORWARD)
     def rows_to_params(state: SGDState, rows: jnp.ndarray):
@@ -665,33 +646,19 @@ def make_fns(param: SGDUpdaterParam, mesh=None):
                         rows: jnp.ndarray, gw: jnp.ndarray,
                         gV: Optional[jnp.ndarray],
                         pull_vmask: Optional[jnp.ndarray]) -> SGDState:
-        """Fused kGradient push over rows the step ALREADY gathered
+        """kGradient push over rows the step ALREADY gathered
         (pull_rows): the per-row FTRL/AdaGrad epilogue (row_epilogue)
-        plus ONE scatter. The pallas backend folds the epilogue into
-        the scatter kernel itself (ops/fused.fm_update_rows), so the
-        table row moves through HBM exactly once on the push."""
-        cap = state.capacity
-
-        def epi(r, g, gv, vm):
-            return row_epilogue(param, cap, r, g, gv, vm)
-
-        if backend == "pallas" and gV is not None \
-                and pull_vmask is not None:
-            VVg = fused.fm_update_rows(state.VVg, slots, rows, gw, gV,
-                                       pull_vmask, epi, backend="pallas")
-        else:
-            VVg = _scatter(state.VVg, slots,
-                           epi(rows, gw, gV, pull_vmask))
-        return state._replace(VVg=VVg)
+        plus ONE scatter."""
+        new = row_epilogue(param, state.capacity, rows, gw, gV, pull_vmask)
+        return state._replace(VVg=_scatter(state.VVg, slots, new))
 
     def apply_grad(state: SGDState, slots: jnp.ndarray,
                    gw: jnp.ndarray, gV: Optional[jnp.ndarray],
                    pull_vmask: Optional[jnp.ndarray]) -> SGDState:
         """kGradient push: FTRL(w) + AdaGrad(V). ``slots`` are sorted unique
-        (padding -> TRASH_SLOT, whose gw must be 0). Gathers the fused
-        rows itself (the "off" path's second gather, CSE'd with
-        get_rows' in the composed train step) and delegates the update
-        to apply_grad_rows — one definition of the push math."""
+        (padding -> TRASH_SLOT, whose gw must be 0). The store's eager
+        push: gathers the fused rows itself and delegates the update to
+        apply_grad_rows — one definition of the push math."""
         if not has_V:
             w = _gather(state.w, slots)
             sg = _gather(state.sqrt_g, slots)
@@ -731,10 +698,9 @@ def make_fns(param: SGDUpdaterParam, mesh=None):
     ns.apply_grad = apply_grad
     ns.evaluate = evaluate
     ns.param = param
-    # fused-kernel surface (ops/fused.py; step.py threads rows through
-    # when ``fused`` is set): pull once, update the threaded rows
-    ns.backend = backend
-    ns.fused = backend != "off"
+    # the table has fused rows: step.py then pulls once and threads the
+    # gathered rows to the push
+    ns.fused = has_V
     ns.pull_rows = pull_rows
     ns.rows_to_params = rows_to_params
     ns.apply_grad_rows = apply_grad_rows

@@ -1,7 +1,7 @@
 """Device binding for entry points: where compiled programs are cached
 and which device the process actually got.
 
-Entry points (``python -m difacto_tpu``, bench.py, chip_smoke.py) call
+Entry points (``python -m difacto_tpu``, chip_smoke.py, perfbench/) call
 :func:`place_compile_cache` before their first backend touch and report
 :func:`bound_device` once bound, so a run that came up on the CPU
 because it could not get the chip says so in its first lines instead of
@@ -48,25 +48,3 @@ def bound_device() -> dict:
     return {"platform": devs[0].platform,
             "device_kind": devs[0].device_kind,
             "count": len(devs)}
-
-
-class ChipHeldByParent(RuntimeError):
-    """A TPU belongs to one process at a time: a parent that has bound
-    it cannot start a child that needs it too — the child would fail,
-    hang, or come up on the CPU and report numbers under the chip's
-    name."""
-
-
-def refuse_chip_child(what: str) -> None:
-    """Raise :class:`ChipHeldByParent` when this process is on a TPU
-    backend and is about to start ``what``, a child that needs a device
-    of its own. One TPU host is driven by one process (with a mesh for
-    several chips); multi-process layouts are for the CPU backend
-    (``JAX_PLATFORMS=cpu``)."""
-    dev = bound_device()
-    if dev["platform"] == "tpu":
-        raise ChipHeldByParent(
-            f"{what} needs a device of its own, and this process "
-            f"already holds the TPU ({dev}): one process per "
-            "chip. Run this mode with JAX_PLATFORMS=cpu, or drive the "
-            "chips of one host from one process (mesh_fs/mesh_dp)")

@@ -178,7 +178,7 @@ def scan_fn(site: str, fn, args: tuple, kwargs: Optional[dict] = None,
             budget: Optional[int] = None) -> Optional[dict]:
     """Lower+compile ``fn`` on ``args`` and :func:`record` it — the
     explicit entry capacity.py and the tests use. Returns the record,
-    or None when ``fn`` cannot lower (pallas inner callables)."""
+    or None when ``fn`` cannot lower (not a jit wrapper)."""
     if not hasattr(fn, "lower"):
         return None
     compiled = fn.lower(*args, **(kwargs or {})).compile()

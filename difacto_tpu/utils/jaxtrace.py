@@ -202,31 +202,6 @@ def pjit(fun, **jit_kwargs):
     return _wrap(fun, jit_kwargs, _site())
 
 
-def pallas_call(kernel, **kw):
-    """``pl.pallas_call(kernel, **kw)`` with the SAME creation-site
-    identity contract as :func:`jit`/:func:`pjit`: the ``relpath:lineno``
-    of THIS call is the site id, byte-identical to the static analyzer's
-    pallas-site discovery (analysis/jaxflow.py ``_is_pallas_name``), so
-    the fused-kernel programs (ops/fused.py) stay inside the
-    recompile/donation/host-sync gates and ``make jitmap`` shows them.
-
-    Unlike a jit wrapper, the returned callable runs at TRACE time of
-    its enclosing jit program — so its per-site call count approximates
-    the number of enclosing-program compiles that baked this kernel in
-    (steady state: the count stops growing with the bucket caps, same
-    acceptance as the jit sites)."""
-    from jax.experimental import pallas as pl
-
-    inner = pl.pallas_call(kernel, **kw)
-    if not enabled():
-        return inner
-    site = _site()
-    label = getattr(kernel, "__name__", type(kernel).__name__)
-    with _reg_mu:
-        _sites.setdefault(site, _SiteStats(label))
-    return _TracedJit(inner, site, frozenset())
-
-
 def fetch(x, point: str = "") -> np.ndarray:
     """A DECLARED device->host sync: ``np.asarray(x)``, counted per
     call site when DIFACTO_JAXTRACE=1. The static analyzer treats

@@ -6,9 +6,9 @@ Acceptance legs:
 - DEFAULTS ARE BYTE-IDENTICAL: ``slot_dtype=fp32`` + ``admit_min_count=0``
   + cold tier off reproduces the knob-free learner run bit-for-bit, at
   fs=1 AND fs=4 — the new subsystem costs nothing when off;
-- quantized trajectories are byte-identical across
-  ``fused_kernel=off|jnp`` (and pallas interpret where available) — the
-  dequant/requant epilogue is part of the portable row contract;
+- the quantized row's dequant/requant epilogue threads from pull to
+  push like any other row (tests/test_fused.py
+  test_threaded_step_matches_composed_step, int8 cases);
 - sketch admission is deterministic across the thread and process
   producer transports (same (seed, epoch, part) mix on both);
 - a quantized (and tiered) checkpoint round-trips through the
@@ -291,36 +291,6 @@ def test_defaults_byte_identical_fs4(rcv1_path):
     s1, t1 = _learner_run(rcv1_path, mesh_fs=4, slot_dtype="fp32",
                           admit_min_count=0, evict_occupancy=0,
                           cold_tier_rows=0)
-    assert s0 == s1
-    np.testing.assert_array_equal(t0, t1)
-
-
-@pytest.mark.parametrize("backends", [("off", "jnp")])
-def test_quantized_trajectory_across_backends(rcv1_path, backends):
-    """int8 slot storage keeps the off|jnp fused backends byte-identical
-    — the dequant/requant epilogue is part of the shared row contract."""
-    s0, t0 = _learner_run(rcv1_path, slot_dtype="int8",
-                          fused_kernel=backends[0])
-    s1, t1 = _learner_run(rcv1_path, slot_dtype="int8",
-                          fused_kernel=backends[1])
-    assert s0 == s1
-    np.testing.assert_array_equal(t0, t1)
-
-
-def test_quantized_trajectory_pallas_interpret(rcv1_path):
-    s0, t0 = _learner_run(rcv1_path, slot_dtype="int8",
-                          fused_kernel="off")
-    s2, t2 = _learner_run(rcv1_path, slot_dtype="int8",
-                          fused_kernel="pallas")
-    assert s0 == s2
-    np.testing.assert_array_equal(t0, t2)
-
-
-def test_quantized_trajectory_fs4(rcv1_path):
-    s0, t0 = _learner_run(rcv1_path, slot_dtype="int8", mesh_fs=4,
-                          fused_kernel="off")
-    s1, t1 = _learner_run(rcv1_path, slot_dtype="int8", mesh_fs=4,
-                          fused_kernel="jnp")
     assert s0 == s1
     np.testing.assert_array_equal(t0, t1)
 

@@ -13,7 +13,7 @@ Covers the tentpole's acceptance legs:
   byte-identical to the single-device path, whatever layout the
   checkpoint was saved in;
 - make_mesh's multi-host host-complete fs constraint fails typed;
-- the capacity-scaling report (bench --multichip /
+- the capacity-scaling report (parallel/capacity.py /
   __graft_entry__.dryrun_multichip) emits per-fs legs with constant
   per-device bytes.
 """

@@ -1,18 +1,18 @@
-"""Fused sparse-FM kernel + on-device dedup (ISSUE 13, ROADMAP item 3).
+"""The fused table path + on-device dedup (ROADMAP item 3).
 
 Acceptance legs:
 
-- trajectories are BYTE-identical across ``fused_kernel=off|jnp`` (and
-  ``pallas`` via interpret mode — the same kernels Mosaic compiles on
-  TPU, executed bit-exactly on CPU) at the step level AND through full
-  learner runs at fs=1 and fs=4;
+- threading the gathered rows from the step's pull to its push equals
+  re-gathering them: the train step (step.py) against a step composed
+  in the test from the store's eager ``get_rows`` + ``apply_grad``,
+  byte for byte, over both row dtypes, plain and int8-quantized rows,
+  one device and an fs=4 sharded table;
 - the on-device dedup (ops/fused.dedup_tokens) reproduces the host
   ``np.unique`` + ``pad_slots_oob`` contract exactly, and a streamed
   ``device_dedup=1`` learner run is byte-identical to the host-dedup
   run;
-- backend resolution fails typed where the backend cannot exist
-  (pallas under a sharded table) and degrades to ``off`` on flat
-  tables.
+- a conf that still names the removed ``fused_kernel`` key is reported
+  as unknown, not swallowed.
 """
 
 import os
@@ -68,87 +68,125 @@ def test_dedup_tokens_single_value():
     assert np.asarray(inv).tolist() == [0] * 16
 
 
-# -------------------------------------------------------------- resolve
-
-def test_resolve_backend_contract():
-    assert fused.resolve_backend("off", V_dim=4) == "off"
-    assert fused.resolve_backend("auto", V_dim=0) == "off"
-    assert fused.resolve_backend("auto", V_dim=4) == "jnp"
-    assert fused.resolve_backend("jnp", V_dim=4) == "jnp"
-    with pytest.raises(ValueError, match="sharded"):
-        fused.resolve_backend("pallas", mesh=object(), V_dim=4)
-    with pytest.raises(ValueError, match="unknown fused_kernel"):
-        fused.resolve_backend("mosaic", V_dim=4)
-    # the knob validates at learner init too (Param enum metadata)
-    param = SGDUpdaterParam(V_dim=2, fused_kernel="pallas")
-    assert make_fns(param).backend == "pallas"
-
-
 # ----------------------------------------------------- step trajectories
 
-def _run_steps(fused_kernel, v_dtype, steps=5, vdim=8):
-    from bench import make_batches
+def make_batches(n, B, nnz_per_row, uniq_space, capacity, seed=0):
+    """Host-side localized PANEL batches (fixed-width [B, F] index matrix,
+    the criteo layout) with zipf-skewed features, chunked for the FM
+    backward, + sorted-unique slot vectors padded with ascending
+    out-of-bounds indices (the table kernels' contract)."""
+    from difacto_tpu.data.rowblock import RowBlock
+    from difacto_tpu.ops.batch import bucket, pad_panel, panel_chunk_tokens
+
+    rng = np.random.RandomState(seed)
+    raw = []
+    u_cap = 8
+    for _ in range(n):
+        idx = ((rng.zipf(1.25, B * nnz_per_row) - 1)
+               % uniq_space).astype(np.int64)
+        uniq, inverse = np.unique(idx, return_inverse=True)
+        raw.append((uniq, inverse))
+        u_cap = max(u_cap, bucket(len(uniq)))
+    chunker = jax.jit(panel_chunk_tokens, static_argnums=(1,))
+    out = []
+    for uniq, inverse in raw:
+        blk = RowBlock(
+            offset=np.arange(B + 1, dtype=np.int64) * nnz_per_row,
+            label=rng.choice([0.0, 1.0], B).astype(np.float32),
+            index=inverse.astype(np.uint32),
+            value=None)
+        batch = chunker(pad_panel(blk, num_uniq=len(uniq), batch_cap=B,
+                                  width=nnz_per_row), u_cap)
+        slots = np.sort(rng.permutation(capacity - 1)[:len(uniq)] + 1)
+        out.append((batch, pad_slots_oob(slots.astype(np.int32), u_cap,
+                                         capacity)))
+    return out
+
+
+def _composed_step(fns, loss, constrain):
+    """The reference: the same step built from the store's eager pull
+    and push, which gather the rows once each, jitted whole."""
+    from difacto_tpu.losses import FMParams
+    from difacto_tpu.losses.metrics import auc_times_n_binned_jnp
+
+    def step(state, batch, slots):
+        w, V, vmask = fns.get_rows(state, slots)
+        params = FMParams(w=w, V=V, v_mask=vmask)
+        pred, xv = loss.predict_xv(params, batch)
+        objv = loss.evaluate(pred, batch)
+        auc = auc_times_n_binned_jnp(batch.labels, pred, batch.row_mask)
+        gw, gV = loss.calc_grad(params, batch, pred, xv)
+        state = fns.apply_grad(state, slots, gw, gV, vmask)
+        return constrain(state), objv, auc
+    return step
+
+
+@pytest.mark.parametrize("fs", [1, 4], ids=["one_device", "mesh_fs4"])
+@pytest.mark.parametrize("slot_dtype", ["fp32", "int8"])
+@pytest.mark.parametrize("v_dtype", ["bfloat16", "float32"])
+def test_threaded_step_matches_composed_step(v_dtype, slot_dtype, fs):
+    from difacto_tpu.parallel import (make_mesh, sharding_tree,
+                                      state_sharding)
+    from difacto_tpu.step import state_constrainer
+    if len(jax.devices()) < fs:
+        pytest.skip("needs four (virtual) devices")
+    vdim, cap, steps = 8, 512, 4
     param = SGDUpdaterParam(V_dim=vdim, V_threshold=0, lr=0.1, l1=1e-4,
                             l2=1e-4, V_dtype=v_dtype,
-                            fused_kernel=fused_kernel)
+                            slot_dtype=slot_dtype)
     fns = make_fns(param)
+    assert fns.fused
     loss = create("fm", vdim)
-    state = set_all_live(param, init_state(param, 512))
-    _, train_step, _ = make_step_fns(fns, loss)
-    step = jax.jit(train_step, donate_argnums=0)
-    batches = make_batches(2, 32, 5, 128, 512, "zipf", seed=3)
-    objs = []
-    for i in range(steps):
-        b, s = batches[i % 2]
-        state, objv, auc = step(state, b, jnp.asarray(s))
-        objs.append((float(objv), float(auc)))
-    return objs, _table_bits(state.VVg)
+    batches = make_batches(2, 32, 5, 128, cap, seed=3)
 
+    def run(build):
+        state = set_all_live(param, init_state(param, cap))
+        shardings = None
+        if fs > 1:
+            shardings = sharding_tree(
+                state, state_sharding(make_mesh(dp=1, fs=fs)))
+            state = jax.device_put(state, shardings)
+        step = jax.jit(build(shardings), donate_argnums=0)
+        objs = []
+        for i in range(steps):
+            b, s = batches[i % 2]
+            state, objv, auc = step(state, b, jnp.asarray(s))
+            objs.append((float(objv), float(auc)))
+        return objs, _table_bits(state.VVg)
 
-@pytest.mark.parametrize("v_dtype", ["bfloat16", "float32"])
-def test_trajectory_byte_identical_off_vs_jnp(v_dtype):
-    o0, t0 = _run_steps("off", v_dtype)
-    o1, t1 = _run_steps("jnp", v_dtype)
+    o0, t0 = run(lambda sh: _composed_step(fns, loss,
+                                           state_constrainer(sh)))
+    o1, t1 = run(lambda sh: make_step_fns(fns, loss,
+                                          state_shardings=sh)[1])
     assert o0 == o1                      # float equality, not allclose
     np.testing.assert_array_equal(t0, t1)
+    # the steps trained: the table moved off its initial bits
+    assert (t1 != _table_bits(set_all_live(
+        param, init_state(param, cap)).VVg)).any()
 
 
-def test_trajectory_byte_identical_pallas_interpret():
-    """The pallas kernels (interpret mode off-TPU — the same kernel
-    bodies Mosaic compiles) reproduce the off-path trajectory
-    bit-for-bit: gather, in-kernel FTRL/AdaGrad epilogue, DMA
-    scatter-back, OOB pad handling."""
-    o0, t0 = _run_steps("off", "bfloat16", steps=3)
-    o2, t2 = _run_steps("pallas", "bfloat16", steps=3)
-    assert o0 == o2
-    np.testing.assert_array_equal(t0, t2)
+# ---------------------------------------------------------- removed knob
 
-
-def test_pallas_is_refused_typed_on_a_tpu_backend(monkeypatch):
-    """Mosaic does not compile these kernels (ops/fused._MOSAIC_REFUSAL,
-    taken on the chip): on a TPU backend the knob must raise at
-    resolution, in the compiler's words — never crash mid-run, never
-    reach interpret mode."""
-    monkeypatch.setattr(fused.jax, "default_backend", lambda: "tpu")
-    assert not fused.interpret_mode()
-    with pytest.raises(fused.PallasRefused, match="Mosaic failed to "
-                                                  "compile TPU kernel"):
-        fused.resolve_backend("pallas", V_dim=8)
-    assert fused.resolve_backend("auto", V_dim=8) == "jnp"
-
-
-def test_pallas_gather_scatter_kernels_match_jnp():
-    rng = np.random.RandomState(1)
-    table = jnp.asarray(rng.randn(64, 16).astype(np.float32))
-    slots = jnp.asarray(
-        pad_slots_oob(np.array([1, 5, 9, 30, 63], np.int32), 12, 64))
-    g_jnp = fused.gather_rows(table, slots, "jnp")
-    g_pl = fused.gather_rows(table, slots, "pallas")
-    np.testing.assert_array_equal(np.asarray(g_jnp), np.asarray(g_pl))
-    rows = jnp.asarray(rng.randn(12, 16).astype(np.float32))
-    s_jnp = fused.scatter_rows(table, slots, rows, "jnp")
-    s_pl = fused.scatter_rows(table, slots, rows, "pallas")
-    np.testing.assert_array_equal(np.asarray(s_jnp), np.asarray(s_pl))
+def test_fused_kernel_key_is_reported_unknown(rcv1_path, tmp_path, caplog):
+    """The switch is gone: a conf or command line that still sets it is
+    told so, the way any unknown key is — a leftover of init, and
+    main()'s "unknown config key" warning."""
+    from difacto_tpu.__main__ import main
+    ln = Learner.create("sgd")
+    left = ln.init([("data_in", rcv1_path), ("V_dim", "2"),
+                    ("hash_capacity", "4096"), ("fused_kernel", "jnp")])
+    assert left == [("fused_kernel", "jnp")]
+    conf = tmp_path / "old.conf"
+    conf.write_text(f"data_in = {rcv1_path}\nV_dim = 2\n"
+                    "hash_capacity = 4096\nfused_kernel = off\n")
+    for argv in ([str(conf)], [str(conf), "fused_kernel=pallas"]):
+        caplog.clear()
+        with caplog.at_level("WARNING", logger="difacto_tpu"):
+            assert main(argv + ["max_num_epochs=1",
+                                "report_interval=0"]) == 0
+        said = [r.getMessage() for r in caplog.records
+                if "unknown config key" in r.getMessage()]
+        assert said and all("fused_kernel" in m for m in said)
 
 
 # --------------------------------------------------------- learner runs
@@ -167,31 +205,6 @@ def _learner_run(data, **over):
     ln.add_epoch_end_callback(lambda e, t, v: seen.append(t.loss))
     ln.run()
     return seen, _table_bits(ln.store.state.VVg)
-
-
-def test_learner_byte_equality_fs1(rcv1_path):
-    s0, t0 = _learner_run(rcv1_path, fused_kernel="off")
-    s1, t1 = _learner_run(rcv1_path, fused_kernel="jnp")
-    assert s0 == s1
-    np.testing.assert_array_equal(t0, t1)
-
-
-def test_learner_byte_equality_fs4(rcv1_path):
-    """fused_kernel=off|jnp stay byte-identical under the fs=4 sharded
-    table (the jnp fused path partitions like the composed one and the
-    state_constrainer keeps the donated layout)."""
-    s0, t0 = _learner_run(rcv1_path, fused_kernel="off", mesh_fs=4)
-    s1, t1 = _learner_run(rcv1_path, fused_kernel="jnp", mesh_fs=4)
-    assert s0 == s1
-    np.testing.assert_array_equal(t0, t1)
-
-
-def test_pallas_knob_rejected_on_mesh(rcv1_path):
-    ln = Learner.create("sgd")
-    with pytest.raises(ValueError, match="sharded"):
-        ln.init([("data_in", rcv1_path), ("V_dim", "2"),
-                 ("hash_capacity", "4096"), ("mesh_fs", "4"),
-                 ("fused_kernel", "pallas")])
 
 
 # ----------------------------------------------------- device_dedup path

@@ -975,10 +975,9 @@ def test_the_tree_is_clean(capsys):
     # the suite itself keeps the analyzer honest: suppressions in the
     # tree must stay EXACTLY this number — bump deliberately when
     # adding one, prune when a fix removes one. Inventory (the v4
-    # sweep re-justified every entry): 22 data-race (stop flags,
+    # sweep re-justified every entry): 17 data-race (stop flags,
     # monotonic #stats counters, atomic reference swaps, single-owner
-    # instances, pre-spawn publication, the ISSUE 18 client
-    # blacklist-refold fields, the router group's write-once
+    # instances, pre-spawn publication, the router group's write-once
     # accept-thread handle and the Reporter's write-once monitor — PR 25
     # took three away with obs/trace's ``_annotate`` global and the
     # generator ``span`` whose reads of ``_active``/``_trace_id`` the
@@ -987,32 +986,29 @@ def test_the_tree_is_clean(capsys):
     # documented blind spot, so those two pragmas stay in the file
     # unmatched. Deleting utils/profiling.py left ``Reporter.report``
     # the only ``report`` method, so the serve batcher's call resolved
-    # precisely and the monitor's pre-spawn write needed its reason),
+    # precisely and the monitor's pre-spawn write needed its reason.
+    # PR 31 deleted the one script that drove a ``ServeClient`` from
+    # threads the call graph could see, so five of serve/client.py's
+    # single-owner pragmas match no finding now; they stay, the
+    # contract they state is the class's),
     # 6 wall-clock (cross-process file
     # timestamps x3, JSONL record stamps, trace-id entropy, run-dir
     # stamp), 2 lock-release (locktrace forwarding wrapper),
-    # 1 lock-blocking (native build serialization), 17 jax-recompile
+    # 1 lock-blocking (native build serialization), 14 jax-recompile
     # (pack/staging-time sticky caps the provenance model cannot chase
     # through payload tuples / the device cache — incl. the ISSUE 13
-    # panel_raw device-dedup dispatch; warm-replay keys; probe-tool
-    # per-variant compiles; the capacity-scaling sweep's
-    # one-compile-per-fs-rung loop in parallel/capacity.py and the
-    # kernel bench's one-compile-per-backend loop in bench.py — those
-    # loops ARE the benchmark matrices), 4 jax-host-sync
-    # (timing-harness completion fences in probe tools). The v5 scrub
-    # added ZERO suppressions: its one real finding (the bench --mesh
-    # leg jitted an unpinned donated-state program) was FIXED by
-    # threading mesh -> state_shardings through build_step, and the
-    # three shard rules run clean on the tree.
-    assert doc["counts"]["suppressed"] == 52
+    # panel_raw device-dedup dispatch; warm-replay keys; the
+    # capacity-scaling sweep's one-compile-per-fs-rung loop in
+    # parallel/capacity.py — that loop IS the sweep). The two shard
+    # rules run clean on the tree with no suppression.
+    assert doc["counts"]["suppressed"] == 40
     import collections
     per_rule = collections.Counter(
         f["rule"] for f in doc["findings"] if f["suppressed"])
     assert dict(per_rule) == {
-        "data-race": 22,
-        "jax-recompile": 17,
+        "data-race": 17,
+        "jax-recompile": 14,
         "wall-clock": 6,
-        "jax-host-sync": 4,
         "lock-release": 2,
         "lock-blocking": 1,
     }
@@ -1750,7 +1746,7 @@ def test_jax_donate_flow_aliased_positions(tmp_path):
 # ---------------------------------------------------------------------------
 # shardflow cross rules (analysis/shardflow.py, difacto-lint v5):
 # fixture twins — true positive exactly once, negative, suppressed —
-# for each of jax-shard-break / jax-shard-replicate / jax-shard-pallas.
+# for each of jax-shard-break / jax-shard-replicate.
 # The model-level views (pin verdicts, hlomap merge, the HLOSCAN
 # tier-1 gate) live in tests/test_hloscan.py.
 
@@ -1923,67 +1919,3 @@ def test_jax_shard_replicate_suppressed_twin(tmp_path):
         "full = jax.device_put(state.w)"
         "  # lint: ok(jax-shard-replicate) export path, mesh-free")
     assert lint_src(tmp_path, src, ["jax-shard-replicate"]) == []
-
-
-SHARD_PALLAS_TP = """
-    from jax.experimental import pallas as pl
-
-    def _kernel_body(ref, out):
-        pass
-
-    def _pallas_gather(table, slots):
-        return pl.pallas_call(_kernel_body)(table, slots)
-
-    def gather(table, slots, backend="jnp"):
-        if backend == "pallas":
-            return _pallas_gather(table, slots)
-        return table[slots]
-
-    def hot(table, slots):
-        return gather(table, slots, backend="pallas")
-"""
-
-
-def test_jax_shard_pallas_unresolved_literal_true_positive(tmp_path):
-    found = lint_src(tmp_path, SHARD_PALLAS_TP, ["jax-shard-pallas"])
-    assert len(found) == 1, found
-    assert "gather" in found[0].message
-    assert "resolve_backend" in found[0].message
-
-
-def test_jax_shard_pallas_resolved_and_default_clean(tmp_path):
-    # the three safe shapes: a backend bound from resolve_backend, the
-    # parameter left to its non-pallas default, and a non-pallas literal
-    assert lint_src(tmp_path, """
-        from jax.experimental import pallas as pl
-        from difacto_tpu.ops.fused import resolve_backend
-
-        def _kernel_body(ref, out):
-            pass
-
-        def _pallas_gather(table, slots):
-            return pl.pallas_call(_kernel_body)(table, slots)
-
-        def gather(table, slots, backend="jnp"):
-            if backend == "pallas":
-                return _pallas_gather(table, slots)
-            return table[slots]
-
-        def hot(table, slots, mesh):
-            backend = resolve_backend("auto", mesh=mesh)
-            return gather(table, slots, backend=backend)
-
-        def cold(table, slots):
-            return gather(table, slots)
-
-        def explicit(table, slots):
-            return gather(table, slots, backend="jnp")
-    """, ["jax-shard-pallas"]) == []
-
-
-def test_jax_shard_pallas_suppressed_twin(tmp_path):
-    src = SHARD_PALLAS_TP.replace(
-        'return gather(table, slots, backend="pallas")',
-        'return gather(table, slots, backend="pallas")'
-        "  # lint: ok(jax-shard-pallas) interpret-mode parity harness")
-    assert lint_src(tmp_path, src, ["jax-shard-pallas"]) == []

@@ -457,8 +457,8 @@ def test_metrics_overhead_bounded(case, limit_us):
 # ------------------------------------------------- learner stage source
 
 def test_learner_stage_stats_from_registry(rcv1_path):
-    """The streamed stage decomposition bench.py reports is sourced from
-    the learner's obs registry (stage_seconds_total), including the
+    """The streamed stage decomposition stage_stats() reports is sourced
+    from the learner's obs registry (stage_seconds_total), including the
     parse/pack split, and the metrics_path knob writes a renderable
     JSONL log."""
     import tempfile

@@ -3,9 +3,9 @@
 
 The static half is the sharding-flow model difacto-lint builds
 (difacto_tpu/analysis/shardflow.py): every fs-scoped state program and
-its layout-pin verdict, the pinning builders, the pallas kernel
-reachability sets, and the full jit-site universe. The dynamic half is
-a compiled-HLO scan (difacto_tpu/utils/hloscan.py): per jit site, the
+its layout-pin verdict, the pinning builders, and the full jit-site
+universe. The dynamic half is a compiled-HLO scan
+(difacto_tpu/utils/hloscan.py): per jit site, the
 collectives XLA actually emitted and the memory_analysis() byte
 counts, recorded either from a prior run's dump
 (``DIFACTO_HLOSCAN_OUT=<path>``) or produced in-process by ``--scan``,
@@ -71,7 +71,7 @@ def drive_scan(fs: int, capacity: int, budget: int,
 
     import numpy as np
 
-    # train leg: the same fused step bench --multichip measures, one
+    # train leg: the fs-sharded fused step of parallel/capacity.py, one
     # leg at the requested fs (capacity.py scans it explicitly too)
     from difacto_tpu.parallel.capacity import (bounded_delay_report,
                                                capacity_scaling_report)
@@ -124,11 +124,10 @@ def drive_scan(fs: int, capacity: int, budget: int,
 
 
 def build(root=".", dynamic=None) -> dict:
-    """{'state_programs', 'pinning_builders', 'kernel_functions',
-    'sites', 'programs', 'table_hits', 'budget_hits',
-    'unknown_sites'} — everything the writers, the --check gate and
-    the tier-1 test consume. ``dynamic`` is a scan dict (drive_scan or
-    hloscan.load)."""
+    """{'state_programs', 'pinning_builders', 'sites', 'programs',
+    'table_hits', 'budget_hits', 'unknown_sites'} — everything the
+    writers, the --check gate and the tier-1 test consume. ``dynamic``
+    is a scan dict (drive_scan or hloscan.load)."""
     root = Path(root).resolve()
     paths = [p for p in DEFAULT_PATHS if (root / p).exists()]
     project = core.Project(root, paths)
@@ -137,7 +136,6 @@ def build(root=".", dynamic=None) -> dict:
     out = {
         "state_programs": doc["state_programs"],
         "pinning_builders": doc["pinning_builders"],
-        "kernel_functions": doc["kernel_functions"],
         "sites": doc["sites"],
         "programs": {},
         "table_hits": [],
@@ -175,8 +173,6 @@ def to_text(graph: dict) -> str:
                      f"pin={rec['pin']} donate={rec['donate_argnums']}")
     lines.append(f"pinning builders: "
                  f"{', '.join(graph['pinning_builders']) or '-'}")
-    lines.append(f"pallas kernel functions: "
-                 f"{len(graph['kernel_functions'])}")
     for site, rec in sorted(graph["programs"].items()):
         lines.append(
             f"scan {site}  {rec['label']}  "

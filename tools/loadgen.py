@@ -15,7 +15,7 @@ Usage:
 
 Prints one JSON line: offered/achieved QPS, ok/shed/err counts, and
 p50/p95/p99/max response latency (ms). Importable as ``run_loadgen`` —
-bench.py --serve and tests/test_serve.py drive it in-process.
+tests/test_serve.py drives it in-process.
 
 ``--endpoints h1:p1,h2:p2`` switches to the FAILOVER driver
 (``run_loadgen_failover``): arrivals follow the same open-loop schedule,
@@ -34,17 +34,16 @@ and the router.
 ``--profile diurnal`` shapes the offered rate over the run as a
 piecewise-linear multiplier of ``--qps`` (trough → morning ramp → peak
 at 1.6x → evening decay → trough), the day-cycle in miniature that an
-elastic fleet must follow: bench.py --serve and the autoscaler chaos
-runs use it to force a scale-up mid-run and a drain after the peak.
+elastic fleet must follow: the autoscaler chaos runs use it to force a
+scale-up mid-run and a drain after the peak.
 ``flat`` (the default) keeps the constant-rate schedule. The schedule
 stays open-loop either way — the multiplier rides on the SCHEDULED
 arrival time, not on response progress.
 
 ``--zipf-alpha A`` (flat and failover drivers) skews WHICH rows get
 sent: row ranks draw from a Zipf(A) law instead of the round-robin
-cycle, the popularity shape real key traffic has — the knob the
-capacity bench (bench.py --capacity) sweeps to measure the cold
-tier's hit rate under realistic skew.
+cycle, the popularity shape real key traffic has — the knob to sweep
+when measuring the cold tier's hit rate under realistic skew.
 
 ``--label-rate R --label-delay-s D`` switches to the FEEDBACK driver
 (``run_loadgen_feedback``) for the online-learning loop
@@ -106,8 +105,7 @@ def make_picker(n: int, zipf_alpha: float, seed: int = 0):
     round-robin (every row equally hot — the historical behavior);
     ``zipf_alpha > 0`` draws ranks from a Zipf law ``p(r) ~ 1/r^alpha``
     over the row set, the skewed key popularity real traffic has and
-    the shape the cold tier's hit-rate depends on (bench.py --capacity
-    sweeps two alphas). Seeded
+    the shape the cold tier's hit-rate depends on. Seeded
     and independent of the arrival-schedule RNG, so turning skew on
     never perturbs the offered-rate schedule."""
     if zipf_alpha <= 0.0 or n <= 1:
