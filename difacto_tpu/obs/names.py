@@ -73,6 +73,12 @@ STEP_ROWS = "step_rows_total"        # sum of their distinct table rows
 # grew mid-run shows as a step in the ratio
 STEP_CHUNK_CAP = "step_chunk_cap_total"  # sum of the steps' chunk caps
 STEP_CHUNKS = "step_chunks_total"        # sum of the chunks they need
+# of the mesh panel steps whose table legs take a shard's owned run
+# (ops/fused.gather_rows, mesh_fs > 1): rows / cap is the fill of the
+# run, cap / step_row_cap_total ~ 1/fs says the run engaged (1: it did
+# not, the fullest shard owned a whole row cap)
+STORE_OWNED_ROWS = "store_owned_rows_total"  # sum of the fullest shard's rows
+STORE_OWNED_CAP = "store_owned_cap_total"    # sum of the steps' own_cap
 
 # ----------------------------------------------------------------- spans
 EPOCH = "epoch"

@@ -60,12 +60,12 @@ def capacity_scaling_report(fs_values: Optional[Sequence[int]] = None,
         param = SGDUpdaterParam(V_dim=V_dim, V_threshold=0, lr=0.1,
                                 l1=1e-4, l2=1e-4, V_dtype=v_dtype,
                                 hash_capacity=cap, slot_dtype=slot_dtype)
-        fns = make_fns(param)
+        mesh = make_mesh(dp=1, fs=fs)
+        fns = make_fns(param, mesh)
         loss = create_loss("fm", V_dim)
         state = init_state(param, cap)
         if V_dim:
             state = set_all_live(param, state)
-        mesh = make_mesh(dp=1, fs=fs)
         shardings = sharding_tree(state, state_sharding(mesh))
         state = shard_pytree(state, state_sharding(mesh))
         _, train_step, _ = make_step_fns(fns, loss,
@@ -217,12 +217,12 @@ def bounded_delay_report(hosts_values: Sequence[int] = (1, 2, 4),
     param = SGDUpdaterParam(V_dim=V_dim, V_threshold=0, lr=0.1,
                             l1=1e-4, l2=1e-4, V_dtype=v_dtype,
                             hash_capacity=cap)
-    fns = make_fns(param)
+    mesh = make_mesh(dp=1, fs=fs)
+    fns = make_fns(param, mesh)
     loss = create_loss("fm", V_dim)
     state = init_state(param, cap)
     if V_dim:
         state = set_all_live(param, state)
-    mesh = make_mesh(dp=1, fs=fs)
     shardings = sharding_tree(state, state_sharding(mesh))
     state = shard_pytree(state, state_sharding(mesh))
     _, train_step, _ = make_step_fns(fns, loss, state_shardings=shardings)

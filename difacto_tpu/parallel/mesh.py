@@ -20,6 +20,19 @@ TPU-native replacement for the reference's distributed topology
 
 All shapes are padded to power-of-two buckets (ops/batch.py), so any mesh with
 power-of-two axis sizes divides them evenly.
+
+**The owned run.** A step's unique slots arrive sorted and the table is
+sharded by key range (:func:`fs_shard_bounds`), so the rows one fs shard
+owns are one contiguous run of the slot vector. Left to GSPMD, every
+shard is handed all ``u_cap`` slots: its gather masks the ones it does
+not own and its scatter drops them, at the price of the whole vector.
+Where the host has counted how many slots the fullest shard owns
+(``own_cap``, learners/sgd.py ``_owned_cap``), ops/fused.gather_rows and
+scatter_rows instead run inside a ``shard_map`` over ``fs``: each shard
+finds where its run starts, slices ``own_cap`` slots, gathers them into
+their place in a zero operand (summed over ``fs``: the same exchange) and
+scatters its ``own_cap`` new rows in place. Same rows, each read and
+written once by the chip that owns it, at about ``1/fs`` of the indices.
 """
 
 from __future__ import annotations

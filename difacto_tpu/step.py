@@ -63,7 +63,8 @@ def state_constrainer(state_shardings):
 
 
 def make_step_fns(fns, loss: LossSpec, train_auc: str = "binned",
-                  state_shardings=None) -> Tuple:
+                  state_shardings=None,
+                  own_cap: Optional[int] = None) -> Tuple:
     """(forward, train_step, eval_step) over (state, batch, slots).
 
     ``fns`` is the updater namespace from updaters.sgd_updater.make_fns;
@@ -78,6 +79,11 @@ def make_step_fns(fns, loss: LossSpec, train_auc: str = "binned",
     (tests/test_fused.py). A flat ``V_dim = 0`` table has no fused row
     and composes ``get_rows`` + ``apply_grad`` over its w/z/sqrt_g
     arrays.
+
+    ``own_cap`` (None everywhere but the learner's mesh panel steps) is
+    the counted bound on the slots one fs shard owns, a constant of the
+    programs built here: with it the fused-row table legs run over each
+    shard's owned run (ops/fused.gather_rows).
     """
     constrain = state_constrainer(state_shardings)
     fused = fns.fused
@@ -86,7 +92,7 @@ def make_step_fns(fns, loss: LossSpec, train_auc: str = "binned",
         """(params, slot_vmask, rows-or-None): a fused-row table keeps
         the gathered rows so train_step can hand them to the push."""
         if fused:
-            rows = fns.pull_rows(state, slots)
+            rows = fns.pull_rows(state, slots, own_cap)
             w, V, vmask = fns.rows_to_params(state, rows)
             return FMParams(w=w, V=V, v_mask=vmask), vmask, rows
         w, V, vmask = fns.get_rows(state, slots)
@@ -119,7 +125,7 @@ def make_step_fns(fns, loss: LossSpec, train_auc: str = "binned",
             gw, gV = loss.calc_grad(params, batch, pred, xv)
         if fused:
             state = fns.apply_grad_rows(state, slots, rows, gw, gV,
-                                        slot_vmask)
+                                        slot_vmask, own_cap)
         else:
             state = fns.apply_grad(state, slots, gw, gV, slot_vmask)
         return constrain(state), objv, auc

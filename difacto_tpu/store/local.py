@@ -149,7 +149,7 @@ class SlotStore:
                  initial_capacity: Optional[int] = None, mesh=None,
                  read_only: bool = False):
         self.param = param
-        self.fns = make_fns(param)
+        self.fns = make_fns(param, mesh)
         self.mesh = mesh
         # read-only stores serve inference (serve/, task=pred): lookups
         # never insert into the dictionary, push/apply paths raise, and

@@ -546,12 +546,12 @@ def make_fns(param: SGDUpdaterParam, mesh=None):
     as compile-time constants. Returns a namespace of jit-ready callables
     (not yet jit-wrapped; the store/learner composes and jits them).
 
-    ``mesh`` is unused: the functions are the same plain XLA ops with or
-    without a mesh (GSPMD partitions them). The keyword stays only
-    because tests/perfbench/test_perfbench_compile.py, which the
-    benchmark holds, still passes it (ROADMAP D16)."""
-    del mesh
-
+    ``mesh`` is the store's (None: one device). The functions are plain
+    XLA ops that GSPMD partitions under it; the two table legs also take
+    a static ``own_cap`` with which, under ``mesh_fs > 1``, each shard
+    gathers and scatters only the run of ``slots`` it owns
+    (ops/fused.gather_rows). ``own_cap`` is counted by the caller, never
+    assumed: a run shorter than the rows a shard owns loses updates."""
     from ..ops import fused
 
     l1, l2 = param.l1, param.l2
@@ -559,16 +559,16 @@ def make_fns(param: SGDUpdaterParam, mesh=None):
     has_V = param.V_dim > 0
     # V_l2 / V_lr / V_lr_beta are read by row_epilogue from ``param``.
 
-    def _gather(arr, slots):
+    def _gather(arr, slots, own_cap=None):
         # the store guarantees sorted unique slots (map_keys_dedup) with
         # out-of-bounds ASCENDING padding (pad_slots) — the gather-flag
         # contract lives in ops/fused.gather_rows (measured ~20% off
         # the fused step); padded lanes read as zeros (mode=fill)
-        return fused.gather_rows(arr, slots)
+        return fused.gather_rows(arr, slots, mesh, own_cap)
 
-    def _scatter(arr, slots, rows):
+    def _scatter(arr, slots, rows, own_cap=None):
         # padded (out-of-bounds) entries are dropped, real rows are unique
-        return fused.scatter_rows(arr, slots, rows)
+        return fused.scatter_rows(arr, slots, rows, mesh, own_cap)
 
     thr = float(param.V_threshold)
 
@@ -579,14 +579,15 @@ def make_fns(param: SGDUpdaterParam, mesh=None):
     def _ftrl(w, z, sg, gw):
         return ftrl_w(w, z, sg, gw, l1, l2, lr, lr_beta)
 
-    def pull_rows(state: SGDState, slots: jnp.ndarray) -> jnp.ndarray:
+    def pull_rows(state: SGDState, slots: jnp.ndarray,
+                  own_cap: Optional[int] = None) -> jnp.ndarray:
         """ONE full fused-row gather of the batch's unique slots. The
         train step (step.py) threads the result from pull to push so
         the push never re-gathers. A partial-row gather
         (VVg[slots, :k]) would lower to a strided gather ~8x slower. V
         keeps its STORAGE dtype (param.V_dtype) so the loss's
         per-token gather can ride bf16."""
-        return _gather(state.VVg, slots)
+        return _gather(state.VVg, slots, own_cap)
 
     @names.leg(names.FORWARD)
     def rows_to_params(state: SGDState, rows: jnp.ndarray):
@@ -608,13 +609,14 @@ def make_fns(param: SGDUpdaterParam, mesh=None):
             V = rows[:, :param.V_dim]
         return w, V, vmask.astype(jnp.float32)
 
-    def get_rows(state: SGDState, slots: jnp.ndarray
+    def get_rows(state: SGDState, slots: jnp.ndarray,
+                 own_cap: Optional[int] = None
                  ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray],
                             Optional[jnp.ndarray]]:
         """Pull [w, V, v_mask] rows for the batch's unique slots (Get)."""
         if not has_V:
             return _gather(state.w, slots), None, None
-        return rows_to_params(state, pull_rows(state, slots))
+        return rows_to_params(state, pull_rows(state, slots, own_cap))
 
     def apply_count(state: SGDState, slots: jnp.ndarray, counts: jnp.ndarray
                     ) -> SGDState:
@@ -645,12 +647,14 @@ def make_fns(param: SGDUpdaterParam, mesh=None):
     def apply_grad_rows(state: SGDState, slots: jnp.ndarray,
                         rows: jnp.ndarray, gw: jnp.ndarray,
                         gV: Optional[jnp.ndarray],
-                        pull_vmask: Optional[jnp.ndarray]) -> SGDState:
+                        pull_vmask: Optional[jnp.ndarray],
+                        own_cap: Optional[int] = None) -> SGDState:
         """kGradient push over rows the step ALREADY gathered
         (pull_rows): the per-row FTRL/AdaGrad epilogue (row_epilogue)
         plus ONE scatter."""
         new = row_epilogue(param, state.capacity, rows, gw, gV, pull_vmask)
-        return state._replace(VVg=_scatter(state.VVg, slots, new))
+        return state._replace(
+            VVg=_scatter(state.VVg, slots, new, own_cap))
 
     def apply_grad(state: SGDState, slots: jnp.ndarray,
                    gw: jnp.ndarray, gV: Optional[jnp.ndarray],
