@@ -14,9 +14,13 @@ executable in the epoch that follows; ``--part first`` ends after the
 third step, over the first eight members of the cell's rows, and reads
 the first steps' numbers alone (enough for a control that they fail).
 ``--faults`` reads, beside each seed's numbers, the faults planted in the
-reference put in the program's place, against the reference as it is:
-every second row of each batch left out (both parts), and a second step
-of the pair that reads the state from before the first. Not part of a
+reference put in the program's place, against the reference as it is
+(``FAULTS``): every second row of each batch left out (both parts), a
+second step of the pair that reads the state from before the first, and,
+where the table is flat (``V_dim = 0``, l1 logistic regression), the soft
+threshold left out, so that no w is exactly 0. A configuration whose
+program has no lower precision to run as its control names one of them
+instead: ``"control": {"fault": "<name>", "why": ...}``. Not part of a
 benchmark run.
 """
 
@@ -35,6 +39,20 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 PAIR_FAULTS = ("stale", "half_batch")
+FAULTS = PAIR_FAULTS + ("no_l1",)
+
+
+def first_faults(hyper, batches) -> dict:
+    """{fault: (hyper, batches)} of the first steps' planted faults that
+    a table of this layout can show: what the reference follows in the
+    program's place."""
+    import dataclasses
+    out = {"half_batch": (hyper, [(i[::2], y[::2]) for i, y in batches])}
+    if hyper.V_dim == 0:
+        # w = z / eta wherever z is not 0: only l1 makes a weight that a
+        # batch touched exactly 0
+        out["no_l1"] = (dataclasses.replace(hyper, l1=0.0), batches)
+    return out
 
 
 def reading(bench: dict, workload: str, seed: int, override, part: str,
@@ -68,9 +86,9 @@ def reading(bench: dict, workload: str, seed: int, override, part: str,
     nums = check.numbers(prog, ref, ref_mod.rel_diff)
     planted = {}
     if faults:
-        bad = ref_mod.follow(hyper, V0, [(i[::2], y[::2])
-                                         for i, y in batches])
-        planted["half_batch"] = check.numbers(bad, ref, ref_mod.rel_diff)
+        for f, (h, bs) in first_faults(hyper, batches).items():
+            planted[f] = check.numbers(ref_mod.follow(h, V0, bs), ref,
+                                       ref_mod.rel_diff)
     pair = prog.pop("pair")
     if pair is not None:
         pref = ref_mod.follow_pair(hyper, pair["before"], batches[:2])
@@ -80,9 +98,10 @@ def reading(bench: dict, workload: str, seed: int, override, part: str,
                                       fault=f)
             planted.setdefault(f, {}).update(ref_mod.pair_numbers(
                 dict(bad, before=pair["before"]), pref, check.gap))
-    ok, _ = check.judge(dict(nums, epoch_rows=0.0), loaded["limits"])
+    ok, _ = check.judge(dict(nums, epoch_rows=0.0), loaded["limits"],
+                        hyper.V_dim)
     for side in (prog, ref):
-        side.pop("V"), side.pop("Vg")
+        side.pop("rows")
     return {"seed": seed, "override": override, "part": part,
             "correct": ok, "numbers": nums, "faults": planted,
             "program": prog, "reference": ref}

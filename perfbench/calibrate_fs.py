@@ -7,10 +7,11 @@ only a feature-sharded table can have.
 A cell under a mesh replays no pairs, so its limits hold the first three
 steps' numbers alone; each seed's run ends after the third step, over the
 first eight members of the cell's rows. ``--faults`` reads, beside each
-seed's numbers, two faults planted in the reference put in the program's
-place, against the reference as it is: every second row of each batch
-left out (``calibrate.py``'s), and ``shard_out``: one shard's rows left
-out of the gather, so that the steps read zeros where the seed's table
+seed's numbers, the faults planted in the reference put in the program's
+place, against the reference as it is: ``calibrate.py``'s of the first
+steps (every second row of each batch left out), and ``shard_out``: one
+shard's rows left out of the gather, so that the steps read zeros where
+the seed's table
 has the embeddings of rows ``[capacity/fs * k, capacity/fs * (k + 1))``
 (the shard that holds most of the touched rows). Not part of a benchmark
 run.
@@ -41,7 +42,7 @@ def shard_out(V0, probe_rows, capacity: int, fs: int):
 
 def reading(bench: dict, workload: str, seed: int, override,
             faults: bool, require_tpu: bool) -> dict:
-    from perfbench import check, sut
+    from perfbench import calibrate, check, sut
     from perfbench import run as R
     loaded = R.load_cell(bench, ROOT, workload)
     config, traffic = loaded["config"], dict(loaded["traffic"])
@@ -69,14 +70,15 @@ def reading(bench: dict, workload: str, seed: int, override,
     nums = check.numbers(prog, ref, ref_mod.rel_diff)
     planted = {}
     if faults:
-        bad = ref_mod.follow(hyper, V0, [(i[::2], y[::2])
-                                         for i, y in batches])
-        planted["half_batch"] = check.numbers(bad, ref, ref_mod.rel_diff)
+        for f, (h, bs) in calibrate.first_faults(hyper, batches).items():
+            planted[f] = check.numbers(ref_mod.follow(h, V0, bs), ref,
+                                       ref_mod.rel_diff)
         bad = ref_mod.follow(
             hyper, shard_out(V0, probe_rows, capacity,
                              int(config.get("mesh_fs", 1))), batches)
         planted["shard_out"] = check.numbers(bad, ref, ref_mod.rel_diff)
-    ok, _ = check.judge(dict(nums, epoch_rows=0.0), loaded["limits"])
+    ok, _ = check.judge(dict(nums, epoch_rows=0.0), loaded["limits"],
+                        hyper.V_dim)
     return {"seed": seed, "override": override, "correct": ok,
             "numbers": nums, "faults": planted}
 

@@ -24,14 +24,24 @@ each has a limit of its own, from ``limits/<workload>.json``:
 - ``epoch_rows``: the largest gap between the rows an epoch of the window
   reports and the rows of the traffic's epoch; exact, limit 0.
 
-A cell's limits file names the numbers it compares: the ten of the first
-steps always, the pair's where the file lists them. A number that the
-run produced and the file does not limit, or the reverse, fails.
+The flat table (``V_dim = 0``: w, z and sqrt_g a row, no embedding) has no
+number of V. Beside ``loss1..3``, ``grad_w``, ``change_w`` and
+``epoch_rows`` it compares, row by row over the rows that the reference
+updated, ``round_w``, ``round_z``, ``round_sg`` and ``zero_w``: the share
+of those rows on which program and reference disagree whether w is
+exactly 0 (a row whose |z| lands within rounding of ``l1`` may differ,
+so its limit is small and not 0); the pair's are ``pair_loss1..2``,
+``pair_change_w`` and ``pair_round_w``.
 
-The first seven are gaps of norms, not norms of a difference: rounding
+A cell's limits file names the numbers it compares: those of its layout's
+first steps always (``names``), the pair's where the file lists them. A
+number that the run produced and the file does not limit, or the
+reverse, fails.
+
+The gaps of norms are not norms of a difference: rounding
 that is not biased all but cancels in them, a wrong step does not. That
 is also why they cannot tell 8-bit rows from bfloat16 rows, whose
-rounding is unbiased (PERF.md, section 2): the last three, row by row,
+rounding is unbiased (PERF.md, section 2): the row-by-row numbers
 are what a lower storage precision fails.
 """
 
@@ -41,6 +51,13 @@ import math
 
 NUMBERS = ("loss1", "loss2", "loss3", "grad_w", "grad_V", "change_w",
            "change_V", "keep_V", "round_V", "round_Vg")
+FLAT_NUMBERS = ("loss1", "loss2", "loss3", "grad_w", "change_w",
+                "round_w", "round_z", "round_sg", "zero_w")
+
+
+def names(V_dim: int) -> tuple:
+    """The first steps' numbers that a table of this layout has."""
+    return NUMBERS if V_dim > 0 else FLAT_NUMBERS
 
 
 def gap(prog: float, ref: float) -> float:
@@ -52,14 +69,15 @@ def gap(prog: float, ref: float) -> float:
 def numbers(prog: dict, ref: dict, rel_diff) -> dict:
     """The first steps' numbers: ``prog`` and ``ref`` as
     ``sut.Probe.numbers`` and ``reference.follow`` give them;
-    ``rel_diff`` is the reference's."""
+    ``rel_diff`` is the reference's. The leaves compared are those the
+    reference has a norm of: no norm of an empty leaf enters a gap."""
     out = {f"loss{t + 1}": gap(p, r) for t, (p, r)
            in enumerate(zip(prog["loss"], ref["loss"]))}
-    for leaf in ("w", "V"):
+    for leaf in ref["change"]:
         out[f"grad_{leaf}"] = gap(prog["grad"][leaf], ref["grad"][leaf])
         out[f"change_{leaf}"] = gap(prog["change"][leaf],
                                     ref["change"][leaf])
-    out.update(rel_diff(prog["V"], prog["Vg"], ref["V"], ref["Vg"]))
+    out.update(rel_diff(prog["rows"], ref["rows"]))
     return out
 
 
@@ -72,18 +90,19 @@ def epoch_rows(rows_by_epoch, rows_per_epoch: int) -> float:
         / rows_per_epoch
 
 
-def judge(nums: dict, limits: dict) -> tuple:
-    """(correct, {name: {"value", "limit"}}): every number of ``NUMBERS``,
-    every other number the run produced and every number the limits file
-    names, each held to its limit; one without a limit, one that the run
-    did not produce and one that is not finite fail."""
-    names = list(NUMBERS)
+def judge(nums: dict, limits: dict, V_dim: int) -> tuple:
+    """(correct, {name: {"value", "limit"}}): every number of the
+    layout's ``names``, every other number the run produced and every
+    number the limits file names, each held to its limit; one without a
+    limit, one that the run did not produce and one that is not finite
+    fail."""
+    order = list(names(V_dim))
     for n in list(nums) + [k for k in limits if not k.startswith("_")]:
-        if n not in names:
-            names.append(n)
+        if n not in order:
+            order.append(n)
     checked = {}
     ok = True
-    for name in names:
+    for name in order:
         v = nums.get(name, math.inf)
         lim = limits.get(name)
         checked[name] = {"value": v if math.isfinite(v) else "inf",

@@ -92,7 +92,7 @@ def least_exchange_seconds(least: dict, peaks: dict, ici: float,
     that chip must take in, in a step of ``rows`` rows of ``width``
     features whose least time ``work.least_seconds`` gave as ``least``."""
     V_dim = int(config["V_dim"])
-    itemsize = 2 if config["V_dtype"] == "bfloat16" else 4
+    itemsize = work.item_size(config)
     u = uniq_of(least, peaks, chips, rows, rows * width, V_dim, itemsize)
     need = step_exchange(u, V_dim, itemsize, int(config["mesh_fs"]))
     return need["bytes_per_chip"] / ici

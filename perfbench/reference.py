@@ -17,6 +17,9 @@ the table):
 A row's embedding takes part only when it is live and, with ``l1_shrk``,
 its w is not zero; it becomes live once w != 0 and its count of
 occurrences passes ``V_threshold``. Counts are pushed before the step.
+At ``V_dim = 0`` the model is l1-regularised logistic regression (the
+flat table: w, z, sqrt_g a row and no embedding): the same functions on a
+V of no columns, and the numbers compared are of w, z and sqrt_g.
 
 Departures from the paper, each the configuration's: features are hashed
 into ``hash_capacity`` rows and share a row on collision; V is stored in
@@ -86,8 +89,10 @@ def initial_V(seed: int, capacity: int, rows: np.ndarray, h: Hyper
               ) -> jnp.ndarray:
     """Rows ``rows`` of the table a seed gives: uniform on
     [-V_init_scale/2, V_init_scale/2) from ``jax.random.PRNGKey(seed)``
-    over the whole [capacity, V_dim] table, rounded to the storage type."""
-
+    over the whole [capacity, V_dim] table, rounded to the storage type.
+    A table with no embedding has nothing to draw."""
+    if h.V_dim == 0:
+        return jnp.zeros((len(rows), 0), jnp.float32)
     return _draw_rows(jnp.int32(seed), jnp.asarray(rows, jnp.int32),
                       capacity, h.V_dim, h.V_init_scale, h.V_dtype)
 
@@ -129,7 +134,7 @@ def gradients(h: Hyper, s: State, idx: jnp.ndarray, y: jnp.ndarray,
     px = p[:, None] * x                             # [B, F]
     gw = jnp.zeros((n,), jnp.float32).at[flat].add(px.reshape(-1))
     t1 = jnp.zeros((n, k), jnp.float32).at[flat].add(
-        (px[:, :, None] * XV[:, None, :]).reshape(-1, k))
+        (px[:, :, None] * XV[:, None, :]).reshape(flat.shape[0], k))
     xxp = gw if vals is None else jnp.zeros((n,), jnp.float32).at[
         flat].add((px * x).reshape(-1))
     gV = (t1 - xxp[:, None] * Vm) * vm[:, None]
@@ -186,6 +191,9 @@ def norm(x) -> float:
     return float(np.sqrt(np.sum(x * x)))
 
 
+ROWS = ("w", "z", "sg", "V", "Vg")      # the leaves compared row by row
+
+
 def follow(h: Hyper, V0: jnp.ndarray, batches, lower=None) -> dict:
     """Follow the first steps from the seed's table. ``batches`` is a
     list of (idx int32[B, F] into the touched rows, y f32[B]). Returns the
@@ -193,8 +201,9 @@ def follow(h: Hyper, V0: jnp.ndarray, batches, lower=None) -> dict:
     gradient of each leaf as the optimizer got it (w: FTRL's sqrt_g after
     step 1; V: AdaGrad's Vg after the first step that pulled an
     embedding, which is step 2, since every w is 0 before step 1), the
-    norm of each leaf's change after the last step, and the touched rows'
-    V and Vg after it.
+    norm of each leaf's change after the last step, and the touched rows
+    after it (``rows``: w, z, sg, V, Vg). Where the table has no
+    embedding there is no number of V.
 
     ``lower`` turns the reference into its own lower-precision control: a
     function applied to (V, Vg) wherever the table would store them."""
@@ -210,20 +219,22 @@ def follow(h: Hyper, V0: jnp.ndarray, batches, lower=None) -> dict:
         out["loss"].append(float(loss))
         if t == 1:
             out["grad"]["w"] = norm(s.sg)
-        if t == 2:
+        if t == 2 and h.V_dim:
             out["grad"]["V"] = norm(s.Vg)
     out["change"]["w"] = norm(s.w)
-    out["change"]["V"] = norm(s.V - V0s)
+    if h.V_dim:
+        out["change"]["V"] = norm(s.V - V0s)
     out["nnz_w"] = int(jnp.sum(s.w != 0))
     out["live"] = int(jnp.sum(s.live))
-    out["V"], out["Vg"] = s.V, s.Vg
+    out["rows"] = {k: getattr(s, k) for k in ROWS}
     return out
 
 
 def follow_pair(h: Hyper, before: dict, batches, fault: str = "") -> dict:
     """Follow one call of the pair-replay program: two steps from the
     touched rows as the program held them just before the call
-    (``before``: host arrays w, z, sg, cnt, live, V, Vg; the one thing
+    (``before``: host arrays w, z, sg, cnt, live, V, Vg, the last two
+    with no columns where the table has no embedding; the one thing
     the reference takes from the program, because the state after an
     epoch of bfloat16 steps cannot be had from the seed to better than
     the comparison's own limits). A replayed step pushes no counts.
@@ -251,35 +262,62 @@ def follow_pair(h: Hyper, before: dict, batches, fault: str = "") -> dict:
     else:
         s2, lb = jstep(s1, jnp.asarray(ib), jnp.asarray(yb))
     return {"loss": [float(la), float(lb)],
-            "after": {k: np.asarray(getattr(s2, k))
-                      for k in ("w", "V", "Vg")}}
+            "after": {k: np.asarray(getattr(s2, k)) for k in ROWS}}
+
+
+def _rel(a, b, mask) -> float:
+    """The norm of ``a - b`` over the norm of ``b``, both over the rows
+    of ``mask``."""
+    den = norm(np.asarray(b)[mask])
+    return norm(np.asarray(a)[mask] - np.asarray(b)[mask]) / den \
+        if den else float("inf")
 
 
 def pair_numbers(prog: dict, ref: dict, gap) -> dict:
     """The pair call's numbers: the gap of each step's loss, the gaps of
     the norms of each leaf's change over the call, and row by row over
     the rows that the reference's two steps updated the norm of the
-    difference of V over the reference's norm. (The same of Vg was read
+    difference over the reference's norm: of V, or of w where the table
+    has no embedding. (The same of Vg was read
     and is not compared: neither planted fault reads three times what
     sound runs do, PERF.md section 2.)"""
     b, a, r = prog["before"], prog["after"], ref["after"]
     out = {f"pair_loss{t + 1}": gap(p, q) for t, (p, q)
            in enumerate(zip(prog["loss"], ref["loss"]))}
-    for leaf in ("w", "V"):
+    flat = r["V"].shape[1] == 0
+    for leaf in ("w",) if flat else ("w", "V"):
         out[f"pair_change_{leaf}"] = gap(norm(a[leaf] - b[leaf]),
                                          norm(r[leaf] - b[leaf]))
-    moved = np.any(r["Vg"] != b["Vg"], axis=1)
-    out["pair_round_V"] = (norm((a["V"] - r["V"])[moved])
-                           / norm(r["V"][moved]))
+    moved = r["sg"] != b["sg"] if flat \
+        else np.any(r["Vg"] != b["Vg"], axis=1)
+    out[f"pair_round_{leaf}"] = _rel(a[leaf], r[leaf], moved)
     return out
 
 
-def rel_diff(V, Vg, ref_V, ref_Vg) -> dict:
-    """Row by row: the norm of the difference over the reference's norm,
-    of V over the rows that the reference never updated (``keep_V``: its
-    Vg is still all zero) and over those it did (``round_V``), and of Vg
-    (``round_Vg``)."""
-    V, Vg = jnp.asarray(V, jnp.float32), jnp.asarray(Vg, jnp.float32)
+def rel_diff(prog: dict, ref: dict) -> dict:
+    """Row by row over the touched rows after the last step (``rows`` of
+    the probe and of ``follow``): the norm of the difference over the
+    reference's norm.
+
+    Fused rows: of V over the rows that the reference never updated
+    (``keep_V``: its Vg is still all zero) and over those it did
+    (``round_V``), and of Vg (``round_Vg``).
+
+    The flat table: of w, z and sqrt_g over the rows that the reference
+    updated (``round_w``, ``round_z``, ``round_sg``: its sqrt_g is not
+    zero), and ``zero_w``: the rows on which the two disagree whether w
+    is exactly 0, as a share of those rows. l1's sparsity is the
+    guarantee this model gives, and a gap of norms cannot see it."""
+    if ref["V"].shape[1] == 0:
+        upd = np.asarray(ref["sg"]) != 0
+        out = {f"round_{k}": _rel(prog[k], ref[k], upd)
+               for k in ("w", "z", "sg")}
+        differ = (np.asarray(prog["w"]) == 0) != (np.asarray(ref["w"]) == 0)
+        out["zero_w"] = float(differ.sum()) / max(float(upd.sum()), 1.0)
+        return out
+    ref_V, ref_Vg = ref["V"], ref["Vg"]
+    V = jnp.asarray(prog["V"], jnp.float32)
+    Vg = jnp.asarray(prog["Vg"], jnp.float32)
     updated = jnp.any(ref_Vg != 0, axis=1)[:, None]
 
     def rel(a, b, mask):

@@ -245,10 +245,9 @@ def run_cell(bench: dict, root: str, workload: str, seed: int,
         if trace:
             rows = tracered.load_events(tracered.find_xplane(trace_dir))
             red = tracered.reduce(rows, res["window_s"])
-            itemsize = 2 if hyper.V_dtype == "bfloat16" else 4
             w = work.step_work(data["uniq_per_step"], data["batch"],
                                data["batch"] * data["width"],
-                               hyper.V_dim, itemsize)
+                               hyper.V_dim, work.item_size(cfg_kw))
             peaks = (work.load_peaks(dev["kind"]) if require_tpu
                      else None)
             least = (work.least_seconds(w, peaks, chips) if peaks
@@ -297,9 +296,9 @@ def run_cell(bench: dict, root: str, workload: str, seed: int,
                          "reference": pref["loss"]}
         nums["epoch_rows"] = check.epoch_rows(
             res["window_rows_by_epoch"], data["rows"])
-        ok, checked = check.judge(nums, loaded["limits"])
+        ok, checked = check.judge(nums, loaded["limits"], hyper.V_dim)
         for side in (res["probe"], ref):
-            side.pop("V"), side.pop("Vg")
+            side.pop("rows")
         out("reference", json.dumps({
             "seconds": round(time.perf_counter() - t0, 3),
             "initial_table_s": round(t_init, 3),

@@ -136,7 +136,11 @@ class Probe:
     """What the comparison reads of the program: sums over the table rows
     that the first steps touch, after each of them, and those rows whole
     before and after the first call of the pair-replay executable. All of
-    it is read from the learner's own state."""
+    it is read from the learner's own state, in one of two ways, by the
+    layout the store holds (``param.V_dim``): a fused row ``VVg[r]`` taken
+    apart by the program's own accessors, or the flat table's ``w[r]``,
+    ``z[r]``, ``sqrt_g[r]``, ``cnt[r]`` (no embedding: V and Vg of no
+    columns). Everything behind the read is one code."""
 
     LEAVES = ("w", "z", "sg", "cnt", "live", "V", "Vg")
 
@@ -146,14 +150,48 @@ class Probe:
         self.stop_after = stop_after
         import jax
         import jax.numpy as jnp
+        self.learner = learner
+        param = learner.store.param
+        if param.V_dim > 0:
+            self._kept = ("V", "Vg")
+            self._table = lambda state: state.VVg
+            leaves = self._fused_leaves(param, learner.store.state.capacity)
+        else:
+            self._kept = ("w", "z", "sg")
+            self._table = lambda state: (state.w, state.z, state.sqrt_g,
+                                         state.cnt, state.v_live)
+
+            def leaves(table, r):
+                none = jnp.zeros((r.shape[0], 0), jnp.float32)
+                return tuple(x[r] for x in table) + (none, none)
+
+        def sums(table, r, V0):
+            w, _, sg, _, live, V, Vg = leaves(table, r)
+            d = V - V0
+            return jnp.stack([
+                jnp.sum(w * w), jnp.sum(sg * sg), jnp.sum(Vg * Vg),
+                jnp.sum(d * d), jnp.sum((w != 0).astype(jnp.float32)),
+                jnp.sum(live.astype(jnp.float32))])
+
+        self._rows = self._replicated(np.asarray(rows, np.int32))
+        self._sums = jax.jit(sums)
+        self._leaves = jax.jit(leaves)
+        self.rows = None    # host leaves of the touched rows, at the end
+        self._V0 = jax.jit(lambda table, r: leaves(table, r)[5])(
+            self._table(learner.store.state), self._rows)
+        self.loss = []      # device scalars, one a step
+        self.sums = []      # device f32[6], one a step
+        self.pair = None    # the pair executable's one watched call
+        self._armed = {}    # key -> the executable, while wrapped
+        self._orig = learner._dispatch_item
+        learner._dispatch_item = self._spy
+
+    @staticmethod
+    def _fused_leaves(param, capacity: int):
+        import jax.numpy as jnp
         from difacto_tpu.updaters.sgd_updater import (quantized,
                                                       row_layout,
                                                       scal_f32)
-        self.learner = learner
-        param = learner.store.param
-        if param.V_dim <= 0:
-            raise ValueError("the probe reads fused rows: V_dim > 0")
-        capacity = learner.store.state.capacity
         k, h, _, off = row_layout(param, capacity)
 
         def leaves(VVg, r):
@@ -170,26 +208,7 @@ class Probe:
                 Vg = got[:, h:h + k].astype(jnp.float32)
             return f[:, 0], f[:, 1], f[:, 2], f[:, 3], f[:, 4] > 0, V, Vg
 
-        def sums(VVg, r, V0):
-            w, _, sg, _, live, V, Vg = leaves(VVg, r)
-            d = V - V0
-            return jnp.stack([
-                jnp.sum(w * w), jnp.sum(sg * sg), jnp.sum(Vg * Vg),
-                jnp.sum(d * d), jnp.sum((w != 0).astype(jnp.float32)),
-                jnp.sum(live.astype(jnp.float32))])
-
-        self._rows = self._replicated(np.asarray(rows, np.int32))
-        self._sums = jax.jit(sums)
-        self._leaves = jax.jit(leaves)
-        self.emb = None     # host (V, Vg) of the touched rows, at the end
-        self._V0 = jax.jit(lambda VVg, r: leaves(VVg, r)[5])(
-            learner.store.state.VVg, self._rows)
-        self.loss = []      # device scalars, one a step
-        self.sums = []      # device f32[6], one a step
-        self.pair = None    # the pair executable's one watched call
-        self._armed = {}    # key -> the executable, while wrapped
-        self._orig = learner._dispatch_item
-        learner._dispatch_item = self._spy
+        return leaves
 
     def _replicated(self, x):
         import jax.numpy as jnp
@@ -199,11 +218,11 @@ class Probe:
         from difacto_tpu.parallel import put_global, replicated
         return put_global(x, replicated(mesh))
 
-    def _host_leaves(self, VVg) -> dict:
+    def _host_leaves(self, state) -> dict:
         """The touched rows as the table holds them now, on the host at
         once: nothing of the probe stays on the device."""
-        return dict(zip(self.LEAVES, (np.asarray(x) for x in
-                                      self._leaves(VVg, self._rows))))
+        return dict(zip(self.LEAVES, (np.asarray(x) for x in self._leaves(
+            self._table(state), self._rows))))
 
     def _spy(self, *args, **kw):
         pending = kw["pending"] if "pending" in kw else args[6]
@@ -213,11 +232,15 @@ class Probe:
             raise RuntimeError("a streamed batch of epoch 0 ran "
                                f"{len(pending) - before} steps, not one")
         self.loss.append(pending[-1][1])
-        self.sums.append(self._sums(self.learner.store.state.VVg,
-                                    self._rows, self._V0))
+        state = self.learner.store.state
+        self.sums.append(self._sums(self._table(state), self._rows,
+                                    self._V0))
         if len(self.loss) >= N_STEPS:
-            got = self._host_leaves(self.learner.store.state.VVg)
-            self.emb = (got["V"], got["Vg"])
+            # kept through the window: the leaves compared row by row
+            # and no other. (With all seven kept, each later step of
+            # epoch 0 took 95 ms longer on four chips: PERF.md section 6.)
+            got = self._host_leaves(state)
+            self.rows = {k: got[k] for k in self._kept}
             self.release()
             if self.stop_after == "first":
                 raise ProbeDone()
@@ -252,11 +275,11 @@ class Probe:
 
     def _pair_spy(self, ex):
         def spy(state, pa, pb):
-            before = self._host_leaves(state.VVg)
+            before = self._host_leaves(state)
             out = ex(state, pa, pb)
             self.pair = {"loss": [float(out[1]), float(out[3])],
                          "before": before,
-                         "after": self._host_leaves(out[0].VVg)}
+                         "after": self._host_leaves(out[0])}
             self.disarm_pair()
             if self.stop_after == "pair":
                 # the run ends here: the learner takes the new state
@@ -279,7 +302,7 @@ class Probe:
                      "V": float(np.sqrt(s[1][2]))},
             "change": {"w": float(np.sqrt(s[N_STEPS - 1][0])),
                        "V": float(np.sqrt(s[N_STEPS - 1][3]))},
-            "V": self.emb[0], "Vg": self.emb[1],
+            "rows": self.rows,
             "Vg_after_step1": float(np.sqrt(s[0][2])),
             "nnz_w": int(s[N_STEPS - 1][4]),
             "live": int(s[N_STEPS - 1][5]),
@@ -425,7 +448,7 @@ def drive(kwargs: dict, probe_rows, seconds: float, trace_dir: str = None,
         "paired_dispatches": getattr(learner, "_paired_dispatches", 0),
         "device_cache": learner.device_cache_info(),
         "table_rows": int(learner.store.state.capacity),
-        "table_bytes": int(learner.store.state.VVg.nbytes),
+        "table_bytes": sum(int(x.nbytes) for x in learner.store.state),
         "probe": probe.numbers() if probe is not None else None,
     }
     out["memory_peak_bytes"] = memory_peak_bytes()

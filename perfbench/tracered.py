@@ -24,9 +24,22 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OP_LINE = "XLA Ops"
 MODULE_LINE = "XLA Modules"
 MARKS = ("perfbench_window_open", "perfbench_window_close")
-COLLECTIVE = re.compile(     # the trace names an op "%all-reduce.3 = ..."
+# The trace gives an op its HLO text, "%all-reduce.3 = f32[8]{0}
+# all-reduce(...)". XLA names an instruction after what made it, so a
+# ``psum`` inside a ``shard_map`` is "%psum_invariant.7 = ... all-reduce(":
+# a collective is found by its leading name or by the opcode behind the
+# "=", the first word there that a "(" follows.
+COLLECTIVE = re.compile(
     r"^%?(all-reduce|all-gather|all-to-all|collective-permute|"
     r"reduce-scatter|collective-broadcast|send|recv)\b")
+OPCODE = re.compile(r"=\s.*?\s([a-z][a-z0-9\-]*)\(")
+
+
+def is_collective(op: str) -> bool:
+    if COLLECTIVE.match(op):
+        return True
+    opcode = OPCODE.search(op)
+    return bool(opcode and COLLECTIVE.match(opcode.group(1)))
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -126,7 +139,7 @@ def reduce(rows: list, window_s: float) -> dict:
         du = [o[2] for o in ops]
         b, ms, me = union_seconds(st, du)
         busy.append(b)
-        c = [(o[1], o[2]) for o in ops if COLLECTIVE.match(o[0])]
+        c = [(o[1], o[2]) for o in ops if is_collective(o[0])]
         coll.append(union_seconds([x[0] for x in c],
                                   [x[1] for x in c])[0])
         if gaps_src is None or b > gaps_src[0]:

@@ -20,6 +20,18 @@ INDEX_BYTES = 4
 LABEL_BYTES = 4
 
 
+def item_size(config: dict) -> int:
+    """Bytes an item of V and Vg takes in the type the configuration
+    stores them in: ``slot_dtype`` int8 or fp8 1, bf16 2, and otherwise
+    (``slot_dtype`` fp32, or none) what ``V_dtype`` says."""
+    slot = str(config.get("slot_dtype", "fp32"))
+    if slot in ("int8", "fp8"):
+        return 1
+    if slot == "bf16" or str(config.get("V_dtype", "float32")) == "bfloat16":
+        return 2
+    return 4
+
+
 def step_work(u: float, rows: float, nnz: float, V_dim: int,
               itemsize: int, valued: bool = False) -> dict:
     """{"bytes", "flops"} that one step cannot do without.

@@ -21,6 +21,16 @@ TINY_LIMITS = dict(FIRST_LIMITS, **{n: 1e-5 for n in (
     "pair_round_V")})
 
 
+# the flat table (V_dim = 0): no number of V
+FLAT_LIMITS = dict({n: 1e-5 for n in (
+    "loss1", "loss2", "loss3", "grad_w", "change_w", "round_w", "round_z",
+    "round_sg", "zero_w", "pair_loss1", "pair_loss2", "pair_change_w",
+    "pair_round_w")}, epoch_rows=0)
+# l1 logistic regression on the flagship's replay cell; an l1 that zeroes
+# some of the weights a tiny batch touches (the cell's 1e-4 zeroes none)
+FLAT = dict(V_dim=0, l1=0.3, limits=FLAT_LIMITS)
+
+
 def bench() -> dict:
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         return json.load(f)

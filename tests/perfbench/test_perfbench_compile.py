@@ -13,9 +13,10 @@ import pytest
 import perfbench_tiny as tiny
 
 HBM = 16e9
-# the cells' step: rows, width, and the row cap that ~284k distinct
-# features a step are padded to (bucket rungs 262144, 393216, 524288)
-B, F, U = 65536, 39, 393216
+# the cells' step: rows, width, and the row cap that ~279k distinct
+# table rows a step are padded to (``ops/batch.row_cap``'s ladder of
+# eighths: 278528, 294912, 327680)
+B, F, U = 65536, 39, 294912
 
 
 @pytest.fixture(scope="module")

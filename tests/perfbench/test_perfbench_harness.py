@@ -18,8 +18,26 @@ E2E_KEYS = {"correct", "attempted", "failed", "metrics", "device",
 
 
 @pytest.fixture()
-def root(tmp_path):
+def fused_root(tmp_path):
     return tiny.make_root(str(tmp_path))
+
+
+class Root(str):
+    """A tiny benchmark's directory, and the layout it was made for."""
+    flat = False
+    limits = tiny.TINY_LIMITS
+
+
+@pytest.fixture(params=["fused", "flat"])
+def root(request, tmp_path):
+    """The flagship's replay cell at a tiny size, in each of the table's
+    layouts: fused float32 rows of V_dim 8, and the flat V_dim = 0 table
+    (l1 logistic regression)."""
+    if request.param == "fused":
+        return Root(tiny.make_root(str(tmp_path)))
+    root = Root(tiny.make_root(str(tmp_path), **tiny.FLAT))
+    root.flat, root.limits = True, tiny.FLAT_LIMITS
+    return root
 
 
 def test_replay_cell_runs_and_is_correct(root):
@@ -43,10 +61,14 @@ def test_replay_cell_runs_and_is_correct(root):
     ref = json.loads(lines["reference"])
     assert ref["program"]["Vg_after_step1"] == 0.0
     assert ref["program"]["nnz_w"] == ref["reference"]["nnz_w"] > 0
-    # the first steps' ten numbers, the pair executable's five, the rows
-    assert set(res["checked"]) == set(tiny.TINY_LIMITS)
+    # exactly the layout's numbers: the first steps' (ten of a fused row,
+    # nine of the flat table), the pair executable's, the rows
+    assert set(res["checked"]) == set(root.limits)
+    assert any(n.endswith("_V") for n in res["checked"]) is not root.flat
     for name, c in res["checked"].items():
-        assert c["value"] <= c["limit"] == tiny.TINY_LIMITS[name], name
+        assert c["value"] <= c["limit"] == root.limits[name], name
+    # every leaf of the state: 17 bytes a flat row, 128 lanes a fused one
+    assert win["table_bytes"] == 4096 * (17 if root.flat else 128 * 4)
     assert len(ref["pair_loss"]["program"]) == 2
     json.dumps(res)                              # one JSON line
 
@@ -73,9 +95,9 @@ def test_same_seed_same_comparison(root, seed):
             assert a["checked"][name] == b["checked"][name], name
 
 
-def test_lower_precision_control_is_not_correct(root):
+def test_lower_precision_control_is_not_correct(fused_root):
     """The float32 configuration run with bfloat16 rows."""
-    res, _ = tiny.run(root, override={"V_dtype": "bfloat16"})
+    res, _ = tiny.run(fused_root, override={"V_dtype": "bfloat16"})
     assert res["correct"] is False
     bad = {n for n, c in res["checked"].items() if c["value"] > c["limit"]}
     assert {"change_V", "keep_V", "round_V"} <= bad
@@ -96,6 +118,61 @@ def test_int8_control_of_bf16_rows_is_not_correct(tmp_path):
     # (the pair's reference starts from the rows as the program held
     # them, so the pair's numbers cannot see how they are stored)
     assert bad == {"keep_V"}
+
+
+def test_l1_left_out_fails_zero_w(tmp_path):
+    """The flat table's control: the program run without the soft
+    threshold, so that no weight a batch touched is exactly 0."""
+    root = tiny.make_root(str(tmp_path), **tiny.FLAT)
+    res, lines = tiny.run(root, override={"l1": 0})
+    assert res["correct"] is False
+    assert res["checked"]["zero_w"]["value"] > 0.05
+    assert res["checked"]["loss1"]["value"] == 0.0    # every w starts at 0
+    ref = json.loads(lines["reference"])
+    assert ref["program"]["nnz_w"] > ref["reference"]["nnz_w"] > 0
+
+
+@pytest.mark.parametrize("limits, bad", [
+    ({k: v for k, v in tiny.FLAT_LIMITS.items() if k != "zero_w"},
+     {"zero_w"}),
+    (dict(tiny.FLAT_LIMITS, keep_V=1e-5), {"keep_V"}),
+    (tiny.TINY_LIMITS, set(tiny.TINY_LIMITS) ^ set(tiny.FLAT_LIMITS)),
+], ids=["lacks_a_flat_number", "names_a_V_number", "a_fused_cells_file"])
+def test_flat_cell_needs_the_flat_limits(tmp_path, limits, bad):
+    root = tiny.make_root(str(tmp_path), **dict(tiny.FLAT, limits=limits))
+    res, _ = tiny.run(root)
+    assert res["correct"] is False
+    failed = {n for n, c in res["checked"].items()
+              if c["limit"] is None or c["value"] == "inf"}
+    assert failed == bad
+
+
+def test_calibrate_reads_the_layouts_faults(root, monkeypatch):
+    """``calibrate.py --faults`` at the tiny size: the sound numbers, and
+    each fault planted in the reference reads far from them; the soft
+    threshold left out is the flat table's alone."""
+    from perfbench import calibrate
+    # the tool reads the repository's own files: hand it the tiny ones
+    monkeypatch.setattr(calibrate, "ROOT", str(root))
+    row = calibrate.reading(tiny.bench(), "fm_v64_criteo.replay", 7, None,
+                            "pair", True, False)
+    assert row["correct"] is True
+    assert set(row["numbers"]) | {"epoch_rows"} == set(root.limits)
+    assert max(row["numbers"].values()) < 1e-5
+    faults = row["faults"]
+    assert set(faults) == {"half_batch", "stale"} | (
+        {"no_l1"} if root.flat else set())
+    assert set(faults) <= set(calibrate.FAULTS)
+    assert faults["half_batch"]["loss1"] == pytest.approx(0.5, abs=0.05)
+    assert faults["half_batch"]["pair_loss1"] == pytest.approx(0.5,
+                                                               abs=0.05)
+    assert faults["stale"]["pair_loss1"] == 0.0
+    assert faults["stale"]["pair_change_w"] > 1e-3
+    assert set(faults["stale"]) == {n for n in root.limits
+                                    if n.startswith("pair_")}
+    if root.flat:
+        assert faults["no_l1"]["zero_w"] > 0.05
+        assert faults["no_l1"]["loss1"] == 0.0
 
 
 def _broken_step(monkeypatch, breaker):
@@ -188,6 +265,7 @@ def _write_back_dropped(pair):
 ], ids=["first_batch_twice", "write_back_dropped"])
 def test_fault_in_the_pair_program_alone_is_not_correct(
         root, monkeypatch, breaker, sees):
+    sees = sees & set(root.limits)
     _broken_pair(monkeypatch, breaker)
     res, lines = tiny.run(root, seconds=0.2)
     assert json.loads(lines["window"])["paired_dispatches"] > 0

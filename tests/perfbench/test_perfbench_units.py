@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 import perfbench_tiny as tiny  # noqa: F401  (puts the repo on sys.path)
-from perfbench import check, gen, layers, tracered, work
+from perfbench import calibrate, check, gen, layers, tracered, work
 from perfbench import run as R
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -82,6 +82,33 @@ def test_collective_share_on_two_planes(recorded):
     assert tracered.reduce(rows, span)["collective_s_fullest"] == 0.0
 
 
+@pytest.mark.parametrize("name, is_collective", [
+    ("%all-reduce.7 = f32[8]{0} all-reduce(f32[8]{0} %p), to_apply=%add",
+     True),
+    ("all-gather-start.3", True),
+    # jax.lax.psum inside a shard_map: XLA names the instruction after
+    # the primitive, the opcode behind the "=" is the collective's
+    ("%psum_invariant.7 = u32[9437184,4]{1,0} all-reduce(u32[9437184,4]"
+     "{1,0} %fusion.3), channel_id=1, to_apply=%region_1.5", True),
+    ("%ppermute.2 = (f32[8]{0}, f32[8]{0}) collective-permute-start("
+     "f32[8]{0} %x), source_target_pairs={{0,1}}", True),
+    ("%fusion.1 = f32[8]{0} fusion(f32[8]{0} %all-reduce.7), kind=kLoop",
+     False),
+    ("%all_reduce_like.1 = f32[8]{0} add(f32[8]{0} %a, f32[8]{0} %b)",
+     False),
+    ("%fusion.8", False),
+], ids=["by_name", "bare_name", "psum_invariant", "ppermute", "consumer",
+        "lookalike", "no_text"])
+def test_collective_is_found_by_name_or_opcode(name, is_collective):
+    assert tracered.is_collective(name) is is_collective
+    rows = [("/device:TPU:0", "XLA Ops", name, 0, 4_000_000),
+            ("/device:TPU:0", "XLA Ops", "%fusion.9 = f32[8]{0} fusion()",
+             4_000_000, 6_000_000)]
+    red = tracered.reduce(rows, 0.010)
+    assert red["collective_s_fullest"] == pytest.approx(
+        0.004 if is_collective else 0.0)
+
+
 def test_device_events_are_clipped_to_the_windows_marks():
     rows = [("/device:TPU:0", "XLA Ops", "%before", 0, 2_000_000),
             ("/device:TPU:0", "XLA Ops", "%across", 9_000_000, 2_000_000),
@@ -151,6 +178,44 @@ def test_step_work_ignores_the_stored_width():
     assert work.step_work(1000, 64, 64 * 39, 16, 4) == a
     b16 = work.step_work(1000, 64, 64 * 39, 32, 2)
     assert b16["bytes"] == a["bytes"]        # 32 bf16 items = 16 f32
+
+
+@pytest.mark.parametrize("config, size", [
+    ({}, 4),
+    ({"V_dtype": "float32"}, 4),
+    ({"V_dtype": "bfloat16"}, 2),
+    ({"V_dtype": "float32", "slot_dtype": "bf16"}, 2),
+    ({"V_dtype": "bfloat16", "slot_dtype": "int8"}, 1),
+    ({"V_dtype": "float32", "slot_dtype": "fp8"}, 1),
+    ({"V_dtype": "bfloat16", "slot_dtype": "fp32"}, 2),
+], ids=["default", "f32", "bf16", "slot_bf16", "int8", "fp8", "slot_fp32"])
+def test_item_size_is_the_stored_types(config, size):
+    assert work.item_size(config) == size
+    # the exchange's work takes the same size: an 8-bit row is not
+    # counted at four bytes an item
+    from perfbench import exchange
+    peaks = work.load_peaks("TPU v5 lite")
+    u, rows, width = 1000.0, 64, 39
+    least = work.least_seconds(
+        work.step_work(u, rows, rows * width, 64, size), peaks, 4)
+    t = exchange.least_exchange_seconds(
+        least, peaks, 200e9, 4, rows, width,
+        dict(config, V_dim=64, mesh_fs=4))
+    assert t == pytest.approx(u * 0.75 * (2 * 64 * size + 16) / 200e9)
+
+
+def test_step_work_of_the_flat_table():
+    """V_dim = 0: four float32 scalars a row each way, the indices and
+    the labels; the item size has nothing to multiply."""
+    w = work.step_work(u=279_000, rows=65536, nnz=65536 * 39, V_dim=0,
+                       itemsize=4)
+    assert w["bytes"] == 2 * 279_000 * 16 + 65536 * 39 * 4 + 65536 * 4
+    assert w["bytes"] == 19_413_760            # about 20 MB a step
+    assert w["flops"] == 16 * 279_000 + 2 * 65536 * 39
+    assert work.step_work(279_000, 65536, 65536 * 39, 0, 1) == w
+    t = work.least_seconds(w, work.load_peaks("TPU v5 lite"))
+    assert t["bound"] == "hbm" and t["seconds"] == pytest.approx(23.7e-6,
+                                                                 rel=1e-2)
 
 
 def test_peaks_table():
@@ -266,23 +331,56 @@ def test_field_rides_the_reversed_ids_top():
 
 
 # -------------------------------------------------------------- the judge
-def test_judge_holds_every_number_to_its_limit():
-    nums = {n: 1e-6 for n in check.NUMBERS}
-    lim = dict({n: 1e-5 for n in check.NUMBERS}, _about="text")
-    ok, checked = check.judge(nums, lim)
-    assert ok and list(checked) == list(check.NUMBERS)
+@pytest.mark.parametrize("V_dim", [8, 0], ids=["fused", "flat"])
+def test_judge_holds_every_number_to_its_limit(V_dim):
+    names = check.names(V_dim)
+    leaf = "grad_V" if V_dim else "zero_w"
+    nums = {n: 1e-6 for n in names}
+    lim = dict({n: 1e-5 for n in names}, _about="text")
+    ok, checked = check.judge(nums, lim, V_dim)
+    assert ok and list(checked) == list(names)
     # a number the run produced and no limit holds, and a limit whose
     # number the run did not produce (the pair never ran), both fail
-    assert not check.judge(dict(nums, pair_loss1=0.0), lim)[0]
-    ok, checked = check.judge(nums, dict(lim, pair_loss1=1e-5))
+    assert not check.judge(dict(nums, pair_loss1=0.0), lim, V_dim)[0]
+    ok, checked = check.judge(nums, dict(lim, pair_loss1=1e-5), V_dim)
     assert not ok and checked["pair_loss1"]["value"] == "inf"
     assert check.judge(dict(nums, pair_loss1=0.0),
-                       dict(lim, pair_loss1=1e-5))[0]
+                       dict(lim, pair_loss1=1e-5), V_dim)[0]
     assert checked["loss2"] == {"value": 1e-6, "limit": 1e-5}
-    assert not check.judge(dict(nums, grad_V=2e-5), lim)[0]
-    assert not check.judge(dict(nums, loss3=float("nan")), lim)[0]
+    assert not check.judge(dict(nums, **{leaf: 2e-5}), lim, V_dim)[0]
+    assert not check.judge(dict(nums, loss3=float("nan")), lim, V_dim)[0]
     assert not check.judge(nums, {k: v for k, v in lim.items()
-                                  if k != "change_w"})[0]
+                                  if k != "change_w"}, V_dim)[0]
+    # a number of the layout that the run lost and the file forgot
+    assert not check.judge({k: v for k, v in nums.items() if k != leaf},
+                           {k: v for k, v in lim.items() if k != leaf},
+                           V_dim)[0]
+
+
+def test_each_layout_has_its_numbers():
+    assert check.names(64) == check.NUMBERS and len(check.NUMBERS) == 10
+    flat = check.names(0)
+    assert flat == ("loss1", "loss2", "loss3", "grad_w", "change_w",
+                    "round_w", "round_z", "round_sg", "zero_w")
+    assert not any(n.endswith(("_V", "_Vg")) for n in flat)
+    # the other layout's limits judge no run of this one
+    nums = {n: 0.0 for n in flat}
+    assert not check.judge(nums, {n: 1.0 for n in check.NUMBERS}, 0)[0]
+    assert not check.judge(nums, {n: 1.0 for n in flat}, 64)[0]
+
+
+def test_numbers_take_no_gap_of_an_empty_leaf():
+    """The flat table's probe reports a V of no columns, norm 0: the
+    leaves compared are the reference's."""
+    prog = {"loss": [2.0, 1.0, 1.0], "grad": {"w": 3.0, "V": 0.0},
+            "change": {"w": 1.0, "V": 0.0}, "rows": "p"}
+    ref = {"loss": [2.0, 1.0, 1.0], "grad": {"w": 3.0},
+           "change": {"w": 1.0}, "rows": "r"}
+    nums = check.numbers(prog, ref, lambda p, r: {"zero_w": 0.0,
+                                                  "got": float(p + r == "pr")})
+    assert nums == {"loss1": 0.0, "loss2": 0.0, "loss3": 0.0,
+                    "grad_w": 0.0, "change_w": 0.0, "zero_w": 0.0,
+                    "got": 1.0}
 
 
 def test_epoch_rows_is_exact():
@@ -292,7 +390,7 @@ def test_epoch_rows_is_exact():
     assert check.judge(dict({n: 0.0 for n in check.NUMBERS},
                             epoch_rows=0.0),
                        dict({n: 0.0 for n in check.NUMBERS},
-                            epoch_rows=0))[0]
+                            epoch_rows=0), 64)[0]
 
 
 def test_gap_is_of_norms():
@@ -311,6 +409,7 @@ def test_benchmark_json_is_served_by_files():
     assert set(b) == {"command", "paths", "run_seconds", "configs",
                       "workloads", "end_to_end", "per_layer"}
     bdir = os.path.join(tiny.ROOT, b["paths"][0])
+    layout = {}
     for c in b["configs"]:
         assert NAME.match(c["name"])
         with open(os.path.join(tiny.ROOT, c["file"])) as f:
@@ -323,15 +422,29 @@ def test_benchmark_json_is_served_by_files():
         assert set(c["reduced"]) <= set(cfg)
         assert set(c["reduced"]) == set(cfg["about"]["reduced"]) | {
             k for k in cfg["about"]["assumed"] if k != "why"}
-        assert cfg["control"] and cfg["precision"]
+        # the control: the program's keys for its nearest precision
+        # below, or one of calibrate.py's planted faults by name
+        control = dict(cfg["control"])
+        assert control.pop("why") and cfg["precision"]
+        if "fault" in control:
+            assert control == {"fault": control["fault"]}
+            assert control["fault"] in calibrate.FAULTS
+        else:
+            assert control and set(control) <= {"V_dtype", "slot_dtype"}
+        layout[c["name"]] = int(cfg["V_dim"])
     e2e = {m["name"] for m in b["end_to_end"]}
     assert "setup_s" in e2e
     for w in b["workloads"]:
         assert NAME.match(w["name"]) and len(w["why"]) <= 200
         assert os.path.exists(os.path.join(
             bdir, "traffic", w["traffic"] + ".json"))
+        # the limits file names the numbers of its own layout, and none
+        # of the other's
         with open(os.path.join(bdir, "limits", w["name"] + ".json")) as f:
-            assert set(json.load(f)) >= set(check.NUMBERS)
+            limited = {k for k in json.load(f) if not k.startswith("_")}
+        mine = set(check.names(layout[w["config"]]))
+        other = set(check.NUMBERS + check.FLAT_NUMBERS) - mine
+        assert limited >= mine | {"epoch_rows"} and not limited & other
         mine = [m["name"] for m in R.metrics_of(b, "end_to_end",
                                                 w["name"])]
         assert "setup_s" in mine and len(mine) >= 2
