@@ -521,6 +521,18 @@ class SGDLearner(Learner):
                 "bytes the replay cache holds, as charged to "
                 "device_cache_mb (per host: a replicated array once a "
                 "device)"))
+        # the model as the epoch's end found it (run(): the two scalars
+        # the epoch line prints)
+        self._nnz_g = self.obs.gauge(
+            names.MODEL_NNZ_W,
+            "non-zero weights of the model at the last epoch's end "
+            "(nnz(w) of the epoch line; with V_dim > 0 plus V_dim a live "
+            "embedding)").labels(job="train")
+        self._penalty_g = self.obs.gauge(
+            names.MODEL_PENALTY,
+            "regularization penalty of the model at the last epoch's "
+            "end (l1 |w| + l2/2 w^2, summed; the epoch line's penalty)"
+        ).labels(job="train")
         # the fill of the step's unique-row dimension: rows / cap is the
         # share of every cap-sized leg that is not padding (_enqueue)
         cap_c = self.obs.counter(
@@ -965,6 +977,11 @@ class SGDLearner(Learner):
             with trace.span(names.TURN_EVAL, epoch=k):
                 train_prog.penalty, train_prog.nnz_w = \
                     self._take_eval_scalars()
+                # the epoch line's two numbers, for a dashboard: nnz(w)
+                # is the reference scheduler's progress column and an
+                # l1 model's product (host floats already: no fetch)
+                self._nnz_g.set(float(train_prog.nnz_w))
+                self._penalty_g.set(float(train_prog.penalty))
             log.info("epoch[%d] training: %s, nnz(w) = %g, penalty = %g",
                      k, train_prog.text(), train_prog.nnz_w,
                      train_prog.penalty)
@@ -1218,8 +1235,10 @@ class SGDLearner(Learner):
         """The one prologue and accounting of EVERY step-program enqueue
         (single, paired replay, mesh, SPMD): traverse the ``step.device``
         chaos point (step.py), count the table row traffic of
-        ``n_steps`` steps (u_cap fused rows pulled, and pushed again
-        when training — updaters.gather_bytes; the serve path counts
+        ``n_steps`` steps (u_cap rows pulled, and pushed again when
+        training — updaters.gather_bytes / scatter_bytes: the fused row
+        whole each way, or the flat table's three scalar gathers and
+        three scatters; the serve path counts
         its own under path="serve"; under a feature-sharded table the
         pulled operand is also what the gather's all-reduce moves:
         ``store_exchange_bytes_total``), the fill of their row cap
@@ -1234,18 +1253,19 @@ class SGDLearner(Learner):
         the ``dispatch`` stage (its seconds also land in ``step``) with
         one ``train_step_seconds`` observation a step."""
         from ..step import fire_step_fault
-        from ..updaters.sgd_updater import gather_bytes
+        from ..updaters.sgd_updater import gather_bytes, scatter_bytes
         fire_step_fault()
-        per_dir = gather_bytes(self.store.param, self.store.state.capacity,
-                               u_cap)
-        self._gather_c.inc(
-            per_dir * n_steps * (2 if job_type == K_TRAINING else 1))
+        training = job_type == K_TRAINING
+        geom = (self.store.param, self.store.state.capacity, u_cap)
+        pull = gather_bytes(*geom, training=training)
+        push = scatter_bytes(*geom) if training else 0
+        self._gather_c.inc((pull + push) * n_steps)
         if self._fs_sharded:
             # the pull alone crosses chips: every shard computes every
             # update from the replicated batch and writes its own rows
-            self._exchange_c.inc(per_dir * n_steps)
+            self._exchange_c.inc(pull * n_steps)
         cap_c, rows_c, ccap_c, chunks_c, own_c, ocap_c = self._fill_c[
-            job_type == K_TRAINING]
+            training]
         cap_c.inc(u_cap * n_steps)
         rows_c.inc(rows)
         if chunks is not None:

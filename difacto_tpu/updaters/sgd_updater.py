@@ -346,17 +346,38 @@ def state_bytes(param: SGDUpdaterParam, capacity: int) -> int:
     return capacity * Wx * np.dtype(v_dtype(param)).itemsize
 
 
-def gather_bytes(param: SGDUpdaterParam, capacity: int, u_cap: int) -> int:
-    """HBM bytes ONE direction of a fused row gather (or scatter) of
-    ``u_cap`` unique rows moves at this table capacity's row layout —
-    the per-dispatch unit of the ``store_gather_bytes_total`` counter
-    (docs/observability.md): serve counts it once per dispatch (pull
-    only), train twice (pull + push), so cross-shard row traffic is
-    observable per path."""
+def gather_bytes(param: SGDUpdaterParam, capacity: int, u_cap: int,
+                 training: bool = False) -> int:
+    """HBM bytes the PULL of ``u_cap`` unique rows moves at this table
+    capacity's row layout — with :func:`scatter_bytes` the per-dispatch
+    unit of the ``store_gather_bytes_total`` counter
+    (docs/observability.md): serve counts the pull once per dispatch,
+    train the pull and the push, so cross-shard row traffic is
+    observable per path.
+
+    A fused-row table (``V_dim > 0``) pulls each row once, whole, and
+    the step threads it to the push. The flat table (``V_dim = 0``) has
+    no row: a predict step gathers ``w`` alone, and a ``training`` step
+    three float32 scalars a slot, ``w``, ``sqrt_g`` and ``z``. (Its
+    text asks for ``w`` twice, in ``get_rows`` for the forward and again
+    in ``apply_grad`` for the push's FTRL; the two gathers are the same
+    operation on the same operand and XLA merges them, on the CPU and
+    for the TPU alike: tests/test_flat_table.py counts the compiled
+    program's.)"""
     if param.V_dim == 0:
-        return u_cap * 3 * 4
+        return u_cap * (3 if training else 1) * 4
     _, _, Wx, _ = row_layout(param, capacity)
     return u_cap * Wx * np.dtype(v_dtype(param)).itemsize
+
+
+def scatter_bytes(param: SGDUpdaterParam, capacity: int, u_cap: int) -> int:
+    """HBM bytes the PUSH of ``u_cap`` unique rows writes back: the
+    fused row whole, or the flat table's ``w``, ``sqrt_g`` and ``z``
+    (``cnt`` moves only with the count push of epoch 0, which is not a
+    step's traffic)."""
+    if param.V_dim == 0:
+        return u_cap * 3 * 4
+    return gather_bytes(param, capacity, u_cap)
 
 
 def set_all_live(param: SGDUpdaterParam, state: SGDState) -> SGDState:
