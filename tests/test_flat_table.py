@@ -12,7 +12,11 @@ what the cell cannot see is guarded here:
 (c) one call of the pair-replay program equals two single replayed
     steps, bit for bit;
 (d) the flat table under ``mesh_fs = 2`` (what the source's 50 key-range
-    servers are; no cell has it) trains as on one device.
+    servers are; no cell has it) trains as on one device;
+(e) ISSUE 36: compiled for a described v5e at the cell's shapes, the
+    step's per-token gathers (``w[idx]``, ``p[idx]``) are row gathers of
+    one slab of 128-lane rows that stays in fast memory, and no
+    one-dimensional gather of the batch's cells is left.
 """
 
 import json
@@ -237,3 +241,59 @@ def test_flat_table_under_mesh_fs2_trains_as_one_device(data):
     assert np.array_equal(w1 == 0, w2 == 0)
     assert 0 < np.count_nonzero(w1) < np.count_nonzero(
         np.asarray(one.store.state.sqrt_g))
+
+
+# ------------------------- (e) the per-token gathers, as the TPU compiles them
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+def test_flat_step_gathers_lane_rows_in_slabs(topo):
+    """The flat train step at the cell's shapes (2^29 rows, 65,536 x 39,
+    row cap 294,912; the compile helper of ``tests/perfbench``, which
+    lays out the head-less 7,274,528 chunk cells). Shapes only: nothing
+    runs, and what it reads is the compiler's text and count, not a
+    device number. About a minute and a half."""
+    from jax.sharding import SingleDeviceSharding
+    from difacto_tpu.losses.fm import _LANE_SLAB
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.abspath(__file__)), "perfbench"))
+    import test_perfbench_compile as C
+    cfg = C._config("lr_l1_criteo")
+    assert cfg["hash_capacity"] == 2 ** 29 and cfg["V_dim"] == 0
+    compiled = C._compile_step(cfg, None,
+                               SingleDeviceSharding(topo.devices[0]))
+    text = compiled.as_text()
+    cells = C.B * C.F                     # 2,555,904: the forward's
+    # every gather of the program by its result's shape
+    shapes = [tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"= f32\[([\d,]+)\]\S* gather\(",
+                                     text)]
+    assert shapes, "no gather in the program's text"
+    flat = [s for s in shapes if len(s) == 1]
+    # the three pulls of the row cap stay scalar gathers from the table;
+    # no one-dimensional gather is as long as the batch's cells
+    assert flat and max(flat) == (C.U,), flat
+    assert not [s for s in flat if s[0] >= cells]
+    # the two per-token gathers: one slab of whole 128-lane rows, out of
+    # w's [2304, 128] and the padded p's [513, 128], kept in fast memory
+    # (S(1)) from the gather to the select + lane sum
+    assert sorted(s for s in shapes if len(s) == 2) \
+        == [(_LANE_SLAB, 128)] * 2, shapes
+    for rows in (C.U // 128, -(-(C.B + 1) // 128)):
+        assert re.search(rf"f32\[{rows},128\]\S* parameter\(", text), rows
+    kept = re.findall(rf"= f32\[{_LANE_SLAB},128\]\{{[^}}]*S\(1\)\}} "
+                      r"fusion\(.*kind=kCustom", text)
+    assert len(kept) == 2, kept
+    # one slab live at a time: the parent's 0.28 GB of temporaries, not
+    # the 3.7 GB of all cells' rows at once
+    total, m = C._per_device_bytes(compiled)
+    assert total < 17 * 2 ** 29 + 0.28e9 + 0.3e9, (total, m)

@@ -631,3 +631,117 @@ def test_two_tier_terms_are_float32():
     # the partials are added INTO the gathered head rows [U, k+1]
     assert scatters[0].invars[0].aval.shape == (U, k + 1)
     assert scatters[0].invars[2].aval.shape == (C, k + 1)
+
+
+# ------------------------------- ISSUE 36: element idx through the row gather
+# the flat model's per-token gathers (w[idx] in the panel forward, p[idx]
+# in the chunked backward) read rows of 128 lanes in slabs and select one
+def _bits(x):
+    """float32 values as their bits, the sign of a zero aside."""
+    import numpy as np
+    x = np.asarray(x, np.float32)
+    return np.where(x == 0, np.float32(0), x).view(np.uint32)
+
+
+@pytest.mark.parametrize("site", ["forward", "backward"])
+@pytest.mark.parametrize("count", ["under", "at", "over"])
+@pytest.mark.parametrize("n", [1, 127, 128, 65537])
+def test_take_lanes_is_the_plain_gather_bit_for_bit(monkeypatch, n, count,
+                                                    site):
+    """``_take_lanes`` against the expression it stands for at each of
+    its two sites, over vectors that are and are not whole rows of 128
+    lanes, index lists under, at and over one slab (a small slab patched
+    in), indices at both ends and out of range on both sides, and a NaN
+    and an Inf in lanes of a selected row that no index selects."""
+    import jax.numpy as jnp
+    import numpy as np
+    from difacto_tpu.losses import fm
+    slab = 64
+    monkeypatch.setattr(fm, "_LANE_SLAB", slab)
+    rng = np.random.RandomState(n)
+    vec = rng.randn(n).astype(np.float32)
+    vec[rng.rand(n) < 0.3] = 0.0
+    vec[::7] *= -1                      # -0.0 among the zeros
+    ends = [0, n - 1, -1, -n, -n - 3, n, n + 5, 2 ** 31 - 1, -2 ** 31]
+    allowed = np.arange(n)
+    if n >= 127:
+        vec[5], vec[6] = np.nan, np.inf
+        allowed = allowed[(allowed != 5) & (allowed != 6)]
+        ends += [4, 7]                  # their neighbours, the same row
+    many = {"under": slab - 7, "at": slab, "over": 3 * slab + 9}[count]
+    many = max(many, len(ends))
+    idx = rng.choice(allowed, many).astype(np.int32)
+    idx[:len(ends)] = ends
+    if n >= 127:                        # -1 -> n-1, n.. -> n-1: never 5, 6
+        assert not np.isin(np.clip(np.where(idx < 0, idx + n, idx),
+                                   0, n - 1), [5, 6]).any()
+    vec_d = jnp.asarray(vec)
+    if site == "forward":               # params.w[pb.idx], [B, F]
+        idx = idx[: many - many % 3].reshape(-1, 3)
+        want = vec_d[jnp.asarray(idx)]
+        got = fm._take_lanes(vec_d, jnp.asarray(idx))
+    else:                               # pad(p).at[idx].get(clip), [C', L]
+        idx = idx[: many - many % 4].reshape(-1, 4)
+        want = jnp.pad(vec_d, (0, 1)).at[jnp.asarray(idx)].get(mode="clip")
+        got = fm._take_lanes(jnp.pad(vec_d, (0, 1)), jnp.asarray(idx))
+    assert got.shape == idx.shape and got.dtype == jnp.float32
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    assert np.isfinite(np.asarray(got)).all()
+    # under one slab the program has no loop; over it, one
+    import jax
+    text = str(jax.make_jaxpr(fm._take_lanes)(vec_d, jnp.asarray(idx)))
+    assert ("scan[" in text) == (idx.size > slab)
+
+
+@pytest.mark.parametrize("slabs", ["one", "many"])
+@pytest.mark.parametrize("head", [True, False])
+@pytest.mark.parametrize("kind", ["binary", "valued"])
+def test_flat_panel_sites_match_coo(monkeypatch, kind, head, slabs):
+    """The two sites end to end: the flat (``V = None``) panel forward
+    and chunked backward, with and without the head tier and values, in
+    one slab and in many, against ``fm_predict`` / ``fm_grad`` on the COO
+    batch of the same rows."""
+    import jax.numpy as jnp
+    import numpy as np
+    from difacto_tpu.data.rowblock import RowBlock
+    from difacto_tpu.losses import (FMParams, fm, fm_grad, fm_grad_panel,
+                                    fm_predict, fm_predict_panel)
+    from difacto_tpu.ops.batch import (chunks_needed, pad_batch, pad_panel,
+                                       panel_chunk_tokens_np)
+    if slabs == "many":
+        monkeypatch.setattr(fm, "_LANE_SLAB", 32)
+    flat, vals, F = _tt_panel(kind)
+    B, U = len(flat) // F, _TT_U
+    rng = np.random.RandomState(36)
+    blk = RowBlock(
+        offset=np.arange(B + 1, dtype=np.int64) * F,
+        label=rng.choice([0.0, 1.0], B).astype(np.float32),
+        index=flat.astype(np.uint32), value=vals,
+        weight=rng.rand(B).astype(np.float32))
+    params = FMParams(w=jnp.asarray(rng.randn(U).astype(np.float32)))
+    coo = pad_batch(blk, num_uniq=U, batch_cap=B)
+    layout = panel_chunk_tokens_np(
+        flat, vals, U, B, F, C=chunks_needed(flat, U, head=head) + 2,
+        head=head)
+    pb = pad_panel(blk, U, B, F).with_chunks(
+        tuple(None if x is None else jnp.asarray(x) for x in layout))
+    assert (pb.head_row is not None) == head
+    assert (pb.vals is not None) == (vals is not None)
+    if slabs == "many":
+        assert pb.idx.size > 32 and pb.chunk_idx.size > 32
+    pred_c = fm_predict(params, coo)
+    pred_p = fm_predict_panel(params, pb)
+    np.testing.assert_allclose(np.asarray(pred_p), np.asarray(pred_c),
+                               rtol=1e-5, atol=1e-6)
+    gw_c, gV_c = fm_grad(params, coo, pred_c)
+    gw_p, gV_p = fm_grad_panel(params, pb, pred_c)
+    assert gV_c is None and gV_p is None
+    np.testing.assert_allclose(np.asarray(gw_p), np.asarray(gw_c),
+                               rtol=5e-5, atol=1e-6)
+    # and in one slab or many the values are the same to the bit
+    if slabs == "many":
+        monkeypatch.setattr(fm, "_LANE_SLAB", 1 << 20)
+        np.testing.assert_array_equal(
+            _bits(fm_predict_panel(params, pb)), _bits(pred_p))
+        np.testing.assert_array_equal(
+            _bits(fm_grad_panel(params, pb, pred_c)[0]), _bits(gw_p))
