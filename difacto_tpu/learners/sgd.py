@@ -533,6 +533,12 @@ class SGDLearner(Learner):
             "regularization penalty of the model at the last epoch's "
             "end (l1 |w| + l2/2 w^2, summed; the epoch line's penalty)"
         ).labels(job="train")
+        self._live_g = self.obs.gauge(
+            names.MODEL_LIVE_V,
+            "live embeddings of the model at the last epoch's end: rows "
+            "whose count passed V_threshold while w was non-zero (the "
+            "V_dim-a-row term of the epoch line's nnz(w); 0 at V_dim = 0)"
+        ).labels(job="train")
         # the fill of the step's unique-row dimension: rows / cap is the
         # share of every cap-sized leg that is not padding (_enqueue)
         cap_c = self.obs.counter(
@@ -975,16 +981,18 @@ class SGDLearner(Learner):
             # (these are children of the open epoch_turn stage, so that
             # a device-idle gap at the epoch boundary names its cause)
             with trace.span(names.TURN_EVAL, epoch=k):
-                train_prog.penalty, train_prog.nnz_w = \
+                train_prog.penalty, train_prog.nnz_w, live_V = \
                     self._take_eval_scalars()
-                # the epoch line's two numbers, for a dashboard: nnz(w)
-                # is the reference scheduler's progress column and an
-                # l1 model's product (host floats already: no fetch)
+                # the epoch line's numbers, for a dashboard: nnz(w) is
+                # the reference scheduler's progress column and an l1
+                # model's product, live V the memory-adaptive model's
+                # (host floats already: no fetch)
                 self._nnz_g.set(float(train_prog.nnz_w))
                 self._penalty_g.set(float(train_prog.penalty))
-            log.info("epoch[%d] training: %s, nnz(w) = %g, penalty = %g",
-                     k, train_prog.text(), train_prog.nnz_w,
-                     train_prog.penalty)
+                self._live_g.set(float(live_V))
+            log.info("epoch[%d] training: %s, live V = %d, nnz(w) = %g, "
+                     "penalty = %g", k, train_prog.text(), live_V,
+                     train_prog.nnz_w, train_prog.penalty)
 
             # occupancy-pressure eviction (ISSUE 19, evict_occupancy):
             # epoch boundary only — one full-table column read, and the
@@ -2362,17 +2370,17 @@ class SGDLearner(Learner):
     def _final_merge(self, job_type: int, pending: list, prog: Progress
                      ) -> None:
         """Epoch-final metric fetch; training epochs piggyback the store's
-        (penalty, nnz) scalars on the same transfer (run() reads them via
-        _take_eval_scalars) — one RTT instead of two per epoch."""
+        (penalty, nnz, live_V) scalars on the same transfer (run() reads
+        them via _take_eval_scalars) — one RTT instead of two per epoch."""
         extra = self.store.evaluate_dev() if job_type == K_TRAINING else ()
         vals = self._merge_pending(pending, prog, extra=extra, final=True)
         if extra:
-            self._eval_scalars = (vals[0], vals[1])
+            self._eval_scalars = tuple(vals[:3])
 
     def _take_eval_scalars(self):
         s = getattr(self, "_eval_scalars", None)
         self._eval_scalars = None
-        return s if s is not None else self.store.evaluate()
+        return s if s is not None else self.store.evaluate_all()
 
     def _run_pred_executor(self, prog: Progress) -> None:
         """task=pred through serve's PredictExecutor (ISSUE 2 satellite):

@@ -81,10 +81,13 @@ STORE_OWNED_ROWS = "store_owned_rows_total"  # sum of the fullest shard's rows
 STORE_OWNED_CAP = "store_owned_cap_total"    # sum of the steps' own_cap
 
 # ---------------------------------------------------------- model gauges
-# set at every training epoch's end from the two scalars the epoch line
+# set at every training epoch's end from the scalars the epoch line
 # prints (SGDLearner.run), label ``job=train``; trace/#metrics only
 MODEL_NNZ_W = "model_nnz_w"          # nnz(w): an l1 model's product
 MODEL_PENALTY = "model_penalty"      # l1 |w| + l2/2 w^2 over the table
+# rows with a live embedding (cnt > V_threshold met w != 0): what the
+# memory-adaptive FM allocates; nnz(w) charges V_dim for each
+MODEL_LIVE_V = "model_live_V"
 
 # ----------------------------------------------------------------- spans
 EPOCH = "epoch"

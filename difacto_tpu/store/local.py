@@ -439,12 +439,19 @@ class SlotStore:
             raise ValueError(f"unknown val_type {val_type}")
 
     def evaluate(self) -> Tuple[float, float]:
-        penalty, nnz = self.evaluate_dev()
-        return float(penalty), float(nnz)
+        return self.evaluate_all()[:2]
+
+    def evaluate_all(self) -> Tuple[float, float, float]:
+        """(penalty, nnz, live_V) on the host."""
+        from ..utils import jaxtrace
+        vals = jaxtrace.fetch(jnp.stack(self.evaluate_dev()),
+                              point="store.evaluate")
+        return tuple(float(v) for v in vals)
 
     def evaluate_dev(self):
-        """(penalty, nnz) as DEVICE scalars — callers batch the fetch with
-        other pending metrics (a sync fetch drains the dispatch queue)."""
+        """(penalty, nnz, live_V) as DEVICE scalars — callers batch the
+        fetch with other pending metrics (a sync fetch drains the
+        dispatch queue)."""
         if not hasattr(self, "_eval_jit"):
             from ..utils import jaxtrace
             self._eval_jit = jaxtrace.jit(self.fns.evaluate)

@@ -697,13 +697,19 @@ def make_fns(param: SGDUpdaterParam, mesh=None):
         return apply_grad_rows(state, slots, rows, gw, gV, pull_vmask)
 
     @names.leg(names.EVALUATE)
-    def evaluate(state: SGDState) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """(penalty, nnz) over real rows (Evaluate, sgd_updater.cc:15-32).
-        Full-table column reads of the fused rows — once per epoch."""
+    def evaluate(state: SGDState
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+        """(penalty, nnz, live_V) over real rows (Evaluate,
+        sgd_updater.cc:15-32). Full-table column reads of the fused rows
+        — once per epoch. ``live_V`` is the count of live embeddings
+        that ``nnz`` charges ``V_dim`` each (0 without an embedding):
+        the memory-adaptive model's product, ``nnz``'s own term handed
+        out beside it."""
         w, _, _, _, live = scal_cols(param, state)
         w = w.at[TRASH_SLOT].set(0.0)
         penalty = jnp.sum(l1 * jnp.abs(w) + 0.5 * l2 * w * w)
         nnz = jnp.sum((w != 0).astype(jnp.float32))
+        live_V = jnp.zeros((), jnp.float32)
         if has_V:
             live = live.at[TRASH_SLOT].set(False)
             Vcol = (emb_cols_f32(param, state)[0] if quantized(param)
@@ -711,8 +717,10 @@ def make_fns(param: SGDUpdaterParam, mesh=None):
             Vm = Vcol * live[:, None]
             # quirk preserved: Evaluate charges l2 (not V_l2) on V
             penalty = penalty + jnp.sum(0.5 * l2 * Vm * Vm)
-            nnz = nnz + jnp.sum(live) * param.V_dim
-        return penalty, nnz
+            n_live = jnp.sum(live)
+            nnz = nnz + n_live * param.V_dim
+            live_V = n_live.astype(jnp.float32)
+        return penalty, nnz, live_V
 
     class _NS:
         pass

@@ -89,6 +89,8 @@ def test_cli_example_confs_train(conf, overrides, monkeypatch, caplog):
     ("criteo_dict.conf", ["V_dim=2"]),
     # the flat table (V_dim = 0 stays): l1 logistic regression, one chip
     ("criteo_lr_l1.conf", ["hash_capacity=4096"]),
+    # the memory-adaptive FM at the reference's default gates, one chip
+    ("avazu_fm.conf", ["hash_capacity=4096"]),
 ])
 def test_cli_example_conf_templates_parse(conf, shrink):
     # the criteo confs are templates (data_in commented out): guard them
