@@ -24,6 +24,7 @@ import contextlib
 import dataclasses
 import logging
 import os
+import re
 import threading
 import time
 from dataclasses import dataclass
@@ -49,6 +50,27 @@ log = logging.getLogger("difacto_tpu")
 # job types (sgd::Job, src/sgd/sgd_utils.h:16-21)
 K_LOAD_MODEL, K_SAVE_MODEL, K_TRAINING, K_VALIDATION, K_PREDICTION, \
     K_EVALUATION = 1, 2, 3, 4, 5, 6
+
+
+def _rematerialised(compiled, rows: int) -> bool:
+    """True where ``compiled``'s text holds a rematerialised instruction
+    whose result has ``rows`` rows: XLA's rematerialisation pass, which
+    runs when its count of live bytes passes the device's memory, has
+    made a value of the table's size more than once (it names the clones
+    ``<name>.remat``, ``.remat2``, ...). Buffer assignment updates the
+    table in place either way, so the clones save no byte and cost their
+    whole time: three scatters for two steps at 2^24 fused bf16 rows."""
+    from jax._src.lib import xla_client
+    # names and shapes without literals: ``compiled.as_text()`` prints
+    # the flat table's empty ``f32[rows, 0]`` constant as ``rows`` pairs
+    # of braces, 2 GB and a minute of the interpreter lock at 2^29 rows
+    how = xla_client._xla.HloPrintOptions.fingerprint()
+    how.canonicalize_instruction_names = False
+    how.print_ids = how.print_percent = True
+    text = "\n".join(m.to_string(how) for m in
+                     compiled.runtime_executable().hlo_modules())
+    return re.search(rf"^\s*(?:ROOT )?%\S*\.remat\S* = \(?\w+\[{rows}[,\]]",
+                     text, re.M) is not None
 
 
 class _DeviceBatchCache:
@@ -866,30 +888,70 @@ class SGDLearner(Learner):
             packed_panel_train_raw, donate_argnums=0,
             static_argnums=(3, 4, 5, 6))
 
-        def packed_panel_train_chunked2(state, pa, pb, b_cap, width,
-                                        u_cap, has_cnt, binary):
+        def pair_program(loop: bool):
             # TWO cached batches in ONE dispatch (replay epochs only):
             # each program invocation costs host marshalling that a
             # ~30-step replay epoch pays in full; pairing halves the
-            # invocation count. Straight-line composition, NOT lax.scan
-            # — the scan's loop-carry copies on the gather-then-scatter
-            # table were measured 55% slower at V64; unrolling keeps
-            # the donated in-place update.
-            state, o1, a1 = packed_panel_train_chunked(
-                state, *pa, b_cap, width, u_cap, has_cnt, binary)
-            state, o2, a2 = packed_panel_train_chunked(
-                state, *pb, b_cap, width, u_cap, has_cnt, binary)
-            return state, o1, a1, o2, a2
+            # invocation count. Two forms of one arithmetic, bit for bit
+            # (tests/test_pair_program.py); _warm_pair_exec says which
+            # is built. In a straight line the table between the two
+            # steps is a value of its own in the compiler's count of
+            # live bytes beside the donated one: where both fit, this is
+            # the faster form (on the v5e: +2.1% on V16's rate and +0.9%
+            # on the flat table's against the loop, -0.7% on V64's).
+            # Where they do not (2^24 fused bf16 rows: 2 x 8.59 GB), XLA
+            # rematerialises the first step's scatter, three table-sized
+            # scatters for two steps; the loop carries ONE table through
+            # two trips of the one-batch program, table in, table out,
+            # updated in place (-3.8 ms of a 27.07 ms step there).
+            def packed_panel_train_chunked2(state, pa, pb, b_cap, width,
+                                            u_cap, has_cnt, binary):
+                def step(state, payload):
+                    return packed_panel_train_chunked(
+                        state, *payload, b_cap, width, u_cap, has_cnt,
+                        binary)
 
+                if not loop:
+                    state, o1, a1 = step(state, pa)
+                    state, o2, a2 = step(state, pb)
+                    return state, o1, a1, o2, a2
+
+                def trip(i, carry):
+                    state, _, _, o1, a1 = carry
+                    # the trip's batch: one of the two staged tuples, by
+                    # a conditional (~15 MB of i32 / f32 a batch copied;
+                    # no staged buffer moves). Not a select: it would
+                    # fuse into every reader of the batch, and the body
+                    # would no longer be the one-batch program operation
+                    # for operation
+                    state, o2, a2 = step(state, jax.lax.cond(
+                        i == 0, lambda: pa, lambda: pb))
+                    # the scalars shift through the carry untouched:
+                    # filed into a slot of an array, the loss's sum would
+                    # be fused into the update and the CPU backend would
+                    # add it up in another order than the one-batch
+                    # program does
+                    return state, o1, a1, o2, a2
+
+                zero = jnp.float32(0.0)
+                return jax.lax.fori_loop(0, 2, trip,
+                                         (state, zero, zero, zero, zero))
+
+            return jaxtrace.jit(packed_panel_train_chunked2,
+                                donate_argnums=0,
+                                static_argnums=(3, 4, 5, 6, 7))
+
+        # both forms carry the function's name: a trace reads
+        # jit(packed_panel_train_chunked2) whichever runs
         # lint: ok(data-race) written once in _build_steps before any
         # warm-pool thread exists; workers only read the jitted fn
-        self._packed_panel_train_chunked2 = jaxtrace.jit(
-            packed_panel_train_chunked2, donate_argnums=0,
-            static_argnums=(3, 4, 5, 6, 7))
+        self._packed_panel_train_chunked2 = pair_program(loop=False)
+        self._packed_panel_train_chunked2_loop = pair_program(loop=True)
         # statics-key -> compiled pair executable (or None while the
         # background compile runs / if it failed). Replay pairs ONLY
-        # when the executable is ready, so the ~18 s pair compile never
-        # lands on an epoch's critical path (_warm_pair_exec).
+        # when the executable is ready, so the pair compile (3-33 s at
+        # the benchmark's shapes) never lands on an epoch's critical
+        # path (_warm_pair_exec).
         # lint: ok(data-race) dict binding set before the first warm
         # thread spawns; workers mutate items, never rebind
         self._pair_execs: dict = {}
@@ -2207,8 +2269,21 @@ class SGDLearner(Learner):
         (packed_panel_train_chunked2) for this payload shape. Launched
         from the staging pass so the compile overlaps its streaming;
         replay pairs only once the executable is ready, so the compile
-        never extends any epoch (a paired first call would cost ~18 s
-        in-line — measured, epoch 2 of the criteo V16 run).
+        never extends any epoch. On the v5e's host, cache off, at the
+        benchmark's one-chip shapes (PERF.md 6, PR 38): 4.9 s (2^23 f32
+        rows of 128 lanes), 7.1 s (2^23 fused bf16 rows), 13.0 s (2^24)
+        and 33.0 s (the flat table's three leaves of 2^29) for the
+        straight line; the loop 3.2 / 8.4 / 11.2 / 33.2 s.
+
+        Which form of the pair (_build_steps jits both) is
+        decided by what the compiler made of the straight line: where
+        its text holds a rematerialised instruction of the table's size
+        (:func:`_rematerialised`: the count of live bytes had no room
+        for the table between the two steps, and the clones cost a
+        third scatter) the loop over one carried table is built in its
+        place; everywhere else the straight line is the faster form.
+        Each compile is a ``compile.pair_exec`` span with its ``form``,
+        so the last one of a shape names the form that runs.
 
         The pair program is compiled with has_cnt=False regardless of the
         payload statics: it serves REPLAY epochs only, whose counts tail
@@ -2230,11 +2305,12 @@ class SGDLearner(Learner):
         exec compiled at an intermediate capacity would fail the AOT
         shape check), so a stale-capacity exec is simply never found and
         the replay entry re-warms at the live capacity."""
-        key = statics + (self.store.state.capacity,)
+        rows = self.store.state.capacity
+        key = statics + (rows,)
         if key in self._pair_execs or self.mesh is not None:
             return
         # evict same-shape execs compiled at older capacities: each is a
-        # dead ~18 s XLA artifact after dictionary growth, and repeated
+        # dead XLA artifact after dictionary growth, and repeated
         # growths would otherwise accumulate them for the life of the run
         for stale in [k for k in self._pair_execs if k[:-1] == statics]:
             del self._pair_execs[stale]
@@ -2248,16 +2324,26 @@ class SGDLearner(Learner):
         pa = jax.tree_util.tree_map(sds, arrays)
         b_cap, width, u_cap, _, binary, _ = statics
 
+        # bound here, on the caller's thread; the worker takes the function
+        looped = self._packed_panel_train_chunked2_loop
+
+        def compiled(loop: bool):
+            # its backend-compile seconds reach
+            # stage_seconds_total{stage=compile} through the
+            # process-wide listener (obs.watch_compiles)
+            with trace.span(names.COMPILE_PAIR, u_cap=u_cap,
+                            form="loop" if loop else "line"):
+                program = (looped if loop
+                           else self._packed_panel_train_chunked2)
+                return program.lower(state_s, pa, pa, b_cap, width, u_cap,
+                                     False, binary).compile()
+
         def build():
             try:
-                # its backend-compile seconds reach
-                # stage_seconds_total{stage=compile} through the
-                # process-wide listener (obs.watch_compiles)
-                with trace.span(names.COMPILE_PAIR, u_cap=u_cap):
-                    lowered = self._packed_panel_train_chunked2.lower(
-                        state_s, pa, pa, b_cap, width, u_cap, False,
-                        binary)
-                    self._pair_execs[key] = lowered.compile()
+                exec_ = compiled(False)
+                if _rematerialised(exec_, rows):
+                    exec_ = compiled(True)
+                self._pair_execs[key] = exec_
             except Exception as e:
                 # handed to the dispatch thread: the next replay of this
                 # shape raises it (_replay_cached) — a program the run
