@@ -499,7 +499,6 @@ class SGDLearner(Learner):
         #   epoch_turn = an epoch's final fetch returned -> the next
         #                epoch's first enqueue
         #   compile    = backend-compile seconds (jax.monitoring)
-        # stage_stats() reads this registry — no private timers.
         from ..obs import Registry, watch_compiles
         from ..obs.stage import stage_counter
         self.obs = Registry()
@@ -587,12 +586,37 @@ class SGDLearner(Learner):
         ocap_c = self.obs.counter(
             names.STORE_OWNED_CAP,
             "owned-run cap (own_cap) of every such step, summed")
+        # steps a dispatch: 2 where a replayed epoch pairs, 1 elsewhere
+        steps_c = self.obs.counter(
+            names.STEPS, "steps of every dispatched step program, summed")
+        disp_c = self.obs.counter(
+            names.STEP_DISPATCHES,
+            "step programs enqueued (a paired replay dispatch is one "
+            "enqueue of two steps)")
         self._fill_c = {train: (cap_c.labels(job=job), rows_c.labels(job=job),
                                 ccap_c.labels(job=job),
                                 chunks_c.labels(job=job),
                                 own_c.labels(job=job),
-                                ocap_c.labels(job=job))
+                                ocap_c.labels(job=job),
+                                steps_c.labels(job=job),
+                                disp_c.labels(job=job))
                         for train, job in ((True, "train"), (False, "eval"))}
+        # the epoch's record (names.EPOCH_COUNTS): the series whose change
+        # over a training epoch it carries, by argument, and their values
+        # at the previous record
+        cap, rows, ccap, chunks, own, ocap, steps, disp = self._fill_c[True]
+        self._count_series = (
+            ("steps", steps), ("dispatches", disp),
+            ("examples", self._rows_c.labels()),
+            ("row_cap", cap), ("rows", rows),
+            ("chunk_cap", ccap), ("chunks", chunks),
+            ("own_cap", ocap), ("own_rows", own),
+            ("gather_bytes", self._gather_c),
+            ("exchange_bytes", self._exchange_c),
+            ("compile_s", stage_counter(self.obs, names.COMPILE)))
+        self._compiles_c = self.obs.counter(names.COMPILES,
+                                            names.COMPILES_HELP)
+        self._counts_prev: dict = {}
         self._last_producer_mode = "thread"
         self._flusher = None
         self._shapes = _ShapeSchedule()
@@ -1055,6 +1079,7 @@ class SGDLearner(Learner):
             log.info("epoch[%d] training: %s, live V = %d, nnz(w) = %g, "
                      "penalty = %g", k, train_prog.text(), live_V,
                      train_prog.nnz_w, train_prog.penalty)
+            self._record_epoch_counts(k)
 
             # occupancy-pressure eviction (ISSUE 19, evict_occupancy):
             # epoch boundary only — one full-table column read, and the
@@ -1298,6 +1323,31 @@ class SGDLearner(Learner):
         if turn is not None:
             turn.end()
 
+    def _record_epoch_counts(self, epoch: int) -> None:
+        """Emit the epoch's record: one span ``epoch.counts`` with no
+        length whose arguments say what the run did since the previous
+        record (``names.COUNT_ARGS``: steps and dispatches, examples,
+        the fills' numerators and denominators, bytes moved, compiles),
+        and the model's two gauges as they stand. Differences, not
+        totals: the records of the epochs that end inside any stretch of
+        a trace sum to that stretch's work with no opening sample. Host
+        numbers all: nothing is read from the device. (A validation
+        pass runs after its epoch's record, so what it adds to the
+        series that carry no ``job`` label, ``examples`` and the two
+        byte counts, is in the next one.)"""
+        now = {arg: series.value() for arg, series in self._count_series}
+        now["compiles"] = sum(series.value() for series
+                              in self._compiles_c.series().values())
+        prev, self._counts_prev = self._counts_prev, now
+        did = {arg: v - prev.get(arg, 0.0) for arg, v in now.items()}
+        # seconds to the microsecond, the rest whole numbers
+        args = {arg: round(v, 6) if arg == "compile_s" else int(round(v))
+                for arg, v in did.items()}
+        with trace.span(names.EPOCH_COUNTS, epoch=epoch, job=K_TRAINING,
+                        **args, nnz_w=int(self._nnz_g.value()),
+                        live_V=int(self._live_g.value())):
+            pass
+
     @contextlib.contextmanager
     def _enqueue(self, job_type: int, u_cap: int, rows: int,
                  n_steps: int = 1, chunks: Optional[int] = None,
@@ -1334,8 +1384,10 @@ class SGDLearner(Learner):
             # the pull alone crosses chips: every shard computes every
             # update from the replicated batch and writes its own rows
             self._exchange_c.inc(pull * n_steps)
-        cap_c, rows_c, ccap_c, chunks_c, own_c, ocap_c = self._fill_c[
-            training]
+        (cap_c, rows_c, ccap_c, chunks_c, own_c, ocap_c, steps_c,
+         disp_c) = self._fill_c[training]
+        steps_c.inc(n_steps)
+        disp_c.inc()
         cap_c.inc(u_cap * n_steps)
         rows_c.inc(rows)
         if chunks is not None:
@@ -2150,25 +2202,6 @@ class SGDLearner(Learner):
                 for jt, c in getattr(self, "_dev_caches", {}).items()}
 
     # ------------------------------------------------ streamed pipeline
-    # the streamed-pipeline stages that stage_stats() reports, in its
-    # legacy "<stage>_s" form (tests/test_obs.py reads them)
-    _STAGE_KEYS = ("parse_s", "pack_s", "ring_wait_s", "transfer_s",
-                   "step_s")
-
-    def stage_stats(self) -> dict:
-        """Streamed-epoch stage decomposition accumulated over the run —
-        read from THE OBS REGISTRY (stage_seconds_total{stage}), so the
-        numbers include what producer worker processes reported across
-        the process boundary (obs/proc.py) — plus the producer transport
-        that ran, so a streamed regression localizes to a stage instead
-        of hiding in the headline rate."""
-        snap = self.obs.snapshot()
-        series = snap.get("counters", {}).get("stage_seconds_total", {})
-        vals = {dict(k).get("stage", ""): v for k, v in series.items()}
-        out = {k: round(vals.get(k[:-2], 0.0), 3) for k in self._STAGE_KEYS}
-        out["producer_mode"] = self._last_producer_mode
-        return out
-
     def _resolve_producer_mode(self) -> str:
         """auto -> process once the host has cores to overlap (>= 4);
         below that the spawn + ring overhead buys nothing a thread
@@ -2682,7 +2715,7 @@ class SGDLearner(Learner):
                 1, (p.batch_size * 320) >> 20)
             # obs_registry: workers report their parse/pack/ring-wait
             # seconds into THIS learner's registry through the pool's
-            # snapshot channel — stage_stats() then spans both processes
+            # snapshot channel — its stage seconds then span both processes
             pool = ProcessProducerPool(
                 len(stream_parts), functools.partial(spec_iter, spec),
                 n_workers=n_workers, depth=p.producer_depth, pool=wp,

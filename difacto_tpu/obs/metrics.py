@@ -79,6 +79,9 @@ class _Noop:
     def value(self, **_kw) -> float:
         return 0.0
 
+    def series(self) -> dict:
+        return {}
+
 
 NOOP = _Noop()
 

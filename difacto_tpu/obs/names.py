@@ -21,6 +21,9 @@ rename here is a change to what a metric reads:
   boundaries nobody sums).
 - **step fill counters** — what the dispatched steps' padded dimensions
   hold, summed by ``SGDLearner._enqueue``, label ``job=train|eval``.
+- **the epoch's record** — ``epoch.counts``, one span a training epoch
+  whose arguments are what the epoch did: the differences of the
+  counters above since the previous epoch's end (``COUNT_ARGS``).
 """
 
 from __future__ import annotations
@@ -64,7 +67,10 @@ STAGE_SPAN = {PARSE: "producer.parse", PACK: "producer.pack",
               RING_WAIT: "producer.ring_wait"}
 
 # --------------------------------------------------- step fill counters
-# trace/#metrics only: no benchmark metric reads them yet (PERF.md 7 (3))
+# each reaches the traced window through the epoch's record (EPOCH_COUNTS
+# below); PERF.md section 3 names the benchmark metric that reads it
+STEPS = "steps_total"                # steps of the dispatched programs
+STEP_DISPATCHES = "step_dispatches_total"  # enqueues: 2 steps each paired
 STEP_ROW_CAP = "step_row_cap_total"  # sum of the steps' unique-row caps
 STEP_ROWS = "step_rows_total"        # sum of their distinct table rows
 # of the steps that carry a chunked-run backward layout (ops/batch.py):
@@ -80,9 +86,17 @@ STEP_CHUNKS = "step_chunks_total"        # sum of the chunks they need
 STORE_OWNED_ROWS = "store_owned_rows_total"  # sum of the fullest shard's rows
 STORE_OWNED_CAP = "store_owned_cap_total"    # sum of the steps' own_cap
 
+# backend compiles by the jitted function's name, from the listener that
+# feeds ``stage_seconds_total{stage=compile}`` (obs/stage.py); a load
+# from the persistent cache counts (and takes milliseconds)
+COMPILES = "compiles_total"
+COMPILES_HELP = ("backend compiles by the jitted function's name (a load "
+                 "from the persistent compile cache counts)")
+
 # ---------------------------------------------------------- model gauges
 # set at every training epoch's end from the scalars the epoch line
-# prints (SGDLearner.run), label ``job=train``; trace/#metrics only
+# prints (SGDLearner.run), label ``job=train``; they ride the epoch's
+# record as ``nnz_w`` and ``live_V``
 MODEL_NNZ_W = "model_nnz_w"          # nnz(w): an l1 model's product
 MODEL_PENALTY = "model_penalty"      # l1 |w| + l2/2 w^2 over the table
 # rows with a live embedding (cnt > V_threshold met w != 0): what the
@@ -99,6 +113,19 @@ TURN_EVICT = "epoch.evict_check"
 TURN_CALLBACKS = "epoch.callbacks"
 TURN_ITER_PARTS = "replay.iter_parts"
 COMPILE_PAIR = "compile.pair_exec"
+# the epoch's record: no length, its arguments are the content. Emitted
+# by ``SGDLearner.run`` inside the open ``epoch_turn``, between
+# ``epoch.eval_scalars`` and ``epoch.evict_check``
+EPOCH_COUNTS = "epoch.counts"
+# its arguments after ``epoch`` and ``job``, each the change of a series
+# of the learner's registry since the previous record (``compile_s`` in
+# seconds, the rest whole numbers), then the two model gauges as they
+# stand. ``perfbench/counts.py`` sums them between the window's marks (a
+# test pins its list to this one)
+COUNT_ARGS = ("steps", "dispatches", "examples", "row_cap", "rows",
+              "chunk_cap", "chunks", "own_cap", "own_rows",
+              "gather_bytes", "exchange_bytes", "compile_s", "compiles",
+              "nnz_w", "live_V")
 
 # the children of ``epoch_turn``: idle time under one of them is idle
 # time of the turn even where the turn's own span is cut by the start or

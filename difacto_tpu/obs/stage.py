@@ -10,16 +10,19 @@ some stages had one without the other. :class:`stage` is a span
 one end, two sinks. It is the one way to time a boundary.
 
 Also here: the process-wide ``jax.monitoring`` listener that feeds
-``stage_seconds_total{stage=compile}``.
+``stage_seconds_total{stage=compile}`` and ``compiles_total{fn}``.
 """
 
 from __future__ import annotations
 
+import logging
 import weakref
 
 from ..utils.locktrace import mutex
 from . import names
 from .trace import span
+
+log = logging.getLogger(__name__)
 
 
 def stage_counter(registry, name: str):
@@ -66,20 +69,29 @@ _sinks_mu = mutex()
 _listening = False
 
 
-def _on_duration(event: str, secs: float, **_kw) -> None:
-    if event == _COMPILE_EVENT:
-        with _sinks_mu:      # a compile thread against a learner's init
-            sinks = list(_compile_sinks)
-        for registry in sinks:
-            stage_counter(registry, names.COMPILE).inc(secs)
+def _on_duration(event: str, secs: float, fun_name: str = "",
+                 **_kw) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    # which step recompiled (a cap rung crossed mid-run) has its answer
+    # in the log, in #metrics and in the epoch's record
+    log.info("compiled %s in %.3f s", fun_name, secs)
+    with _sinks_mu:      # a compile thread against a learner's init
+        sinks = list(_compile_sinks)
+    for registry in sinks:
+        stage_counter(registry, names.COMPILE).inc(secs)
+        registry.counter(names.COMPILES, names.COMPILES_HELP).labels(
+            fn=fun_name).inc()
 
 
 def watch_compiles(registry) -> None:
     """Feed backend-compile seconds (any thread's, the background
     ``pair-exec-compile`` thread's included) into ``registry``'s
-    ``stage_seconds_total{stage=compile}`` for as long as the registry
-    lives. ONE listener a process however many learners register: JAX
-    offers no way to take a listener off again."""
+    ``stage_seconds_total{stage=compile}``, and a count by the compiled
+    function's name into its ``compiles_total{fn}``, for as long as the
+    registry lives; each compile is also one INFO line. ONE listener a
+    process however many learners register: JAX offers no way to take a
+    listener off again."""
     global _listening
     stage_counter(registry, names.COMPILE)   # the series exists at 0
     with _sinks_mu:

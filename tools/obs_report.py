@@ -9,9 +9,10 @@ Inputs are what the observability subsystem writes during a run:
 - optionally a Chrome trace JSON (``DIFACTO_TRACE=<path>``).
 
 Output: the streamed-stage table (where the run's seconds went), every
-histogram's count/mean/p50/p95/p99, top counters, and the top span
-names by total duration — the first thing to read when a streamed rate
-regresses or a serve replica's latency moves.
+histogram's count/mean/p50/p95/p99, top counters, the top span names by
+total duration, and the ``epoch.counts`` records as a per-epoch table
+(steps, fills, MB moved, compiles) — the first thing to read when a
+streamed rate regresses or a serve replica's latency moves.
 
     python tools/obs_report.py --metrics run.metrics.jsonl \
         --trace run.trace.json
@@ -277,6 +278,36 @@ def report_trace(path: str, top: int = 15) -> None:
     for name, us in sorted(total.items(), key=lambda kv: -kv[1])[:top]:
         print(f"  {name:34s} {us / 1e6:10.3f}s  x{count[name]:<8d} "
               f"avg {fmt_seconds(us / count[name] / 1e6)}")
+    print()
+    report_epoch_counts(events)
+
+
+def report_epoch_counts(events: list) -> None:
+    """The ``epoch.counts`` records (obs/names.py COUNT_ARGS), one line
+    an epoch: what the epoch did, which no log line says."""
+    records = sorted((ev["args"] for ev in events
+                      if ev.get("name") == "epoch.counts"),
+                     key=lambda a: a.get("epoch", 0))
+    if not records:
+        return
+
+    def pct(a, num, den):
+        return f"{100 * a[num] / a[den]:6.2f}" if a.get(den) else "     -"
+
+    print("== epoch.counts (what each training epoch did) ==")
+    print("  epoch  steps  /disp  examples  row%  chunk%   own%  "
+          "gather_MB  exchg_MB  compiles  compile_s      nnz_w  live_V")
+    for a in records:
+        per = (f"{a['steps'] / a['dispatches']:5.2f}" if a.get("dispatches")
+               else "    -")
+        print(f"  {a.get('epoch', 0):5d} {a.get('steps', 0):6d}  {per} "
+              f"{a.get('examples', 0):9d} {pct(a, 'rows', 'row_cap')} "
+              f"{pct(a, 'chunks', 'chunk_cap')} "
+              f"{pct(a, 'own_rows', 'own_cap')} "
+              f"{a.get('gather_bytes', 0) / 1e6:10.1f} "
+              f"{a.get('exchange_bytes', 0) / 1e6:9.1f} "
+              f"{a.get('compiles', 0):9d} {a.get('compile_s', 0.0):10.3f} "
+              f"{a.get('nnz_w', 0):10d} {a.get('live_V', 0):7d}")
     print()
 
 
