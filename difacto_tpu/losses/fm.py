@@ -146,6 +146,42 @@ def _take_lanes(vec: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     return out.reshape(-1)[:count].reshape(idx.shape)
 
 
+def packs_forward(dtype, k: int) -> bool:
+    """Whether the panel forward carries its ``[w | V]`` gather source of
+    ``k + 1`` lanes as two 16-bit halves of its bits (``_row_taker``):
+    float32 storage whose packed row of ``2(k + 1)`` halves still fits one
+    128-lane row. Such a row pads to 256 B where the float32 row pads to
+    512 B, and at the cells' row cap (294,912 rows) the v5e's compiler
+    keeps the packed source in fast memory (``S(1)``, 75.5 MB, as it
+    keeps bf16 ``[w | V64]``) and the float32 one in HBM (151 MB): a
+    row gather costs ~10 ns a row from HBM and 1.5-1.8 from fast memory
+    (measured on a v5e: V16's forward 27.96 -> 8.93 ms a step of 39 x
+    65,536 tokens). bfloat16 rows are 2 B a lane already; float32 at
+    ``k >= 64`` pads to the same 512 B either way."""
+    return k > 0 and jnp.dtype(dtype) == jnp.float32 and 2 * (k + 1) <= 128
+
+
+def _row_taker(wv: jnp.ndarray):
+    """``idx -> wv[idx]``, bit for bit (-0.0, NaN payloads, infinities
+    and subnormals included). Where ``packs_forward`` holds, the rows are
+    gathered from one ``uint16[U, 2n]`` array, the high halves of the
+    float32 bits in lanes ``0..n-1`` and the low halves in ``n..2n-1``,
+    and reassembled after the gather. Same-width bitcasts and shifts
+    only: a ``[U, n, 2]`` view would pad its minor 2 to 128 lanes."""
+    n = wv.shape[1]
+    if not packs_forward(wv.dtype, n - 1):
+        return lambda idx: wv[idx]
+    bits = jax.lax.bitcast_convert_type(wv, jnp.uint32)
+    src = jnp.concatenate([(bits >> 16).astype(jnp.uint16),
+                           (bits & 0xFFFF).astype(jnp.uint16)], axis=1)
+
+    def take(idx):
+        half = src[idx].astype(jnp.uint32)
+        return jax.lax.bitcast_convert_type(
+            (half[..., :n] << 16) | half[..., n:], jnp.float32)
+    return take
+
+
 def fm_predict_panel_xv(params: FMParams, pb
                         ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """Panel-layout forward (ops/batch.py PanelBatch): one [B]-row gather
@@ -169,14 +205,16 @@ def fm_predict_panel_xv(params: FMParams, pb
         return jnp.clip(jnp.sum(wc, axis=1), -PRED_CLAMP, PRED_CLAMP), None
     # the [U, 1+k] combined rows keep V's STORAGE dtype: with bf16 V_dtype
     # the per-token gather (the step's largest stream at big batches)
-    # moves half the bytes; accumulation is f32 below
+    # moves half the bytes; accumulation is f32 below. Narrow float32 rows
+    # are gathered as two 16-bit halves (packs_forward) and reassembled
     dt = params.V.dtype
     k = params.V.shape[1]
     B, F = pb.idx.shape
     Vm = params.V * _vmask(params).astype(dt)[:, None]
-    wv = jnp.concatenate([params.w.astype(dt)[:, None], Vm], axis=1)
+    take = _row_taker(
+        jnp.concatenate([params.w.astype(dt)[:, None], Vm], axis=1))
     if F > _COLLOOP_MAX_WIDTH:
-        tok = wv[pb.idx]                             # [B, F, 1+k]
+        tok = take(pb.idx)                           # [B, F, 1+k]
         wc, t = tok[:, :, 0].astype(jnp.float32), tok[:, :, 1:]
         if pb.vals is not None:
             wc = wc * pb.vals
@@ -191,7 +229,7 @@ def fm_predict_panel_xv(params: FMParams, pb
         XV = jnp.zeros((B, k), jnp.float32)
         XXVV = jnp.zeros((B, k), jnp.float32)
         for f in range(F):
-            tok = wv[idxT[f]]                        # [B, 1+k]
+            tok = take(idxT[f])                      # [B, 1+k]
             wc = tok[:, 0].astype(jnp.float32)
             t = tok[:, 1:]
             if pb.vals is not None:

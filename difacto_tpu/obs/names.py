@@ -93,6 +93,12 @@ COMPILES = "compiles_total"
 COMPILES_HELP = ("backend compiles by the jitted function's name (a load "
                  "from the persistent compile cache counts)")
 
+# 1 where the job's panel forward gathers its float32 [w | V] rows as two
+# 16-bit halves (losses/fm.packs_forward: the source then fits fast
+# memory), else 0; set once when the learner builds its step programs,
+# label ``job=train``. Trace/#metrics only
+STEP_FORWARD_PACKED = "step_forward_packed"
+
 # ---------------------------------------------------------- model gauges
 # set at every training epoch's end from the scalars the epoch line
 # prints (SGDLearner.run), label ``job=train``; they ride the epoch's
