@@ -788,17 +788,20 @@ class SGDLearner(Learner):
         _, train_step, eval_step = make_step_fns(
             fns, self.loss, train_auc=self.param.train_auc,
             state_shardings=state_shardings)
-        # the dtype V reaches the loss in (rows_to_params: 8-bit codes
-        # are dequantised to float32) decides the forward's gather source
-        from ..losses.fm import packs_forward
+        # the storage dtype decides the forward's gather source: 8-bit
+        # rows gather their codes (rows_to_params), float32 rows their
+        # bits as 16-bit halves where either fits one 128-lane row
+        from ..losses.fm import packs_codes, packs_forward
         from ..updaters.sgd_updater import quantized, v_dtype
         up = self.store.param
         self.obs.gauge(
             names.STEP_FORWARD_PACKED,
-            "1 where the train step's panel forward gathers its float32 "
-            "[w | V] rows as two 16-bit halves (losses/fm.packs_forward), "
-            "else 0").labels(job="train").set(float(packs_forward(
-                jnp.float32 if quantized(up) else v_dtype(up), up.V_dim)))
+            "1 where the train step's panel forward gathers a 16-bit "
+            "source: 8-bit rows' codes (losses/fm.packs_codes) or float32 "
+            "[w | V] rows as two halves (losses/fm.packs_forward), "
+            "else 0").labels(job="train").set(float(
+                packs_codes(up.V_dim) if quantized(up)
+                else packs_forward(v_dtype(up), up.V_dim)))
         # every step program routes through jaxtrace.jit — identical to
         # jax.jit unless DIFACTO_JAXTRACE=1, in which case per-site
         # compile counts feed the jitmap/gate (analysis/jaxflow.py)

@@ -22,11 +22,14 @@ path with V=None.
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ..ops import fused
 from ..ops.batch import DeviceBatch
 from ..ops.segment import spmm, spmm_t, spmv, spmv_t
 
@@ -47,12 +50,33 @@ _COLLOOP_MAX_WIDTH = 64
 # one-dimensional gathers)
 _LANE_SLAB = 65536
 
+# trips of the column loop of the 8-bit rows' forward, ceil(F /
+# _CODE_TRIPS) columns unrolled to a trip. At the 8-bit cell's shapes (39
+# columns) on a v5e: unrolled whole, forward 10.49 ms a step and a pair
+# program of 41.2 MB, which each run loads 2 s longer than the float32
+# source's 25.0 MB; three trips 10.29 ms and 23.6 MB; one column a trip
+# 26.46 ms (its sums are carried in a padded layout through HBM)
+_CODE_TRIPS = 3
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["src"], meta_fields=["kind"])
+@dataclasses.dataclass(frozen=True)
+class CodeRows:
+    """The panel forward's gather source of 8-bit rows (:func:`code_rows`):
+    ``src`` uint16[U, ceil(k/2) + 4], ``kind`` the codes' form ("int8"
+    or "fp8", ops/fused.quant_half)."""
+    src: jnp.ndarray
+    kind: str
+
 
 class FMParams(NamedTuple):
     """Gathered per-batch parameter rows."""
     w: jnp.ndarray                     # f32[U]
     V: Optional[jnp.ndarray] = None    # f32[U, k] or None (pure LR)
     v_mask: Optional[jnp.ndarray] = None  # f32[U]; None == all active
+    # 8-bit rows: the forward gathers these codes in place of [w | V]
+    codes: Optional[CodeRows] = None
 
 
 def _vmask(params: FMParams) -> jnp.ndarray:
@@ -182,6 +206,76 @@ def _row_taker(wv: jnp.ndarray):
     return take
 
 
+def packs_codes(k: int) -> bool:
+    """Whether the panel forward of 8-bit rows (slot_dtype int8 or fp8)
+    gathers their codes (:func:`code_rows`): ``k`` one-byte codes two to
+    a 16-bit lane and the four halves of ``w`` and the V scale fit one
+    128-lane row. At ``V_dim = 64`` that is ``u16[U, 36]``, 256 B a
+    padded row, the bytes of bf16 ``[w | V64]``, which the v5e's
+    compiler keeps in fast memory at the cells' row cap, where the
+    dequantised float32 ``[U, 65]`` source (512 B a padded row) sits in
+    HBM: 11.7 ns a gathered row against ~3.6 (PERF.md 5)."""
+    return k > 0 and (k + 1) // 2 + 4 <= 128
+
+
+def _halves(x: jnp.ndarray):
+    """float32[U] -> (high, low) 16-bit halves of its bits, u16[U, 1]."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)[:, None]
+    return (bits >> 16).astype(jnp.uint16), (bits & 0xFFFF).astype(jnp.uint16)
+
+
+def code_rows(codes: jnp.ndarray, w: jnp.ndarray, scale: jnp.ndarray,
+              v_mask: jnp.ndarray, kind: str) -> CodeRows:
+    """The forward's gather source of 8-bit rows: ``codes`` int8[U, k]
+    (the row's V codes as stored), ``w`` and the V ``scale`` float32[U],
+    ``v_mask`` 0/1 float32[U]. Lane j of the first ``m = ceil(k/2)``
+    holds code j in its low byte and code ``j + m`` in its high byte;
+    then the high halves of ``w`` and of ``scale * v_mask``, then their
+    low halves. The mask folds into the scale bit for bit: a scale is
+    positive, so ``(c * s) * 0`` and ``c * (s * 0)`` are the same signed
+    zero. Same-width bitcasts, shifts and 32-bit integers only: no minor
+    dimension of 2 to pad to 128 lanes, no 8-bit type to repack."""
+    k = codes.shape[1]
+    m = (k + 1) // 2
+    c = jnp.pad(codes.astype(jnp.int32) & 0xFF, ((0, 0), (0, 2 * m - k)))
+    w_hi, w_lo = _halves(w)
+    s_hi, s_lo = _halves(scale * v_mask)
+    return CodeRows(jnp.concatenate(
+        [(c[:, :m] | (c[:, m:] << 8)).astype(jnp.uint16),
+         w_hi, s_hi, w_lo, s_lo], axis=1), kind)
+
+
+def _code_taker(cr: CodeRows, k: int):
+    """``idx -> [w | V * v_mask][idx]`` from :func:`code_rows`' source,
+    bit for bit the dequantised float32 rows' gather: each gathered row's
+    codes are widened and scaled by ``ops/fused.dequant_half``, with the
+    mask riding in the scale. The unpacking is 32-bit arithmetic on whole
+    columns (fp8 codes alone pass through 8 bits, for their float8
+    bitcast): with 8-bit and two-lane values the v5e's compiler repacks
+    them in copies of their own, 0.6 MB more program a gathered column
+    and 3.5 s more set-up a run loading it from the compile cache."""
+    m = (k + 1) // 2
+
+    def take(idx):
+        x = cr.src[idx].reshape(-1, m + 4).astype(jnp.uint32)
+
+        def f32(hi, lo):
+            return jax.lax.bitcast_convert_type(
+                (x[:, hi] << 16) | x[:, lo], jnp.float32)
+
+        c = jnp.concatenate([x[:, :m] & 0xFF, x[:, :m] >> 8],
+                            axis=1)[:, :k].astype(jnp.int32)
+        if cr.kind == "int8":
+            codes = (c ^ 0x80) - 0x80                    # sign-extended
+        else:
+            codes = jax.lax.bitcast_convert_type(c.astype(jnp.uint8),
+                                                 jnp.int8)
+        V = fused.dequant_half(codes, f32(m + 1, m + 3), cr.kind)
+        tok = jnp.concatenate([f32(m, m + 2)[:, None], V], axis=1)
+        return tok.reshape(*idx.shape, k + 1)
+    return take
+
+
 def fm_predict_panel_xv(params: FMParams, pb
                         ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """Panel-layout forward (ops/batch.py PanelBatch): one [B]-row gather
@@ -206,13 +300,17 @@ def fm_predict_panel_xv(params: FMParams, pb
     # the [U, 1+k] combined rows keep V's STORAGE dtype: with bf16 V_dtype
     # the per-token gather (the step's largest stream at big batches)
     # moves half the bytes; accumulation is f32 below. Narrow float32 rows
-    # are gathered as two 16-bit halves (packs_forward) and reassembled
+    # are gathered as two 16-bit halves (packs_forward) and reassembled,
+    # 8-bit rows as their codes (code_rows) and dequantised after
     dt = params.V.dtype
     k = params.V.shape[1]
     B, F = pb.idx.shape
-    Vm = params.V * _vmask(params).astype(dt)[:, None]
-    take = _row_taker(
-        jnp.concatenate([params.w.astype(dt)[:, None], Vm], axis=1))
+    if params.codes is not None:
+        take = _code_taker(params.codes, k)
+    else:
+        Vm = params.V * _vmask(params).astype(dt)[:, None]
+        take = _row_taker(
+            jnp.concatenate([params.w.astype(dt)[:, None], Vm], axis=1))
     if F > _COLLOOP_MAX_WIDTH:
         tok = take(pb.idx)                           # [B, F, 1+k]
         wc, t = tok[:, :, 0].astype(jnp.float32), tok[:, :, 1:]
@@ -225,10 +323,9 @@ def fm_predict_panel_xv(params: FMParams, pb
         XXVV = jnp.sum(t * t, axis=1)
     else:
         idxT = pb.idx.T                              # [F, B]
-        pred = jnp.zeros((B,), jnp.float32)
-        XV = jnp.zeros((B, k), jnp.float32)
-        XXVV = jnp.zeros((B, k), jnp.float32)
-        for f in range(F):
+
+        def column(f, acc):
+            pred, XV, XXVV = acc
             tok = take(idxT[f])                      # [B, 1+k]
             wc = tok[:, 0].astype(jnp.float32)
             t = tok[:, 1:]
@@ -236,9 +333,22 @@ def fm_predict_panel_xv(params: FMParams, pb
                 wc = wc * pb.vals[:, f]
                 t = t * pb.vals[:, f, None].astype(dt)  # t = val * V
             t = t.astype(jnp.float32)
-            pred = pred + wc
-            XV = XV + t
-            XXVV = XXVV + t * t
+            return pred + wc, XV + t, XXVV + t * t
+
+        acc = (jnp.zeros((B,), jnp.float32), jnp.zeros((B, k), jnp.float32),
+               jnp.zeros((B, k), jnp.float32))
+        if params.codes is not None:
+            # the codes' unpacking is a dozen operations a column: F
+            # copies of it grow the step programs by megabytes, which
+            # every run loads from the compile cache before its window
+            # opens. A loop of _CODE_TRIPS trips keeps a third of them,
+            # the same sums in the same order
+            acc = jax.lax.fori_loop(0, F, column, acc,
+                                    unroll=-(-F // _CODE_TRIPS))
+        else:
+            for f in range(F):
+                acc = column(f, acc)
+        pred, XV, XXVV = acc
     pred = pred + 0.5 * jnp.sum(XV * XV - XXVV, axis=1)
     return jnp.clip(pred, -PRED_CLAMP, PRED_CLAMP), XV
 

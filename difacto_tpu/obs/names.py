@@ -46,7 +46,8 @@ LEGS = (UNPACK, GATHER, FORWARD, BACKWARD, UPDATE, SCATTER, EVALUATE)
 # ``jax.named_scope`` names nested INSIDE a leg, never a leg of their own:
 # the 8-bit rows' (slot_dtype int8/fp8) elementwise codes <-> float32
 # (ops/fused.dequant_half, quant_half). In the train step ``dequant``
-# sits in ``forward`` (rows_to_params) and ``update`` (row_epilogue),
+# sits in ``forward`` (rows_to_params, and after each token gather of
+# the codes, losses/fm._code_taker) and ``update`` (row_epilogue),
 # ``requant`` in ``update``; in ``evaluate`` and the table's init they
 # sit in that program. A leg reader takes the last LEG on an op_name
 # path, so every leg's time is what it was; ``perfbench/quant.py`` reads
@@ -106,10 +107,11 @@ COMPILES = "compiles_total"
 COMPILES_HELP = ("backend compiles by the jitted function's name (a load "
                  "from the persistent compile cache counts)")
 
-# 1 where the job's panel forward gathers its float32 [w | V] rows as two
-# 16-bit halves (losses/fm.packs_forward: the source then fits fast
-# memory), else 0; set once when the learner builds its step programs,
-# label ``job=train``. Trace/#metrics only
+# 1 where the job's panel forward gathers a 16-bit source that fits fast
+# memory: 8-bit rows' codes with w and the V scale (losses/fm.packs_codes)
+# or float32 [w | V] rows as two 16-bit halves (losses/fm.packs_forward),
+# else 0; set once when the learner builds its step programs, label
+# ``job=train``. Trace/#metrics only
 STEP_FORWARD_PACKED = "step_forward_packed"
 
 # ---------------------------------------------------------- model gauges

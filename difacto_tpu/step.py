@@ -38,7 +38,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .losses import FMParams, LossSpec
+from .losses import LossSpec
 from .losses.metrics import auc_times_n_binned_jnp, auc_times_n_jnp
 from .obs import names
 
@@ -60,6 +60,16 @@ def state_constrainer(state_shardings):
         return lambda state: state
     return lambda state: jax.lax.with_sharding_constraint(
         state, state_shardings)
+
+
+def pull(fns, state, slots, own_cap: Optional[int] = None):
+    """(params, rows-or-None) of the batch's unique ``slots``: a
+    fused-row table keeps the gathered rows so the train step can hand
+    them to the push (``own_cap``: see :func:`make_step_fns`)."""
+    if fns.fused:
+        rows = fns.pull_rows(state, slots, own_cap)
+        return fns.rows_to_params(state, rows), rows
+    return fns.get_rows(state, slots), None
 
 
 def make_step_fns(fns, loss: LossSpec, train_auc: str = "binned",
@@ -88,18 +98,8 @@ def make_step_fns(fns, loss: LossSpec, train_auc: str = "binned",
     constrain = state_constrainer(state_shardings)
     fused = fns.fused
 
-    def pull(state, batch, slots):
-        """(params, slot_vmask, rows-or-None): a fused-row table keeps
-        the gathered rows so train_step can hand them to the push."""
-        if fused:
-            rows = fns.pull_rows(state, slots, own_cap)
-            w, V, vmask = fns.rows_to_params(state, rows)
-            return FMParams(w=w, V=V, v_mask=vmask), vmask, rows
-        w, V, vmask = fns.get_rows(state, slots)
-        return FMParams(w=w, V=V, v_mask=vmask), vmask, None
-
     def forward(state, batch, slots):
-        params, _, _ = pull(state, batch, slots)
+        params, _ = pull(fns, state, slots, own_cap)
         with names.scope(names.FORWARD):
             pred = loss.predict(params, batch)
             objv = loss.evaluate(pred, batch)
@@ -107,7 +107,7 @@ def make_step_fns(fns, loss: LossSpec, train_auc: str = "binned",
         return params, pred, objv, auc
 
     def train_step(state, batch, slots):
-        params, slot_vmask, rows = pull(state, batch, slots)
+        params, rows = pull(fns, state, slots, own_cap)
         # the forward hands its X·V to the backward so the fused step
         # gathers the [U, 1+k] token rows exactly once (round-4 profile:
         # the duplicate gather was ~15% of the step)
@@ -125,9 +125,9 @@ def make_step_fns(fns, loss: LossSpec, train_auc: str = "binned",
             gw, gV = loss.calc_grad(params, batch, pred, xv)
         if fused:
             state = fns.apply_grad_rows(state, slots, rows, gw, gV,
-                                        slot_vmask, own_cap)
+                                        params.v_mask, own_cap)
         else:
-            state = fns.apply_grad(state, slots, gw, gV, slot_vmask)
+            state = fns.apply_grad(state, slots, gw, gV, params.v_mask)
         return constrain(state), objv, auc
 
     def eval_step(state, batch, slots):
@@ -161,8 +161,7 @@ def make_predict_fn(fns, loss: LossSpec):
     (tests/test_serve.py golden test)."""
 
     def predict_step(state, batch, slots):
-        w, V, vmask = fns.get_rows(state, slots)
-        params = FMParams(w=w, V=V, v_mask=vmask)
+        params, _ = pull(fns, state, slots)
         with names.scope(names.FORWARD):
             pred = loss.predict(params, batch)
             objv = loss.evaluate(pred, batch)

@@ -379,7 +379,8 @@ class SlotStore:
             # gather results come back in routed order, perm maps them
             # to the sorted-slot order the remap step expects
             slots_np, _, perm = self.tier.route(slots_np)
-        w, V, vmask = self.fns.get_rows(self.state, jnp.asarray(slots_np))
+        got = self.fns.get_rows(self.state, jnp.asarray(slots_np))
+        w, V, vmask = got.w, got.V, got.v_mask
         w = np.asarray(w)
         V = None if V is None else np.asarray(V)
         vmask = None if vmask is None else np.asarray(vmask)

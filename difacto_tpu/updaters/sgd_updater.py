@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import Param
+from ..losses.fm import FMParams, code_rows, packs_codes
 from ..obs import names
 
 TRASH_SLOT = 0  # row 0 absorbs padded scatters; never a real feature
@@ -665,10 +666,12 @@ def make_fns(param: SGDUpdaterParam, mesh=None):
         return _gather(state.VVg, slots, own_cap)
 
     @names.leg(names.FORWARD)
-    def rows_to_params(state: SGDState, rows: jnp.ndarray):
+    def rows_to_params(state: SGDState, rows: jnp.ndarray) -> FMParams:
         """(w, V, v_mask) views of gathered fused rows (Get,
         sgd_updater.cc:34-58): the embedding is served only when live
-        and not suppressed by ``l1_shrk`` (w == 0)."""
+        and not suppressed by ``l1_shrk`` (w == 0). 8-bit rows also
+        hand the forward their codes (losses/fm.code_rows) to gather in
+        place of the dequantised V."""
         _, _, _, off = _layout(state)
         f = scal_f32(rows[:, off:])
         w, live = f[:, 0], f[:, 4] > 0
@@ -682,15 +685,19 @@ def make_fns(param: SGDUpdaterParam, mesh=None):
                                    param.slot_dtype)
         else:
             V = rows[:, :param.V_dim]
-        return w, V, vmask.astype(jnp.float32)
+        vmask = vmask.astype(jnp.float32)
+        codes = None
+        if quantized(param) and packs_codes(param.V_dim):
+            codes = code_rows(rows[:, :param.V_dim], w, f[:, 5], vmask,
+                              param.slot_dtype)
+        return FMParams(w=w, V=V, v_mask=vmask, codes=codes)
 
     def get_rows(state: SGDState, slots: jnp.ndarray,
-                 own_cap: Optional[int] = None
-                 ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray],
-                            Optional[jnp.ndarray]]:
-        """Pull [w, V, v_mask] rows for the batch's unique slots (Get)."""
+                 own_cap: Optional[int] = None) -> FMParams:
+        """Pull the [w, V, v_mask] rows of the batch's unique slots
+        (Get)."""
         if not has_V:
-            return _gather(state.w, slots), None, None
+            return FMParams(w=_gather(state.w, slots))
         return rows_to_params(state, pull_rows(state, slots, own_cap))
 
     def apply_count(state: SGDState, slots: jnp.ndarray, counts: jnp.ndarray

@@ -313,7 +313,7 @@ def test_pull_mask_of_a_live_row_at_zero_w(shrk):
     state = state._replace(VVg=build_rows(
         param, 64, V, np.zeros_like(V), w, z, sg, cnt, live))
     slots = jnp.asarray(pad_slots_oob(np.array([5, 6, 7], np.int32), 8, 64))
-    _, _, vmask = make_fns(param).get_rows(state, slots)
+    vmask = make_fns(param).get_rows(state, slots).v_mask
     assert np.asarray(vmask)[:3].tolist() == [0.0 if shrk else 1.0, 1.0, 0.0]
 
 

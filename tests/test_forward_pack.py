@@ -1,5 +1,4 @@
-"""ISSUE 40: the float32 FM forward gathers its ``[w | V]`` rows as two
-16-bit halves.
+"""The FM forward's 16-bit gather sources.
 
 ``losses/fm.fm_predict_panel_xv`` gathers one combined ``[w | V]`` row a
 token. Stored as float32 at ``V_dim = 16`` that source is
@@ -7,17 +6,28 @@ token. Stored as float32 at ``V_dim = 16`` that source is
 leaves it in HBM (~10 ns a gathered row), where V64's bf16 source of the
 same padded bytes as V16's packed one sits in fast memory (``S(1)``).
 Where ``packs_forward`` holds the source is one ``uint16[U, 2(k+1)]``
-array, 256 B a padded row, and each gathered row is reassembled.
+array, 256 B a padded row, and each gathered row is reassembled. 8-bit
+rows (``slot_dtype`` int8 or fp8) dequantised to float32 made the same
+``f32[294912,65]`` source at ``V_dim = 64``; where ``packs_codes`` holds
+the forward gathers their codes, two to a 16-bit lane, with the halves of
+``w`` and of the masked V scale (``code_rows``: ``uint16[U, 36]``), and
+dequantises each gathered row.
 
 (a) on the CPU: ``pred`` and ``XV`` are the plain float32 gather's bit
     for bit, with -0.0, NaN payloads, infinities and subnormals in ``w``
     and ``V``, masked rows, binary and valued panels, the column loop and
-    the wide branch;
-(b) the rule engages by storage dtype and width alone, and the gauge
+    the wide branch; the code source's gathered rows are the dequantised
+    rows' bit for bit, and so are ``pred`` and ``XV`` where the CPU does
+    not contract a multiply and an add into one rounding;
+(b) the rules engage by storage dtype and width alone, and the gauge
     ``step_forward_packed{job=train}`` a learner sets says the same;
-(c) the V16 cell's pair program compiled for a described v5e at its real
-    shapes: every forward gather reads a source in fast memory and none
-    reads the float32 ``[294912,17]``; V64's forward holds no ``u16[``.
+(c) the V16 and 8-bit cells' pair programs compiled for a described v5e
+    at their real shapes: every forward gather reads a source in fast
+    memory and none reads a float32 ``[294912,17]`` or ``[294912,65]``;
+    V64's forward holds no ``u16[``;
+(d) what ``learner.init`` compiles for 8-bit rows, which no persistent
+    cache keeps (the table's seed is a constant of the program), is the
+    table's init alone, and its text is pinned.
 """
 
 import json
@@ -95,14 +105,116 @@ def test_forward_is_the_float32_gather_bit_for_bit(monkeypatch, F, valued,
     assert np.array_equal(_bits(XV), _bits(XV0))
 
 
+def _code_params(kind, U=4096, k=64, seed=43):
+    """``rows_to_params`` of 8-bit fused rows built as the store keeps
+    them: codes of both signs, all-zero rows (scale 1.0), rows whose
+    codes are all negative, rows that are not live and rows whose ``w``
+    is 0.0 or -0.0 (``l1_shrk`` masks their V)."""
+    import jax.numpy as jnp
+    from difacto_tpu.ops import fused
+    from difacto_tpu.updaters import sgd_updater as su
+    param = su.SGDUpdaterParam(V_dim=k, slot_dtype=kind, hash_capacity=U)
+    _, h, _, off = su.row_layout(param, U)
+    rng = np.random.default_rng(seed)
+    V = rng.standard_normal((U, k)).astype(np.float32)
+    V[:64] = 0.0
+    V[64:96] = -np.abs(V[64:96])
+    w = rng.standard_normal(U).astype(np.float32)
+    w[96:128] = 0.0
+    w[128:160] = -0.0
+    live = rng.random(U) < 0.8
+    Vc, sV = fused.quant_half(jnp.asarray(V), kind)
+    Vgc, sVg = fused.quant_half(jnp.asarray(np.abs(V)), kind)
+    zero = np.zeros(U, np.float32)
+    rows = jnp.concatenate(
+        [su.fuse_vvg(Vc, Vgc, h), jnp.zeros((U, off - 2 * h), jnp.int8),
+         su.pack_scal(w, zero, zero, zero, live, jnp.int8,
+                      scale_V=sV, scale_Vg=sVg)], axis=1)
+    empty = jnp.zeros((0,), jnp.float32)
+    state = su.SGDState(empty, empty, empty, empty, rows,
+                        jnp.zeros((0,), bool))
+    return su.make_fns(param).rows_to_params(state, rows)
+
+
+_CODES = """
+import json, sys
+sys.path.insert(0, %(tests)r)
+import jax
+import numpy as np
+import test_forward_pack as t
+from difacto_tpu.losses import fm
+got = {}
+for kind in ("int8", "fp8"):
+    p = t._code_params(kind)
+    fwd = jax.jit(fm.fm_predict_panel_xv)
+    for F in (39, 70):
+        for valued in (False, True):
+            pb = t._panel(np.random.default_rng(F), 4096, 256, F, valued)
+            a = fwd(p, pb)
+            b = fwd(p._replace(codes=None), pb)
+            got[f"{kind}-{F}-{valued}"] = [
+                bool(np.array_equal(t._bits(x), t._bits(y)))
+                for x, y in zip(a, b)]
+print("RESULT " + json.dumps(got))
+"""
+
+
+@pytest.fixture(scope="module")
+def code_forwards():
+    """``pred`` and ``XV`` from the code source against the dequantised
+    float32 source, in a process held to ``--xla_cpu_max_isa=SSE4_2``:
+    with FMA on, XLA's CPU codegen contracts the binary panel's
+    ``XV + c * s`` into one rounding where the float32 source adds a
+    gathered ``c * s`` (tests/test_owned_run.py)."""
+    import subprocess
+    import sys
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_cpu_max_isa=SSE4_2")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         _CODES % {"tests": os.path.join(ROOT, "tests")}],
+        env=env, cwd=ROOT, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    line = [x for x in out.stdout.splitlines() if x.startswith("RESULT ")]
+    return json.loads(line[-1][len("RESULT "):])
+
+
+@pytest.mark.parametrize("valued", [False, True])
+@pytest.mark.parametrize("F", [39, 70])
+@pytest.mark.parametrize("kind", ["int8", "fp8"])
+def test_code_source_is_the_dequantised_gather_bit_for_bit(
+        code_forwards, kind, F, valued):
+    import jax.numpy as jnp
+    from difacto_tpu.losses import fm
+    k = 64
+    p = _code_params(kind)
+    assert p.codes.kind == kind
+    assert p.codes.src.dtype == jnp.uint16
+    assert p.codes.src.shape == (4096, k // 2 + 4)
+    vm = np.asarray(p.v_mask)
+    assert 0 < vm.sum() < len(vm) and not vm[96:160].any()
+    # the gathered token rows: [w | V * v_mask], signed zeros included
+    idx = jnp.asarray(np.random.default_rng(F).integers(0, 4096, (256, F)),
+                      jnp.int32)
+    plain = jnp.concatenate([p.w[:, None], p.V * p.v_mask[:, None]], axis=1)
+    tok = fm._code_taker(p.codes, k)(idx)
+    a = np.asarray(plain)
+    assert ((a == 0) & np.signbit(a)).any()         # -0.0 where masked
+    assert np.array_equal(_bits(tok), _bits(plain[idx]))
+    assert code_forwards[f"{kind}-{F}-{valued}"] == [True, True]
+
+
 # (b) ------------------------------------- the rule, and the gauge's say
 @pytest.mark.parametrize("model,packs", [
     (dict(V_dim=16, V_dtype="float32"), True),      # fm_v16_kaggle
     (dict(V_dim=63, V_dtype="float32"), True),      # 128 halves: one row
     (dict(V_dim=64, V_dtype="float32"), False),     # 130 pad to 256
     (dict(V_dim=16, V_dtype="bfloat16"), False),    # 2 B a lane already
-    (dict(V_dim=16, slot_dtype="int8"), True),      # dequantised to f32
+    (dict(V_dim=16, slot_dtype="int8"), True),      # its codes, u16[U, 12]
     (dict(V_dim=0), False),                         # no V: flat table
+    (dict(V_dim=64, slot_dtype="int8"), True),      # fm_v64_criteo_int8
+    (dict(V_dim=64, slot_dtype="fp8"), True),       # the cell's control
+    (dict(V_dim=64, slot_dtype="bf16"), False),     # bf16 rows: no codes
 ])
 def test_packed_source_engages_by_dtype_and_width(tmp_path, model, packs):
     import jax
@@ -110,21 +222,39 @@ def test_packed_source_engages_by_dtype_and_width(tmp_path, model, packs):
     from difacto_tpu.learners import Learner
     from difacto_tpu.losses import fm
     from difacto_tpu.obs import names
+    from difacto_tpu.step import make_step_fns
     data = write_uniform_libsvm(str(tmp_path / "u.libsvm"), rows=64)
     args = dict(data_in=data, batch_size=32, hash_capacity=4096, **model)
     ln = Learner.create("sgd")
     assert ln.init([(k, str(v)) for k, v in args.items()]) == []
     assert ln.obs.value(names.STEP_FORWARD_PACKED, job="train") == packs
 
+    # the learner's own train step takes a 16-bit source where the rule
+    # of its storage dtype holds, and nowhere else
     k = model["V_dim"]
+    rng = np.random.default_rng(k)
+    _, train_step, _ = make_step_fns(ln.store.fns, ln.loss)
+    step = str(jax.make_jaxpr(train_step)(
+        ln.store.state, _panel(rng, 64, 8, 39, False),
+        jnp.arange(64, dtype=jnp.int32)))
+    assert ("uint16" in step) == packs
+    if model.get("slot_dtype") in ("int8", "fp8"):
+        assert fm.packs_codes(k) == packs
+        return
     dt = jnp.bfloat16 if model.get("V_dtype") == "bfloat16" else jnp.float32
     assert fm.packs_forward(dt, k) == packs
-    rng = np.random.default_rng(k)
     params = fm.FMParams(jnp.zeros((64,), jnp.float32),
                          jnp.zeros((64, k), dt))
     jaxpr = str(jax.make_jaxpr(fm.fm_predict_panel_xv)(
         params, _panel(rng, 64, 8, 39, False)))
     assert ("uint16" in jaxpr) == packs
+
+
+def test_code_source_width_rule():
+    from difacto_tpu.losses import fm
+    # k codes two to a lane and four halves in 128 lanes
+    assert [fm.packs_codes(k) for k in (0, 1, 64, 247, 248, 249, 256)] == [
+        False, True, True, True, True, False, False]
 
 
 # (c) ------------------------- the real shapes, compiled for a described v5e
@@ -145,10 +275,11 @@ def topo():
 B, F, U, C = 65536, 39, 294_912, 212_992
 
 
-def _pair_text(name, device, data):
+def _pair_text(name, device, data, form="line"):
     """The learner's own pair program (``_packed_panel_train_chunked2``,
-    what a replay window times) of configuration ``name``, lowered at the
-    cells' shapes for ``device`` -> its compiled text. Shapes only."""
+    what a replay window times; ``form`` "loop" its two-trip loop) of
+    configuration ``name``, lowered at the cells' shapes for ``device``
+    -> its compiled text. Shapes only."""
     import jax
     import jax.numpy as jnp
     from difacto_tpu.learners import Learner
@@ -158,7 +289,8 @@ def _pair_text(name, device, data):
         cfg = json.load(f)
     args = dict(data_in=data, batch_size=32, hash_capacity=4096,
                 **{k: cfg[k] for k in ("loss", "V_dim", "V_dtype", "lr",
-                                       "l1", "V_threshold")})
+                                       "l1", "V_threshold", "slot_dtype")
+                   if k in cfg})
     ln = Learner.create("sgd")
     assert ln.init([(k, str(v)) for k, v in args.items()]) == []
 
@@ -173,8 +305,10 @@ def _pair_text(name, device, data):
         lambda a, b: ln._panel_chunk_packed(a, b, B, F, U, True, C),
         i32, f32))
     pa = (i32, f32, chunks)
-    return ln._packed_panel_train_chunked2.lower(
-        state, pa, pa, B, F, U, False, True).compile().as_text()
+    program = (ln._packed_panel_train_chunked2_loop if form == "loop"
+               else ln._packed_panel_train_chunked2)
+    return program.lower(state, pa, pa, B, F, U, False,
+                         True).compile().as_text()
 
 
 def _forward_gather_sources(text):
@@ -207,3 +341,66 @@ def test_v16_forward_gathers_from_fast_memory(topo, tmp_path):
                 if 'leg="forward"' in line and "u16[" in line]
     assert all(s.startswith(f"bf16[{U},65]")
                for s in _forward_gather_sources(v64))
+
+
+@pytest.mark.parametrize("form", ["line", "loop"])
+def test_int8_forward_gathers_codes_from_fast_memory(topo, tmp_path, form):
+    """8-bit rows at V_dim = 64: the dequantised float32 source was
+    ``f32[294912,65]``, built in fast memory and copied to HBM (151 MB
+    padded) for the 39 gathers; the codes' source is ``u16[294912,36]``
+    and stays in ``S(1)``. Its columns run as a loop of a few trips, a
+    trip's gathers in the text once. The cell runs the pair's loop (its
+    straight line is rematerialised, learners/sgd._rematerialised); both
+    forms gather so."""
+    from jax.sharding import SingleDeviceSharding
+    from difacto_tpu.losses import fm
+    data = write_uniform_libsvm(str(tmp_path / "u.libsvm"), rows=64)
+    src = _forward_gather_sources(_pair_text(
+        "fm_v64_criteo_int8", SingleDeviceSharding(topo.devices[0]), data,
+        form))
+    steps = 1 if form == "loop" else 2
+    assert len(src) == steps * -(-F // fm._CODE_TRIPS)    # a trip's columns
+    assert all("S(1)" in s for s in src), sorted(set(src))
+    assert not any(s.startswith(f"f32[{U},65]") for s in src)
+    assert all(s.startswith(f"u16[{U},36]") for s in src)
+
+
+# (d) --------------------------- what set-up compiles in every run, CPU
+# sha256 of ``_jitted_init``'s lowered text for the configuration below
+# (seed 0, 4,096 rows of 8-bit codes): the program every run of an 8-bit
+# cell compiles, since its seed is a constant of it (perfbench/sut.drive
+# keeps it out of the persistent cache). A change here costs compile time
+# in every run's set-up; a JAX upgrade that alters the text re-pins it
+INIT_INT8_SHA256 = (
+    "dc4cd957b4136b5a7c19eb695ee1b855a90a5f43e0f722818913a00498732713")
+
+
+def test_int8_init_compiles_the_table_init_alone(tmp_path):
+    import dataclasses
+    import hashlib
+    import jax
+    import jax.monitoring as mon
+    from difacto_tpu.learners import Learner
+    from difacto_tpu.store import local
+    data = write_uniform_libsvm(str(tmp_path / "u.libsvm"), rows=64)
+    args = dict(data_in=data, batch_size=32, hash_capacity=4096, V_dim=64,
+                slot_dtype="int8")
+    seen = []
+
+    def on_compile(event, secs, fun_name="", **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen.append(fun_name)
+
+    # nothing this process compiled before may stand in for init's compile
+    local._jitted_init.cache_clear()
+    jax.clear_caches()
+    mon.register_event_duration_secs_listener(on_compile)
+    try:
+        ln = Learner.create("sgd")
+        assert ln.init([(k, str(v)) for k, v in args.items()]) == []
+    finally:
+        mon.unregister_event_duration_listener(on_compile)
+    assert seen == ["jit(build)"]
+    text = local._jitted_init(dataclasses.astuple(ln.store.param),
+                              ln.store.state.capacity, None).lower().as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == INIT_INT8_SHA256

@@ -106,12 +106,11 @@ def make_batches(n, B, nnz_per_row, uniq_space, capacity, seed=0):
 def _composed_step(fns, loss, constrain):
     """The reference: the same step built from the store's eager pull
     and push, which gather the rows once each, jitted whole."""
-    from difacto_tpu.losses import FMParams
     from difacto_tpu.losses.metrics import auc_times_n_binned_jnp
 
     def step(state, batch, slots):
-        w, V, vmask = fns.get_rows(state, slots)
-        params = FMParams(w=w, V=V, v_mask=vmask)
+        params = fns.get_rows(state, slots)
+        vmask = params.v_mask
         pred, xv = loss.predict_xv(params, batch)
         objv = loss.evaluate(pred, batch)
         auc = auc_times_n_binned_jnp(batch.labels, pred, batch.row_mask)

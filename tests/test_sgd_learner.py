@@ -190,8 +190,8 @@ def test_default_reporting_matches_silent_path(rcv1_path, capsys,
         for _ in range(3):
             st = fns.apply_grad(st, jnp.asarray(slots), jnp.asarray(gw),
                                 jnp.asarray(gV), jnp.ones(U))
-        w, V, vm = fns.get_rows(st, jnp.asarray(slots))
-        return np.asarray(w), np.asarray(V), np.asarray(fns.evaluate(st))
+        got = fns.get_rows(st, jnp.asarray(slots))
+        return np.asarray(got.w), np.asarray(got.V), np.asarray(fns.evaluate(st))
 
     wp, Vp, ep = run(True)
     wc, Vc, ec = run(False)
@@ -207,7 +207,7 @@ def test_default_reporting_matches_silent_path(rcv1_path, capsys,
     assert st.VVg.shape[1] == 128  # scal lanes ride the existing pad
     st = fns.apply_grad(st, jnp.asarray(slots), jnp.asarray(gw),
                         jnp.asarray(gV), jnp.ones(U))
-    _, V_before, _ = fns.get_rows(st, jnp.asarray(slots))
+    V_before = fns.get_rows(st, jnp.asarray(slots)).V
     from difacto_tpu.updaters.sgd_updater import col_Vg, scal_cols
     Vg_before = np.asarray(col_Vg(par, st))[:1024]
     scal_before = [np.asarray(c)[:1024] for c in scal_cols(par, st)]
@@ -223,6 +223,6 @@ def test_default_reporting_matches_silent_path(rcv1_path, capsys,
                                   Vg_before)
     for got, want in zip(scal_cols(par, grown), scal_before):
         np.testing.assert_array_equal(np.asarray(got)[:1024], want)
-    _, V_after, _ = fns.get_rows(grown, jnp.asarray(slots))
+    V_after = fns.get_rows(grown, jnp.asarray(slots)).V
     np.testing.assert_array_equal(np.asarray(V_before),
                                   np.asarray(V_after))
