@@ -64,6 +64,7 @@ def reading(bench: dict, workload: str, seed: int, override, part: str,
     config, traffic = loaded["config"], dict(loaded["traffic"])
     if require_tpu:
         sut.bind(int(loaded["cell"]["chips"]))
+    drv = R.load_driver(ROOT, config)
     if part == "first":
         traffic["rows_per_epoch"] = 8 * int(config["batch_size"])
     ref_mod = R.load_reference(ROOT, config)
@@ -73,13 +74,14 @@ def reading(bench: dict, workload: str, seed: int, override, part: str,
     os.makedirs(run_root, exist_ok=True)
     work_dir = tempfile.mkdtemp(prefix="cal_", dir=run_root)
     try:
-        data = R.make_data(seed, config, traffic, work_dir, sut.N_STEPS)
-        probe_rows, batches = R.first_steps(data, config, ref_mod)
-        kwargs = sut.learner_kwargs(cfg_kw, traffic, work_dir, seed,
+        data = R.make_data(seed, config, traffic, work_dir, drv.N_STEPS)
+        plan = drv.probe_plan(data, cfg_kw, ref_mod)
+        kwargs = drv.learner_kwargs(cfg_kw, traffic, work_dir, seed,
                                     override)
-        prog = sut.drive(kwargs, probe_rows, 0.0, stop_after=part)["probe"]
+        prog = drv.drive(kwargs, plan, 0.0, stop_after=part)["probe"]
     finally:
         shutil.rmtree(work_dir, ignore_errors=True)
+    probe_rows, batches = plan
     V0 = ref_mod.initial_V(kwargs["seed"], int(config["hash_capacity"]),
                            probe_rows, hyper)
     ref = ref_mod.follow(hyper, V0, batches)
@@ -99,7 +101,7 @@ def reading(bench: dict, workload: str, seed: int, override, part: str,
             planted.setdefault(f, {}).update(ref_mod.pair_numbers(
                 dict(bad, before=pair["before"]), pref, check.gap))
     ok, _ = check.judge(dict(nums, epoch_rows=0.0), loaded["limits"],
-                        hyper.V_dim)
+                        check.names(hyper.V_dim))
     for side in (prog, ref):
         side.pop("rows")
     return {"seed": seed, "override": override, "part": part,

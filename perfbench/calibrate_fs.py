@@ -48,6 +48,7 @@ def reading(bench: dict, workload: str, seed: int, override,
     config, traffic = loaded["config"], dict(loaded["traffic"])
     if require_tpu:
         sut.bind(int(loaded["cell"]["chips"]))
+    drv = R.load_driver(ROOT, config)
     traffic["rows_per_epoch"] = 8 * int(config["batch_size"])
     ref_mod = R.load_reference(ROOT, config)
     cfg_kw = {k: v for k, v in config.items() if k not in R.META}
@@ -57,14 +58,14 @@ def reading(bench: dict, workload: str, seed: int, override,
     os.makedirs(run_root, exist_ok=True)
     work_dir = tempfile.mkdtemp(prefix="cal_", dir=run_root)
     try:
-        data = R.make_data(seed, config, traffic, work_dir, sut.N_STEPS)
-        probe_rows, batches = R.first_steps(data, config, ref_mod)
-        kwargs = sut.learner_kwargs(cfg_kw, traffic, work_dir, seed,
+        data = R.make_data(seed, config, traffic, work_dir, drv.N_STEPS)
+        plan = drv.probe_plan(data, cfg_kw, ref_mod)
+        kwargs = drv.learner_kwargs(cfg_kw, traffic, work_dir, seed,
                                     override)
-        prog = sut.drive(kwargs, probe_rows, 0.0,
-                         stop_after="first")["probe"]
+        prog = drv.drive(kwargs, plan, 0.0, stop_after="first")["probe"]
     finally:
         shutil.rmtree(work_dir, ignore_errors=True)
+    probe_rows, batches = plan
     V0 = ref_mod.initial_V(kwargs["seed"], capacity, probe_rows, hyper)
     ref = ref_mod.follow(hyper, V0, batches)
     nums = check.numbers(prog, ref, ref_mod.rel_diff)
@@ -78,7 +79,7 @@ def reading(bench: dict, workload: str, seed: int, override,
                              int(config.get("mesh_fs", 1))), batches)
         planted["shard_out"] = check.numbers(bad, ref, ref_mod.rel_diff)
     ok, _ = check.judge(dict(nums, epoch_rows=0.0), loaded["limits"],
-                        hyper.V_dim)
+                        check.names(hyper.V_dim))
     return {"seed": seed, "override": override, "correct": ok,
             "numbers": nums, "faults": planted}
 

@@ -90,13 +90,13 @@ def epoch_rows(rows_by_epoch, rows_per_epoch: int) -> float:
         / rows_per_epoch
 
 
-def judge(nums: dict, limits: dict, V_dim: int) -> tuple:
-    """(correct, {name: {"value", "limit"}}): every number of the
-    layout's ``names``, every other number the run produced and every
-    number the limits file names, each held to its limit; one without a
-    limit, one that the run did not produce and one that is not finite
-    fail."""
-    order = list(names(V_dim))
+def judge(nums: dict, limits: dict, required) -> tuple:
+    """(correct, {name: {"value", "limit"}}): every number of
+    ``required`` (a layout's ``names``, or what the cell's driver
+    compares), every other number the run produced and every number the
+    limits file names, each held to its limit; one without a limit, one
+    that the run did not produce and one that is not finite fail."""
+    order = list(required)
     for n in list(nums) + [k for k in limits if not k.startswith("_")]:
         if n not in order:
             order.append(n)

@@ -4,17 +4,41 @@
                              --trace <0|1>
 
 One cell of ``BENCHMARK.json`` a run: makes the rows and the table from
-the seed, drives the program's training entry in this process, measures
-from the first steady epoch mark for ``--seconds``, compares the
-program's first steps and one call of the program that the window times
-with the plain reference, and prints one JSON
-object as the last line of standard output. Without a TPU, or with fewer
+the seed, drives the program's training entry in this process through
+the configuration's driver, measures from the first steady epoch mark
+for ``--seconds``, compares what the driver's probe read (for the SGD
+learner: its first steps and one call of the program that the window
+times) with the plain reference, and prints one JSON object as the last
+line of standard output. Without a TPU, or with fewer
 chips than the cell asks for, it exits non-zero and prints no result.
 
 Driven by data: a cell names a configuration (``BENCHMARK.json`` gives
 its file) and a traffic mix (``traffic/<name>.json``); its limits are in
 ``limits/<cell>.json``; each per-layer metric is read by
-``metrics/<name>.py``. Nothing here names a cell.
+``metrics/<name>.py``. Nothing here names a cell or a learner.
+
+A configuration's ``"driver"`` names, by path from the root, the module
+that trains its learner; without the key it is ``perfbench/sut.py``, the
+SGD learner. A driver owns what is particular to its learner (``config``
+below is the configuration's keys, those of ``META`` left out):
+
+- ``LEARNER``: the name the program registers the learner under;
+- ``N_STEPS``: the members of the traffic whose rows ``make_data`` keeps
+  for the comparison;
+- ``learner_kwargs(config, traffic, data_dir, seed, override)``: the keys
+  the learner is built with;
+- ``probe_plan(data, config, ref_mod)``: what the run watches and the
+  reference follows;
+- ``drive(kwargs, plan, seconds, trace_dir)``: builds the learner, runs it,
+  keeps the window between two epoch-end marks and returns its marks,
+  the run's summary, the probe's readings and ``memory_peak_bytes``
+  (``sut.drive`` lists the keys; a key the learner has no value for is
+  ``None``);
+- ``step_work(data, config)``: the necessary bytes and flops of one step
+  of ``batch_size`` rows (``work.py``);
+- ``compare(ref_mod, config, kwargs, plan, res, data)``: the numbers
+  compared, the names that must be among them, and what the
+  ``reference`` line prints.
 """
 
 from __future__ import annotations
@@ -37,7 +61,7 @@ ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-META = ("precision", "reference", "control", "about")
+META = ("precision", "reference", "control", "about", "driver")
 
 
 def say(key: str, value) -> None:
@@ -82,6 +106,32 @@ def load_reference(root: str, config: dict):
     path = os.path.join(root, config["reference"])
     return _load_module(
         path, "perfbench_reference_" + os.path.basename(path)[:-3])
+
+
+def load_driver(root: str, config: dict):
+    """The module that trains the configuration's learner: the file its
+    ``"driver"`` names, or ``perfbench.sut`` where it names none. A path
+    that is no file under the root, or a learner the program does not
+    register, ends the run here, before any data is made."""
+    from perfbench import sut
+    path = config.get("driver")
+    if path is None:
+        drv = sut
+    else:
+        full = os.path.normpath(os.path.join(root, path))
+        if os.path.isabs(path) or not full.startswith(
+                os.path.join(os.path.normpath(root), "")) \
+                or not full.endswith(".py") or not os.path.isfile(full):
+            raise SystemExit(f"perfbench: the configuration's \"driver\" "
+                             f"{path!r} is no .py file under {root}")
+        drv = _load_module(full, "perfbench_driver_"
+                           + os.path.basename(full)[:-3])
+    name = getattr(drv, "LEARNER", None)
+    if not isinstance(name, str) or not sut.learner_known(name):
+        raise SystemExit(f"perfbench: the configuration's \"driver\" "
+                         f"{path or 'perfbench/sut.py'} trains the learner "
+                         f"{name!r}, which the program does not register")
+    return drv
 
 
 def load_reader(bdir: str, metric: str):
@@ -135,17 +185,6 @@ def make_data(seed: int, config: dict, traffic: dict, data_dir: str,
             "bytes_written": sum(nbytes)}
 
 
-def first_steps(data: dict, config: dict, ref_mod) -> tuple:
-    """(the table rows that the compared steps touch, those steps' batches
-    as the reference takes them: [(idx into the rows, labels)])."""
-    from perfbench import gen, sut
-    cap = int(config["hash_capacity"])
-    kept = [data["kept"][m] for m in range(sut.N_STEPS)]
-    probe_rows, idx = ref_mod.touched(
-        [gen.slots_of(data["tables"].rev_of(g), cap) for _, g in kept])
-    return probe_rows, [(i, y) for i, (y, _) in zip(idx, kept)]
-
-
 def run_cell(bench: dict, root: str, workload: str, seed: int,
              seconds: float, trace: bool, require_tpu: bool = True,
              override: dict = None, out=say) -> dict:
@@ -162,6 +201,7 @@ def run_cell(bench: dict, root: str, workload: str, seed: int,
         dev = sut.describe()
     bound_s = time.perf_counter() - T_START
     compiles = sut.Compiles()
+    drv = load_driver(root, config)
     import jax
     import jaxlib
     out("workload", workload)
@@ -177,7 +217,7 @@ def run_cell(bench: dict, root: str, workload: str, seed: int,
         data_dir = os.path.join(work_dir, "data.rec")
         os.makedirs(data_dir)
         t0 = time.perf_counter()
-        data = make_data(seed, config, traffic, data_dir, sut.N_STEPS)
+        data = make_data(seed, config, traffic, data_dir, drv.N_STEPS)
         data_s = round(time.perf_counter() - t0, 3)
         out("data", json.dumps({
             "rows_per_epoch": data["rows"],
@@ -187,15 +227,15 @@ def run_cell(bench: dict, root: str, workload: str, seed: int,
             "seconds": data_s}))
 
         ref_mod = load_reference(root, config)
-        probe_rows, batches = first_steps(data, config, ref_mod)
         cfg_kw = {k: v for k, v in config.items() if k not in META}
-        kwargs = sut.learner_kwargs(cfg_kw, traffic, data_dir, seed,
+        plan = drv.probe_plan(data, cfg_kw, ref_mod)
+        kwargs = drv.learner_kwargs(cfg_kw, traffic, data_dir, seed,
                                     override)
         window = (min(seconds, float(traffic.get("trace_seconds",
                                                  seconds)))
                   if trace else seconds)
         trace_dir = os.path.join(work_dir, "trace") if trace else None
-        res = sut.drive(kwargs, probe_rows, window, trace_dir)
+        res = drv.drive(kwargs, plan, window, trace_dir)
         setup_s = res["t_open"] - T_START
         compile_s = compiles.seconds
     finally:
@@ -203,7 +243,6 @@ def run_cell(bench: dict, root: str, workload: str, seed: int,
                       ignore_errors=True)
 
     try:
-        hyper = ref_mod.Hyper.of(cfg_kw)
         steps = res["window_rows"] / data["batch"]
         out("window", json.dumps({
             "seconds": res["window_s"], "rows": res["window_rows"],
@@ -245,9 +284,7 @@ def run_cell(bench: dict, root: str, workload: str, seed: int,
         if trace:
             rows = tracered.load_events(tracered.find_xplane(trace_dir))
             red = tracered.reduce(rows, res["window_s"])
-            w = work.step_work(data["uniq_per_step"], data["batch"],
-                               data["batch"] * data["width"],
-                               hyper.V_dim, work.item_size(cfg_kw))
+            w = drv.step_work(data, cfg_kw)
             peaks = (work.load_peaks(dev["kind"]) if require_tpu
                      else None)
             least = (work.least_seconds(w, peaks, chips) if peaks
@@ -260,7 +297,10 @@ def run_cell(bench: dict, root: str, workload: str, seed: int,
                                         "modules")}))
             ctx = {"res": res, "trace": red, "least": least,
                    "steps": steps, "compile_s": compile_s,
-                   "chips": chips}
+                   "chips": chips, "cell": workload, "config": cfg_kw,
+                   "data": {k: data[k] for k in (
+                       "rows", "batch", "width", "n_members",
+                       "uniq_per_step")}}
             for m in metrics_of(bench, "per_layer", workload):
                 read = load_reader(loaded["bdir"], m["name"])
                 v = read(ctx) if read is not None else None
@@ -279,32 +319,12 @@ def run_cell(bench: dict, root: str, workload: str, seed: int,
         # the comparison, once the window has closed and the program's
         # state is freed; not counted in setup_s
         t0 = time.perf_counter()
-        V0 = ref_mod.initial_V(kwargs["seed"], int(config["hash_capacity"]),
-                               probe_rows, hyper)
-        V0.block_until_ready()
-        t_init = time.perf_counter() - t0
-        ref = ref_mod.follow(hyper, V0, batches)
-        nums = check.numbers(res["probe"], ref, ref_mod.rel_diff)
-        pair = res["probe"].pop("pair")
-        pair_said = None
-        if pair is not None:
-            # the window's own executable: its first call of the warm
-            # epoch took the epoch's first two batches
-            pref = ref_mod.follow_pair(hyper, pair["before"], batches[:2])
-            nums.update(ref_mod.pair_numbers(pair, pref, check.gap))
-            pair_said = {"program": pair["loss"],
-                         "reference": pref["loss"]}
-        nums["epoch_rows"] = check.epoch_rows(
-            res["window_rows_by_epoch"], data["rows"])
-        ok, checked = check.judge(nums, loaded["limits"], hyper.V_dim)
-        for side in (res["probe"], ref):
-            side.pop("rows")
-        out("reference", json.dumps({
-            "seconds": round(time.perf_counter() - t0, 3),
-            "initial_table_s": round(t_init, 3),
-            "touched_rows": int(len(probe_rows)),
-            "program": res["probe"], "reference": ref,
-            "pair_loss": pair_said}))
+        compared = drv.compare(ref_mod, cfg_kw, kwargs, plan, res, data)
+        ok, checked = check.judge(compared["numbers"], loaded["limits"],
+                                  compared["names"])
+        out("reference", json.dumps(dict(
+            seconds=round(time.perf_counter() - t0, 3),
+            **compared["said"])))
         result["correct"] = bool(ok)
         result["checked"] = checked
         return result

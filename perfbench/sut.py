@@ -1,11 +1,15 @@
-"""The system under test, as the benchmark drives it.
+"""The system under test, as the benchmark drives it: the SGD learner's
+driver, and what every driver shares.
 
-The only module of the benchmark that touches ``difacto_tpu``. It takes
-from the program the entry that ``python -m difacto_tpu task=train`` runs
-(``place_compile_cache``, ``Learner.create("sgd")``, ``init``, ``run``), the
-epoch-end callback, the obs registry's ``stage_seconds_total``, the rec
-member writer, and the accessors of the table's row layout. It plants
-nothing in the program.
+The driver of every configuration that names none (``run.load_driver``;
+``run.py`` lists what a driver owns), and the parts any driver uses: the
+device binding, the compile listener, the rec member writer and the
+window between two epoch-end marks. It takes from the program the entry
+that ``python -m difacto_tpu task=train`` runs (``place_compile_cache``,
+``Learner.create(LEARNER)``, ``init``, ``run``), the epoch-end callback,
+the obs registry's ``stage_seconds_total``, the rec member writer, and
+the accessors of the table's row layout. It plants nothing in the
+program.
 
 Two things the program offers no public way to do, and how each is done
 here (listed in PERF.md under Open questions for a program PR to replace
@@ -39,6 +43,7 @@ import time
 
 import numpy as np
 
+LEARNER = "sgd"
 N_STEPS = 3          # steps of epoch 0 that the reference follows
 HUGE_EPOCHS = 1_000_000
 
@@ -74,6 +79,33 @@ def describe(cache_dir=None) -> dict:
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
             "count": len(devs), "host_cores": os.cpu_count(),
             "compile_cache_dir": cache_dir}
+
+
+def learner_known(name: str) -> bool:
+    """Whether the program registers a learner of that name, as its
+    source says: a class under ``@register("<name>")`` in
+    ``difacto_tpu/learners/`` (``Learner.create`` looks the name up in
+    lower case). Read, not imported: the run imports the program's
+    learners after the seed's rows are made, and importing them before
+    made epoch 0 of the four-chip cell 2.9 s longer (PERF.md)."""
+    import ast
+    import importlib.util
+    spec = importlib.util.find_spec("difacto_tpu.learners")
+    names = set()
+    for path in spec.submodule_search_locations:
+        for fname in sorted(os.listdir(path)):
+            if not fname.endswith(".py"):
+                continue
+            with open(os.path.join(path, fname)) as f:
+                tree = ast.parse(f.read())
+            for node in ast.walk(tree):
+                for deco in getattr(node, "decorator_list", ()):
+                    if isinstance(deco, ast.Call) and getattr(
+                            deco.func, "id", "") == "register" \
+                            and deco.args and isinstance(
+                                deco.args[0], ast.Constant):
+                        names.add(str(deco.args[0].value).lower())
+    return name.lower() in names
 
 
 def memory_peak_bytes() -> int:
@@ -322,6 +354,26 @@ def learner_kwargs(config: dict, traffic: dict, data_dir: str, seed: int,
     return kw
 
 
+def probe_plan(data: dict, config: dict, ref_mod) -> tuple:
+    """(the table rows that the compared steps touch, those steps' batches
+    as the reference takes them: [(idx into the rows, labels)])."""
+    from perfbench import gen
+    cap = int(config["hash_capacity"])
+    kept = [data["kept"][m] for m in range(N_STEPS)]
+    probe_rows, idx = ref_mod.touched(
+        [gen.slots_of(data["tables"].rev_of(g), cap) for _, g in kept])
+    return probe_rows, [(i, y) for i, (y, _) in zip(idx, kept)]
+
+
+def step_work(data: dict, config: dict) -> dict:
+    """The least work of one step: ``work.step_work`` of the step's
+    distinct rows, rows and non-zeros."""
+    from perfbench import work
+    return work.step_work(data["uniq_per_step"], data["batch"],
+                          data["batch"] * data["width"],
+                          int(config["V_dim"]), work.item_size(config))
+
+
 def _pair_compile_pending(learner) -> bool:
     if any(t.name == "pair-exec-compile" and t.is_alive()
            for t in threading.enumerate()):
@@ -347,21 +399,100 @@ def _stage_seconds(learner) -> dict:
     return {dict(k).get("stage", ""): float(v) for k, v in series.items()}
 
 
-def drive(kwargs: dict, probe_rows, seconds: float, trace_dir: str = None,
+class Window:
+    """The epoch-end marks of a run, and the window between two of them.
+
+    The window opens at the first mark after the one at which ``ready()``
+    first held (``on_ready`` runs there): the warm-up before it streams,
+    stages and compiles. It closes at the first mark at or after
+    ``seconds`` from its opening. With ``trace_dir`` the profiler runs
+    over exactly the window, and two marks in the trace give its span
+    there. ``stages()`` reads the learner's stage seconds at both ends.
+    What one epoch's rows are is the driver's: it hands them to ``mark``.
+    """
+
+    def __init__(self, seconds: float, trace_dir, stages, ready,
+                 on_ready=None):
+        import jax
+        self.seconds, self.trace_dir = seconds, trace_dir
+        self._profiler = jax.profiler
+        self._stages, self._ready, self._on_ready = stages, ready, on_ready
+        self.marks = []     # (epoch, time, rows) at each epoch's end
+        self._win = {"ready_at": None}
+
+    def mark(self, k: int, rows: float) -> bool:
+        """Keep the mark of epoch ``k``'s end; True where it closes the
+        window."""
+        now = time.perf_counter()
+        self.marks.append((k, now, float(rows)))
+        win = self._win
+        if "open" not in win:
+            ready = win["ready_at"]
+            if ready is not None and k > ready:
+                win["open"] = len(self.marks) - 1
+                win["stages_open"] = self._stages()
+                if self.trace_dir is not None:
+                    self._profiler.start_trace(self.trace_dir)
+                    _mark(OPEN_MARK)
+                win["t_open"] = time.perf_counter()
+            elif ready is None and self._ready():
+                win["ready_at"] = k
+                if self._on_ready is not None:
+                    self._on_ready()
+        elif "close" not in win and now - win["t_open"] >= self.seconds:
+            win["close"] = len(self.marks) - 1
+            win["t_close"] = now
+            win["stages_close"] = self._stages()
+            if self.trace_dir is not None:
+                _mark(CLOSE_MARK)
+                self._profiler.stop_trace()
+            return True
+        return False
+
+    def summary(self, t0: float) -> dict:
+        """The window's keys of ``drive``'s result; ``t0``: when the
+        learner's run began."""
+        win, marks = self._win, self.marks
+        if "close" not in win:
+            raise RuntimeError("the run ended before the window closed")
+        o, c = win["open"], win["close"]
+        return {
+            "t_open": win["t_open"],
+            # the open mark's own clock reading lies before the trace
+            # starts; the rate runs from the moment the window is open
+            "window_s": win["t_close"] - win["t_open"],
+            "window_rows": sum(m[2] for m in marks[o + 1:c + 1]),
+            "window_rows_by_epoch": [m[2] for m in marks[o + 1:c + 1]],
+            "window_epochs": c - o,
+            "warm_epochs": o,
+            "epoch0_s": marks[0][1] - t0,
+            "warm_s": win["t_open"] - marks[0][1],
+            "stages": {k: win["stages_close"].get(k, 0.0)
+                       - win["stages_open"].get(k, 0.0)
+                       for k in win["stages_close"]},
+        }
+
+
+def drive(kwargs: dict, plan, seconds: float, trace_dir: str = None,
           stop_after: str = "") -> dict:
     """Build the learner, run it as the entry does, and keep the marks.
 
-    The window opens at the first epoch-end mark after the warm-up (epoch
-    0 streams, stages and compiles; then whole epochs until one has run
-    with every program compiled) and closes at the first epoch-end mark
-    at or after ``seconds``. With ``trace_dir`` the profiler runs over
-    exactly the window, and two marks in the trace give its span there.
-    ``stop_after`` ("first", "pair") ends the run once the probe has
-    read that part: ``calibrate.py``'s readings need no window."""
+    An epoch's rows are the rows its training progress counts. The
+    window (``Window``) is ready once epoch 0 has streamed, staged and
+    compiled and no pair-replay program is left to compile. ``plan`` is
+    ``probe_plan``'s, or None for no probe. ``stop_after`` ("first",
+    "pair") ends the run once the probe has read that part:
+    ``calibrate.py``'s readings need no window.
+
+    Returns ``t_open``, ``window_s``, ``window_rows``,
+    ``window_rows_by_epoch``, ``window_epochs``, ``warm_epochs``,
+    ``init_s``, ``epoch0_s``, ``warm_s``, ``stages``, ``producer_mode``,
+    ``paired_dispatches``, ``device_cache``, ``table_rows``,
+    ``table_bytes``, ``probe`` and ``memory_peak_bytes``."""
     import jax
     from difacto_tpu.learners import Learner
 
-    learner = Learner.create("sgd")
+    learner = Learner.create(LEARNER)
     # The table's seed is a constant of the program that draws the table,
     # so a seed the cache has seen would load what a fresh seed compiles.
     # Nothing that compiles in ``init`` is written to the cache: every
@@ -376,37 +507,18 @@ def drive(kwargs: dict, probe_rows, seconds: float, trace_dir: str = None,
         jax.config.update(knob, kept)
     if remain:
         raise ValueError(f"keys the learner does not know: {remain}")
-    probe = (Probe(learner, probe_rows, stop_after=stop_after)
-             if probe_rows is not None else None)
+    probe = (Probe(learner, plan[0], stop_after=stop_after)
+             if plan is not None else None)
     init_s = time.perf_counter() - t_init    # table init and the probe
 
-    marks = []          # (epoch, time, rows, loss) at each epoch's end
-    win = {"ready_at": None}
+    win = Window(seconds, trace_dir,
+                 stages=lambda: _stage_seconds(learner),
+                 ready=lambda: not _pair_compile_pending(learner),
+                 # the pair is read in the epoch that follows
+                 on_ready=probe.arm_pair if probe is not None else None)
 
     def on_epoch_end(k, train_prog, _val):
-        now = time.perf_counter()
-        marks.append((k, now, float(train_prog.nrows),
-                      float(train_prog.loss)))
-        if "open" not in win:
-            ready = win["ready_at"]
-            if ready is not None and k > ready:
-                win["open"] = len(marks) - 1
-                win["stages_open"] = _stage_seconds(learner)
-                if trace_dir is not None:
-                    jax.profiler.start_trace(trace_dir)
-                    _mark(OPEN_MARK)
-                win["t_open"] = time.perf_counter()
-            elif ready is None and not _pair_compile_pending(learner):
-                win["ready_at"] = k
-                if probe is not None:
-                    probe.arm_pair()    # read in the epoch that follows
-        elif "close" not in win and now - win["t_open"] >= seconds:
-            win["close"] = len(marks) - 1
-            win["t_close"] = now
-            win["stages_close"] = _stage_seconds(learner)
-            if trace_dir is not None:
-                _mark(CLOSE_MARK)
-                jax.profiler.stop_trace()
+        if win.mark(k, train_prog.nrows):
             learner.param.max_num_epochs = k + 1
 
     learner.add_epoch_end_callback(on_epoch_end)
@@ -417,46 +529,65 @@ def drive(kwargs: dict, probe_rows, seconds: float, trace_dir: str = None,
         learner.stop()
         numbers = probe.numbers()
         learner.store.state = None
-        learner = probe = None
+        learner = probe = win = None
         gc.collect()
         return {"probe": numbers}
     finally:
         if probe is not None:
             probe.release()
             probe.disarm_pair()
-    if "close" not in win:
-        raise RuntimeError("the run ended before the window closed")
 
-    o, c = win["open"], win["close"]
-    rows = sum(m[2] for m in marks[o + 1:c + 1])
-    out = {
-        "t_open": win["t_open"],
-        # the open mark's own clock reading lies before the trace starts;
-        # the rate runs from the moment the window is open
-        "window_s": win["t_close"] - win["t_open"],
-        "window_rows": rows,
-        "window_rows_by_epoch": [m[2] for m in marks[o + 1:c + 1]],
-        "window_epochs": c - o,
-        "warm_epochs": o,
-        "init_s": init_s,
-        "epoch0_s": marks[0][1] - t0,
-        "warm_s": win["t_open"] - marks[0][1],
-        "stages": {k: win["stages_close"].get(k, 0.0)
-                   - win["stages_open"].get(k, 0.0)
-                   for k in win["stages_close"]},
-        "producer_mode": getattr(learner, "_last_producer_mode", "?"),
-        "paired_dispatches": getattr(learner, "_paired_dispatches", 0),
-        "device_cache": learner.device_cache_info(),
-        "table_rows": int(learner.store.state.capacity),
-        "table_bytes": sum(int(x.nbytes) for x in learner.store.state),
-        "probe": probe.numbers() if probe is not None else None,
-    }
+    out = dict(
+        win.summary(t0), init_s=init_s,
+        producer_mode=getattr(learner, "_last_producer_mode", "?"),
+        paired_dispatches=getattr(learner, "_paired_dispatches", 0),
+        device_cache=learner.device_cache_info(),
+        table_rows=int(learner.store.state.capacity),
+        table_bytes=sum(int(x.nbytes) for x in learner.store.state),
+        probe=probe.numbers() if probe is not None else None)
     out["memory_peak_bytes"] = memory_peak_bytes()
     # free the program's state before the reference runs
     learner.epoch_end_callbacks.clear()
     learner.store.state = None
     getattr(learner, "_dev_caches", {}).clear()
     getattr(learner, "_pair_execs", {}).clear()
-    del learner, probe
+    del learner, probe, win
     gc.collect()
     return out
+
+
+# ------------------------------------------------------------ comparison
+def compare(ref_mod, config: dict, kwargs: dict, plan, res: dict,
+            data: dict) -> dict:
+    """The program's first steps and its watched pair call against the
+    plain reference from the same seed, and each epoch of the window
+    against the traffic's rows (``check.py``). Returns ``numbers``,
+    ``names`` (those the table's layout must have) and ``said`` (the
+    ``reference`` line)."""
+    from perfbench import check
+    probe_rows, batches = plan
+    hyper = ref_mod.Hyper.of(config)
+    t0 = time.perf_counter()
+    V0 = ref_mod.initial_V(kwargs["seed"], int(config["hash_capacity"]),
+                           probe_rows, hyper)
+    V0.block_until_ready()
+    t_init = time.perf_counter() - t0
+    ref = ref_mod.follow(hyper, V0, batches)
+    nums = check.numbers(res["probe"], ref, ref_mod.rel_diff)
+    pair = res["probe"].pop("pair")
+    pair_said = None
+    if pair is not None:
+        # the window's own executable: its first call of the warm epoch
+        # took the epoch's first two batches
+        pref = ref_mod.follow_pair(hyper, pair["before"], batches[:2])
+        nums.update(ref_mod.pair_numbers(pair, pref, check.gap))
+        pair_said = {"program": pair["loss"], "reference": pref["loss"]}
+    nums["epoch_rows"] = check.epoch_rows(res["window_rows_by_epoch"],
+                                          data["rows"])
+    for side in (res["probe"], ref):
+        side.pop("rows")
+    return {"numbers": nums, "names": check.names(hyper.V_dim),
+            "said": {"initial_table_s": round(t_init, 3),
+                     "touched_rows": int(len(probe_rows)),
+                     "program": res["probe"], "reference": ref,
+                     "pair_loss": pair_said}}

@@ -47,9 +47,10 @@ def _three_steps(root, seed):
     work = os.path.join(root, "rows")
     os.makedirs(work)
     data = R.make_data(seed, config, traffic, work, sut.N_STEPS)
-    rows, batches = R.first_steps(data, config, ref_mod)
+    plan = sut.probe_plan(data, cfg_kw, ref_mod)
+    rows, batches = plan
     kwargs = sut.learner_kwargs(cfg_kw, traffic, work, seed)
-    prog = sut.drive(kwargs, rows, 0.0, stop_after="first")["probe"]
+    prog = sut.drive(kwargs, plan, 0.0, stop_after="first")["probe"]
     V0 = ref_mod.initial_V(kwargs["seed"], int(config["hash_capacity"]),
                            rows, hyper)
     ref = ref_mod.follow(hyper, V0, batches)

@@ -337,24 +337,24 @@ def test_judge_holds_every_number_to_its_limit(V_dim):
     leaf = "grad_V" if V_dim else "zero_w"
     nums = {n: 1e-6 for n in names}
     lim = dict({n: 1e-5 for n in names}, _about="text")
-    ok, checked = check.judge(nums, lim, V_dim)
+    ok, checked = check.judge(nums, lim, names)
     assert ok and list(checked) == list(names)
     # a number the run produced and no limit holds, and a limit whose
     # number the run did not produce (the pair never ran), both fail
-    assert not check.judge(dict(nums, pair_loss1=0.0), lim, V_dim)[0]
-    ok, checked = check.judge(nums, dict(lim, pair_loss1=1e-5), V_dim)
+    assert not check.judge(dict(nums, pair_loss1=0.0), lim, names)[0]
+    ok, checked = check.judge(nums, dict(lim, pair_loss1=1e-5), names)
     assert not ok and checked["pair_loss1"]["value"] == "inf"
     assert check.judge(dict(nums, pair_loss1=0.0),
-                       dict(lim, pair_loss1=1e-5), V_dim)[0]
+                       dict(lim, pair_loss1=1e-5), names)[0]
     assert checked["loss2"] == {"value": 1e-6, "limit": 1e-5}
-    assert not check.judge(dict(nums, **{leaf: 2e-5}), lim, V_dim)[0]
-    assert not check.judge(dict(nums, loss3=float("nan")), lim, V_dim)[0]
+    assert not check.judge(dict(nums, **{leaf: 2e-5}), lim, names)[0]
+    assert not check.judge(dict(nums, loss3=float("nan")), lim, names)[0]
     assert not check.judge(nums, {k: v for k, v in lim.items()
-                                  if k != "change_w"}, V_dim)[0]
+                                  if k != "change_w"}, names)[0]
     # a number of the layout that the run lost and the file forgot
     assert not check.judge({k: v for k, v in nums.items() if k != leaf},
                            {k: v for k, v in lim.items() if k != leaf},
-                           V_dim)[0]
+                           names)[0]
 
 
 def test_each_layout_has_its_numbers():
@@ -365,8 +365,9 @@ def test_each_layout_has_its_numbers():
     assert not any(n.endswith(("_V", "_Vg")) for n in flat)
     # the other layout's limits judge no run of this one
     nums = {n: 0.0 for n in flat}
-    assert not check.judge(nums, {n: 1.0 for n in check.NUMBERS}, 0)[0]
-    assert not check.judge(nums, {n: 1.0 for n in flat}, 64)[0]
+    assert not check.judge(nums, {n: 1.0 for n in check.NUMBERS}, flat)[0]
+    assert not check.judge(nums, {n: 1.0 for n in flat},
+                           check.NUMBERS)[0]
 
 
 def test_numbers_take_no_gap_of_an_empty_leaf():
@@ -390,7 +391,7 @@ def test_epoch_rows_is_exact():
     assert check.judge(dict({n: 0.0 for n in check.NUMBERS},
                             epoch_rows=0.0),
                        dict({n: 0.0 for n in check.NUMBERS},
-                            epoch_rows=0), 64)[0]
+                            epoch_rows=0), check.NUMBERS)[0]
 
 
 def test_gap_is_of_norms():
