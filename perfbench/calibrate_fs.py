@@ -8,13 +8,12 @@ A cell under a mesh replays no pairs, so its limits hold the first three
 steps' numbers alone; each seed's run ends after the third step, over the
 first eight members of the cell's rows. ``--faults`` reads, beside each
 seed's numbers, the faults planted in the reference put in the program's
-place, against the reference as it is: ``calibrate.py``'s of the first
-steps (every second row of each batch left out), and ``shard_out``: one
-shard's rows left out of the gather, so that the steps read zeros where
-the seed's table
-has the embeddings of rows ``[capacity/fs * k, capacity/fs * (k + 1))``
-(the shard that holds most of the touched rows). Not part of a benchmark
-run.
+place, against the reference as it is: the SGD driver's of the first
+steps (``sut.first_faults``: every second row of each batch left out),
+and ``shard_out``: one shard's rows left out of the gather, so that the
+steps read zeros where the seed's table has the embeddings of rows
+``[capacity/fs * k, capacity/fs * (k + 1))`` (the shard that holds most
+of the touched rows). Not part of a benchmark run.
 """
 
 from __future__ import annotations
@@ -42,7 +41,7 @@ def shard_out(V0, probe_rows, capacity: int, fs: int):
 
 def reading(bench: dict, workload: str, seed: int, override,
             faults: bool, require_tpu: bool) -> dict:
-    from perfbench import calibrate, check, sut
+    from perfbench import check, sut
     from perfbench import run as R
     loaded = R.load_cell(bench, ROOT, workload)
     config, traffic = loaded["config"], dict(loaded["traffic"])
@@ -71,7 +70,7 @@ def reading(bench: dict, workload: str, seed: int, override,
     nums = check.numbers(prog, ref, ref_mod.rel_diff)
     planted = {}
     if faults:
-        for f, (h, bs) in calibrate.first_faults(hyper, batches).items():
+        for f, (h, bs) in sut.first_faults(hyper, batches).items():
             planted[f] = check.numbers(ref_mod.follow(h, V0, bs), ref,
                                        ref_mod.rel_diff)
         bad = ref_mod.follow(

@@ -37,8 +37,20 @@ below is the configuration's keys, those of ``META`` left out):
 - ``step_work(data, config)``: the necessary bytes and flops of one step
   of ``batch_size`` rows (``work.py``);
 - ``compare(ref_mod, config, kwargs, plan, res, data)``: the numbers
-  compared, the names that must be among them, and what the
-  ``reference`` line prints.
+  compared, the names that must be among them (``names(config)``), and
+  what the ``reference`` line prints;
+- ``names(config)``: the numbers ``compare`` always produces, each of
+  which the cell's limits file holds;
+- ``FAULTS``: the faults the driver plants, by name, which a
+  configuration's ``"control": {"fault": ...}`` may name;
+- ``reading(ref_mod, config, traffic, limits, work_dir, seed, override,
+  part, faults)`` (for ``calibrate.py``; optional): one seed's numbers
+  with no measured window, the part of the run named (None: the
+  driver's first) and, with ``faults``, the planted faults' numbers
+  beside them; returns ``part``, ``correct``, ``numbers``, ``faults``,
+  ``program`` and ``reference``;
+- ``TINY`` (for the CPU tests; optional): the configuration's keys that
+  cut it to the tests' tiny size.
 """
 
 from __future__ import annotations

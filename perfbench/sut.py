@@ -2,8 +2,9 @@
 driver, and what every driver shares.
 
 The driver of every configuration that names none (``run.load_driver``;
-``run.py`` lists what a driver owns), and the parts any driver uses: the
-device binding, the compile listener, the rec member writer and the
+``run.py`` lists what a driver owns, ``reading`` here is what
+``calibrate.py`` reads a seed through), and the parts any driver uses:
+the device binding, the compile listener, the rec member writer and the
 window between two epoch-end marks. It takes from the program the entry
 that ``python -m difacto_tpu task=train`` runs (``place_compile_cache``,
 ``Learner.create(LEARNER)``, ``init``, ``run``), the epoch-end callback,
@@ -46,6 +47,19 @@ import numpy as np
 LEARNER = "sgd"
 N_STEPS = 3          # steps of epoch 0 that the reference follows
 HUGE_EPOCHS = 1_000_000
+# the faults planted in the reference put in the program's place
+# (``reading``), which a configuration's ``"control": {"fault": ...}``
+# may name: every second row of each batch left out, a second step of
+# the pair that reads the state from before the first, and the soft
+# threshold left out (the flat table's alone)
+PAIR_FAULTS = ("stale", "half_batch")
+FAULTS = PAIR_FAULTS + ("no_l1",)
+# ``reading``'s parts: the pair (the default) or the first steps alone
+PARTS = ("pair", "first")
+# a configuration at the size of the CPU tests: an 8-wide float32
+# embedding in a 4096-row table, batches of 64 rows
+TINY = {"V_dim": 8, "V_dtype": "float32", "hash_capacity": 4096,
+        "batch_size": 64}
 
 
 # ---------------------------------------------------------------- device
@@ -586,8 +600,84 @@ def compare(ref_mod, config: dict, kwargs: dict, plan, res: dict,
                                           data["rows"])
     for side in (res["probe"], ref):
         side.pop("rows")
-    return {"numbers": nums, "names": check.names(hyper.V_dim),
+    return {"numbers": nums, "names": names(config),
             "said": {"initial_table_s": round(t_init, 3),
                      "touched_rows": int(len(probe_rows)),
                      "program": res["probe"], "reference": ref,
                      "pair_loss": pair_said}}
+
+
+def names(config: dict) -> tuple:
+    """The numbers ``compare`` always produces: the first steps' of the
+    table's layout (``check.names``)."""
+    from perfbench import check
+    return check.names(int(config["V_dim"]))
+
+
+# ------------------------------------------------------------ calibration
+def first_faults(hyper, batches) -> dict:
+    """{fault: (hyper, batches)} of the first steps' planted faults that
+    a table of this layout can show: what the reference follows in the
+    program's place."""
+    import dataclasses
+    out = {"half_batch": (hyper, [(i[::2], y[::2]) for i, y in batches])}
+    if hyper.V_dim == 0:
+        # w = z / eta wherever z is not 0: only l1 makes a weight that a
+        # batch touched exactly 0
+        out["no_l1"] = (dataclasses.replace(hyper, l1=0.0), batches)
+    return out
+
+
+def reading(ref_mod, config: dict, traffic: dict, limits: dict,
+            work_dir: str, seed: int, override: dict = None,
+            part: str = None, faults: bool = False) -> dict:
+    """One seed's numbers for ``calibrate.py``, with no window.
+
+    ``part`` "pair" (the default) runs the cell's own epoch 0 and ends
+    after the first call of the pair-replay executable in the epoch that
+    follows; "first" ends after the third step, over the first eight
+    members of the cell's rows, and reads the first steps' numbers alone
+    (enough for a control that they fail). With ``faults``, beside the
+    numbers, those of ``FAULTS`` planted in the reference put in the
+    program's place, against the reference as it is (each layout and
+    part reads the faults it can show). The rows are written under
+    ``work_dir``. Returns ``part``, ``correct``, ``numbers``, ``faults``
+    and both sides, ``program`` and ``reference``."""
+    from perfbench import check
+    from perfbench import run as R
+    part = part or PARTS[0]
+    if part not in PARTS:
+        raise SystemExit(f"perfbench: no part {part!r}; the SGD driver "
+                         f"reads {', '.join(PARTS)}")
+    traffic = dict(traffic)
+    if part == "first":
+        traffic["rows_per_epoch"] = 8 * int(config["batch_size"])
+    hyper = ref_mod.Hyper.of(config)
+    data = R.make_data(seed, config, traffic, work_dir, N_STEPS)
+    plan = probe_plan(data, config, ref_mod)
+    kwargs = learner_kwargs(config, traffic, work_dir, seed, override)
+    prog = drive(kwargs, plan, 0.0, stop_after=part)["probe"]
+    probe_rows, batches = plan
+    V0 = ref_mod.initial_V(kwargs["seed"], int(config["hash_capacity"]),
+                           probe_rows, hyper)
+    ref = ref_mod.follow(hyper, V0, batches)
+    nums = check.numbers(prog, ref, ref_mod.rel_diff)
+    planted = {}
+    if faults:
+        for f, (h, bs) in first_faults(hyper, batches).items():
+            planted[f] = check.numbers(ref_mod.follow(h, V0, bs), ref,
+                                       ref_mod.rel_diff)
+    pair = prog.pop("pair")
+    if pair is not None:
+        pref = ref_mod.follow_pair(hyper, pair["before"], batches[:2])
+        nums.update(ref_mod.pair_numbers(pair, pref, check.gap))
+        for f in PAIR_FAULTS if faults else ():
+            bad = ref_mod.follow_pair(hyper, pair["before"], batches[:2],
+                                      fault=f)
+            planted.setdefault(f, {}).update(ref_mod.pair_numbers(
+                dict(bad, before=pair["before"]), pref, check.gap))
+    ok, _ = check.judge(dict(nums, epoch_rows=0.0), limits, names(config))
+    for side in (prog, ref):
+        side.pop("rows")
+    return {"part": part, "correct": ok, "numbers": nums,
+            "faults": planted, "program": prog, "reference": ref}

@@ -36,33 +36,56 @@ def bench() -> dict:
         return json.load(f)
 
 
-def make_root(tmp: str, config: str = "fm_v64_criteo", batch: int = 64,
-              steps: int = 8, capacity: int = 4096, limits=None,
-              **cfg) -> str:
-    """``tmp/perfbench`` = the benchmark's files, with ``config`` cut to
-    a ``capacity``-row float32 table and every traffic mix to ``steps``
-    steps of ``batch`` rows over a few hundred tokens."""
-    bdir = os.path.join(tmp, "perfbench")
-    shutil.copytree(os.path.join(ROOT, "perfbench"), bdir,
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    path = os.path.join(bdir, "configs", config + ".json")
+# the traffics' learner keys that the SGD learner alone takes: a traffic
+# file whose learner block carries one is cut for that learner too
+SGD_TRAFFIC_KEYS = ("device_cache_mb", "shuffle", "report_interval",
+                    "num_jobs_per_epoch")
+
+
+def cut_config(root: str, config: str, batch: int = None,
+               capacity: int = None, **cfg) -> dict:
+    """Cut ``root``'s configuration ``config`` to the tiny size that its
+    driver gives (``TINY``; ``run.load_driver`` finds the driver under
+    ``root``), then to ``batch`` rows a step, a ``capacity``-row table
+    and the keys of ``cfg``; returns it as written."""
+    from perfbench import run as R
+    path = os.path.join(root, "perfbench", "configs", config + ".json")
     with open(path) as f:
         c = json.load(f)
-    c.update(V_dim=8, V_dtype="float32", hash_capacity=capacity,
-             batch_size=batch)
+    c.update(getattr(R.load_driver(root, c), "TINY", {}))
+    if batch is not None:
+        c["batch_size"] = batch
+    if capacity is not None:
+        c["hash_capacity"] = capacity
     c.update(cfg)
     with open(path, "w") as f:
         json.dump(c, f)
+    return c
+
+
+def make_root(tmp: str, config: str = "fm_v64_criteo", batch: int = None,
+              steps: int = 8, capacity: int = None, limits=None,
+              **cfg) -> str:
+    """``tmp/perfbench`` = the benchmark's files, with ``config`` cut as
+    ``cut_config`` cuts it (for the SGD driver: a 4096-row float32 table,
+    batches of 64 rows) and every traffic mix to ``steps`` steps of its
+    batch over a few hundred tokens."""
+    bdir = os.path.join(tmp, "perfbench")
+    shutil.copytree(os.path.join(ROOT, "perfbench"), bdir,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    c = cut_config(tmp, config, batch, capacity, **cfg)
     for name in os.listdir(os.path.join(bdir, "traffic")):
         tpath = os.path.join(bdir, "traffic", name)
         with open(tpath) as f:
             t = json.load(f)
-        t["rows_per_epoch"] = batch * steps
+        t["rows_per_epoch"] = int(c["batch_size"]) * steps
         t["generator"].update(int_tokens=20, cat_tokens=300)
         t["trace_seconds"] = 0.3
-        t["learner"]["producer_mode"] = "thread"
-        if t["learner"].get("device_cache_mb"):
-            t["learner"]["device_cache_mb"] = 64
+        learner = t["learner"]
+        if set(learner) & set(SGD_TRAFFIC_KEYS):
+            learner["producer_mode"] = "thread"
+            if learner.get("device_cache_mb"):
+                learner["device_cache_mb"] = 64
         with open(tpath, "w") as f:
             json.dump(t, f)
     os.makedirs(os.path.join(bdir, "limits"), exist_ok=True)

@@ -9,11 +9,14 @@ of the line search alike. The window is ready once the two-loop's history
 is full (``m`` epochs), so no program it runs has a new shape after. The
 probe keeps the objective the learner reports after epoch 1 and the
 weights it reports it at; ``compare`` sets it against the plain
-objective at those weights (``seam_lbfgs_reference.py``).
+objective at those weights (``seam_lbfgs_reference.py``), and
+``reading`` (``calibrate.py``) does the same with no window, beside the
+faults it plants in that objective.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 import time
 
@@ -25,6 +28,11 @@ LEARNER = "lbfgs"
 N_STEPS = 1 << 20       # every member: the objective is over all rows
 PROBE_EPOCH = 1
 HUGE_EPOCHS = 1_000_000
+# planted in the reference put in the program's place (``reading``): the
+# objective with half of its rows' loss, and without its l2 term
+FAULTS = ("half_the_loss", "no_regulariser")
+# the history of the two-loop cut to 5 pairs, members of 64 rows
+TINY = {"m": 5, "batch_size": 64}
 
 
 class WindowClosed(Exception):
@@ -66,8 +74,14 @@ def step_work(data: dict, config: dict) -> dict:
             "flops": float(2 * nnz + 5 * rows)}
 
 
-def drive(kwargs: dict, plan, seconds: float, trace_dir: str = None
-          ) -> dict:
+def names(config: dict) -> tuple:
+    return ("objv", "epoch_rows")
+
+
+def drive(kwargs: dict, plan, seconds: float, trace_dir: str = None,
+          stop_after: str = "") -> dict:
+    """``stop_after`` "probe" ends the run at the probe's epoch, with the
+    probe alone: ``reading`` needs no window."""
     from difacto_tpu.learners import Learner
     learner = Learner.create(LEARNER)
     t_init = time.perf_counter()
@@ -98,6 +112,8 @@ def drive(kwargs: dict, plan, seconds: float, trace_dir: str = None
                 objv=float(prog.objv),
                 feaids=np.asarray(learner.feaids),
                 w=np.asarray(learner.weights)[learner.offsets[:-1]])
+            if stop_after == "probe":
+                raise WindowClosed()
         if win.mark(k, rows):
             raise WindowClosed()
 
@@ -107,6 +123,9 @@ def drive(kwargs: dict, plan, seconds: float, trace_dir: str = None
         learner.run()
     except WindowClosed:
         pass
+    if stop_after == "probe":
+        learner.epoch_end_callbacks.clear()
+        return {"probe": probe}
     out = dict(win.summary(t0), init_s=init_s, producer_mode=None,
                paired_dispatches=None, device_cache=None,
                table_rows=int(learner.N_pad),
@@ -130,6 +149,36 @@ def compare(ref_mod, config: dict, kwargs: dict, plan, res: dict,
     nums = {"objv": check.gap(p["objv"], ref),
             "epoch_rows": max((math.fmod(r, data["rows"]) / data["rows"]
                                for r in by_epoch), default=math.inf)}
-    return {"numbers": nums, "names": ("objv", "epoch_rows"),
+    return {"numbers": nums, "names": names(config),
             "said": {"program": {"objv": p["objv"]},
                      "reference": {"objv": ref}}}
+
+
+def reading(ref_mod, config: dict, traffic: dict, limits: dict,
+            work_dir: str, seed: int, override: dict = None,
+            part: str = None, faults: bool = False) -> dict:
+    """``calibrate.py``'s one seed: the run up to the probe's epoch (the
+    one part, "probe"), its ``objv`` against the plain objective, and
+    with ``faults`` that of each of ``FAULTS`` at the same weights."""
+    from perfbench import run as R
+    if part not in (None, "probe"):
+        raise SystemExit(f"perfbench: no part {part!r}; the seam's "
+                         "driver reads \"probe\"")
+    data = R.make_data(seed, config, traffic, work_dir, N_STEPS)
+    plan = probe_plan(data, config, ref_mod)
+    kwargs = learner_kwargs(config, traffic, work_dir, seed, override)
+    p = drive(kwargs, plan, 0.0, stop_after="probe")["probe"]
+    objv = functools.partial(ref_mod.objective, plan["rev"], plan["y"],
+                             p["feaids"], p["w"])
+    ref, loss = objv(float(config["l2"])), objv(0.0)
+    nums = {"objv": check.gap(p["objv"], ref)}
+    planted = {}
+    if faults:
+        planted = {"half_the_loss": 0.5 * loss + (ref - loss),
+                   "no_regulariser": loss}
+        planted = {f: {"objv": check.gap(v, ref)}
+                   for f, v in planted.items()}
+    ok, _ = check.judge(dict(nums, epoch_rows=0.0), limits, names(config))
+    return {"part": "probe", "correct": ok, "numbers": nums,
+            "faults": planted, "program": {"objv": p["objv"]},
+            "reference": {"objv": ref}}

@@ -189,7 +189,7 @@ def test_cell_is_declared_by_membership():
         stem = {"configs": CONFIG, "traffic": TRAFFIC, "limits": CELL}[name]
         assert os.path.exists(os.path.join(tiny.ROOT, "perfbench", name,
                                            stem + ".json"))
-    # every per-layer metric that lists no cells reads this one too
+    # every per-layer metric that lists all six cells reads this one too
     from perfbench import run as R
     named = {m["name"] for m in R.metrics_of(b, "per_layer", CELL)}
     assert {"step_device_ms.replay", "leg_scatter_ms.replay",

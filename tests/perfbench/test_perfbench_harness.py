@@ -151,7 +151,7 @@ def test_calibrate_reads_the_layouts_faults(root, monkeypatch):
     """``calibrate.py --faults`` at the tiny size: the sound numbers, and
     each fault planted in the reference reads far from them; the soft
     threshold left out is the flat table's alone."""
-    from perfbench import calibrate
+    from perfbench import calibrate, sut
     # the tool reads the repository's own files: hand it the tiny ones
     monkeypatch.setattr(calibrate, "ROOT", str(root))
     row = calibrate.reading(tiny.bench(), "fm_v64_criteo.replay", 7, None,
@@ -162,7 +162,7 @@ def test_calibrate_reads_the_layouts_faults(root, monkeypatch):
     faults = row["faults"]
     assert set(faults) == {"half_batch", "stale"} | (
         {"no_l1"} if root.flat else set())
-    assert set(faults) <= set(calibrate.FAULTS)
+    assert set(faults) <= set(sut.FAULTS)
     assert faults["half_batch"]["loss1"] == pytest.approx(0.5, abs=0.05)
     assert faults["half_batch"]["pair_loss1"] == pytest.approx(0.5,
                                                                abs=0.05)

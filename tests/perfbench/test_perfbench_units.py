@@ -4,13 +4,13 @@ the necessary-work count, the peaks table, the generator, the judge."""
 import gzip
 import json
 import os
-import re
 
 import numpy as np
 import pytest
 
+import benchmark_checks as checks
 import perfbench_tiny as tiny  # noqa: F401  (puts the repo on sys.path)
-from perfbench import calibrate, check, gen, layers, tracered, work
+from perfbench import check, gen, layers, tracered, work
 from perfbench import run as R
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -402,59 +402,33 @@ def test_gap_is_of_norms():
 
 
 # ------------------------------------------------------- BENCHMARK.json
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-
-
 def test_benchmark_json_is_served_by_files():
+    checks.served_by_files(tiny.bench(), tiny.ROOT)
+
+
+def test_every_per_layer_metric_lists_its_cells():
+    """A cell reports the per-layer metrics whose lists it joins, and no
+    metric falls to a new cell by its end-to-end metric alone."""
     b = tiny.bench()
-    assert set(b) == {"command", "paths", "run_seconds", "configs",
-                      "workloads", "end_to_end", "per_layer"}
-    bdir = os.path.join(tiny.ROOT, b["paths"][0])
-    layout = {}
-    for c in b["configs"]:
-        assert NAME.match(c["name"])
-        with open(os.path.join(tiny.ROOT, c["file"])) as f:
-            cfg = json.load(f)
-        # compared with its plain reference: the file names it, and it
-        # lies in the benchmark's own directory
-        ref = os.path.join(tiny.ROOT, cfg["reference"])
-        assert os.path.exists(ref) and cfg["reference"].startswith(
-            b["paths"][0] + "/")
-        assert set(c["reduced"]) <= set(cfg)
-        assert set(c["reduced"]) == set(cfg["about"]["reduced"]) | {
-            k for k in cfg["about"]["assumed"] if k != "why"}
-        # the control: the program's keys for its nearest precision
-        # below, or one of calibrate.py's planted faults by name
-        control = dict(cfg["control"])
-        assert control.pop("why") and cfg["precision"]
-        if "fault" in control:
-            assert control == {"fault": control["fault"]}
-            assert control["fault"] in calibrate.FAULTS
-        else:
-            assert control and set(control) <= {"V_dtype", "slot_dtype"}
-        layout[c["name"]] = int(cfg["V_dim"])
-    e2e = {m["name"] for m in b["end_to_end"]}
-    assert "setup_s" in e2e
-    for w in b["workloads"]:
-        assert NAME.match(w["name"]) and len(w["why"]) <= 200
-        assert os.path.exists(os.path.join(
-            bdir, "traffic", w["traffic"] + ".json"))
-        # the limits file names the numbers of its own layout, and none
-        # of the other's
-        with open(os.path.join(bdir, "limits", w["name"] + ".json")) as f:
-            limited = {k for k in json.load(f) if not k.startswith("_")}
-        mine = set(check.names(layout[w["config"]]))
-        other = set(check.NUMBERS + check.FLAT_NUMBERS) - mine
-        assert limited >= mine | {"epoch_rows"} and not limited & other
-        mine = [m["name"] for m in R.metrics_of(b, "end_to_end",
-                                                w["name"])]
-        assert "setup_s" in mine and len(mine) >= 2
-        assert R.metrics_of(b, "per_layer", w["name"])
+    cells = [w["name"] for w in b["workloads"]]
     for m in b["per_layer"]:
-        assert NAME.match(m["name"]) and m["moves"] in e2e
-        assert R.load_reader(bdir, m["name"]) is not None
-        if "roofline" in m["name"] or "mfu" in m["name"]:
-            assert m["unit"] == "%"
+        assert m.get("workloads"), m["name"]
+        assert len(set(m["workloads"])) == len(m["workloads"])
+        assert [c for c in cells if c in m["workloads"]] == m["workloads"]
+
+
+def _recorded_cells():
+    with open(os.path.join(HERE, "data", "per_layer_of_cells.json")) as f:
+        return {k: v for k, v in json.load(f).items()
+                if not k.startswith("_")}
+
+
+@pytest.mark.parametrize("cell", list(_recorded_cells()))
+def test_each_cell_reports_the_per_layer_metrics_it_reported(cell):
+    """The names ``metrics_of`` gave each cell before every per-layer
+    entry listed its cells, in the same order."""
+    got = [m["name"] for m in R.metrics_of(tiny.bench(), "per_layer", cell)]
+    assert got == _recorded_cells()[cell]
 
 
 def test_metrics_of_selects_by_cell():
